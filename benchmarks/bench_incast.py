@@ -24,17 +24,17 @@ import os, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core.tar import pad_for_tar, tar_allreduce_rounds
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 1 << 16))
 for incast in (1, 4):
     def body(v, incast=incast):
         vv, ln = pad_for_tar(v.reshape(-1), 8)
         return tar_allreduce_rounds(vv, "data", incast=incast)[None, :ln]
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data", None),
-                          out_specs=P("data", None), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
+                              out_specs=P("data", None), check_vma=False))
     barriers = f.lower(x).as_text().count("optimization_barrier")
     jax.block_until_ready(f(x))
     t0 = time.perf_counter()
@@ -47,7 +47,9 @@ for incast in (1, 4):
 
 
 def _real_schedule_rows(rows: Rows) -> None:
-    env = dict(os.environ)
+    # the child wants eight virtual CPU devices, never the chip (which
+    # this benchmark process may already hold)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

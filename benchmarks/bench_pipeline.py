@@ -37,7 +37,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import (OptiReduceConfig, SyncContext, sync_pytree,
                         sync_pytree_unfused)
 from jax.sharding import PartitionSpec as P
@@ -53,7 +53,7 @@ VARIANTS = (("fused", "scan"),            # historical row name for scan mode
 
 def _build(nbuckets: int, strategy: str = "optireduce",
            mode: str | None = "scan"):
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cfg = OptiReduceConfig(strategy=strategy, drop_rate=0.0,
                            hadamard_block=256)
     tree = {"g": jnp.zeros((nbuckets * BUCKET,), jnp.float32)}
@@ -65,8 +65,8 @@ def _build(nbuckets: int, strategy: str = "optireduce",
             return sync_pytree_unfused(t, ctx, bucket_elems=BUCKET)
         return sync_pytree(t, ctx, bucket_elems=BUCKET, mode=mode)
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                          check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                              out_specs=spec, check_vma=False))
     return f, tree
 
 

@@ -52,8 +52,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from . import drops as drops_lib
 from . import ring as ring_lib
 from . import tar as tar_lib
@@ -655,7 +653,7 @@ class PsumTopology(Topology):
 
     def encode_stage(self, bucket, transport, codec, ctx):
         cfg = ctx.cfg
-        n = compat.axis_size(cfg.data_axis)
+        n = jax.lax.axis_size(cfg.data_axis)
         if active_subset(cfg, n) is not None:
             raise ValueError(
                 "psum is XLA-native: it cannot exclude peers — degraded "
@@ -734,7 +732,7 @@ class RingTopology(Topology):
 
     def encode_stage(self, bucket, transport, codec, ctx):
         cfg = ctx.cfg
-        n = compat.axis_size(cfg.data_axis)
+        n = jax.lax.axis_size(cfg.data_axis)
         active, order, weights = self._geometry(cfg, n)
         if weights is not None:
             pad_n = sum(weights)
@@ -749,7 +747,7 @@ class RingTopology(Topology):
     def exchange_stage(self, state, transport, codec, ctx):
         data, lo, step = state
         cfg = ctx.cfg
-        n = compat.axis_size(cfg.data_axis)
+        n = jax.lax.axis_size(cfg.data_axis)
         active, order, weights = self._geometry(cfg, n)
         if order is not None:
             # virtual ring of active peers in link-avoiding order; ejected
@@ -814,7 +812,7 @@ class TarTopology(Topology):
 
     def _outer_reduce(self, own, codec, ctx):
         cfg = ctx.cfg
-        g = compat.axis_size(cfg.pod_axis)
+        g = jax.lax.axis_size(cfg.pod_axis)
         if g <= 1:
             return own
         if self.outer == "tar" and own.shape[0] % g == 0:
@@ -853,7 +851,7 @@ class TarTopology(Topology):
 
     def encode_stage(self, bucket, transport, codec, ctx):
         cfg = ctx.cfg
-        n = compat.axis_size(cfg.data_axis)
+        n = jax.lax.axis_size(cfg.data_axis)
         _, n_shards, weights, _ = self._participation(cfg, n)
         if weights is not None:
             self._check_weighted(cfg, codec)
@@ -890,7 +888,7 @@ class TarTopology(Topology):
                     amax = jax.lax.pmax(amax, extra)
             enc_q = codec.encode_given_amax(data, amax, ctx)
             data, lo, step = enc_q.data, enc_q.lo, enc_q.step
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         active, n_shards, weights, dead = self._participation(cfg, n)
         enc = Encoded(data, lo=lo, step=step, stale=stale)
         if weights is not None:
@@ -964,7 +962,7 @@ class TarTopology(Topology):
         scattering ``dim`` over ``axis`` — the FSDP/ZeRO grad reduction;
         the all_gather at next use is the deferred stage 2."""
         cfg = ctx.cfg
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         _active = active_subset(cfg, n)
         part_n = n if _active is None else len(_active)
         if weights_subset(cfg, part_n) is not None or dead_link_set(cfg, n):

@@ -11,12 +11,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
-
-def _n(axis: str) -> int:
-    return compat.axis_size(axis)
-
 
 def psum_mean(x: jnp.ndarray, axis) -> jnp.ndarray:
     return jax.lax.pmean(x, axis)
@@ -47,7 +41,7 @@ def ring_allreduce(x: jnp.ndarray, axis: str, *,
     multiple of ``sum(weights)`` and is cut by ``tar.shard_plan`` into
     contiguous slices that ride the ring zero-padded to the widest slice.
     """
-    n = _n(axis)
+    n = jax.lax.axis_size(axis)
     if active is None and weights is not None:
         active = tuple(range(n))
     if active is None:
@@ -101,7 +95,7 @@ def tree_allreduce(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """Recursive halving-doubling (the classic log-round tree allreduce;
     stands in for NCCL Tree): log2 N reduce-scatter + log2 N all-gather.
     After halving, node i owns segment i; doubling reassembles in order."""
-    n = _n(axis)
+    n = jax.lax.axis_size(axis)
     if n & (n - 1):
         return jax.lax.pmean(x, axis)
     i = jax.lax.axis_index(axis)
@@ -137,7 +131,7 @@ def bcube_allreduce(x: jnp.ndarray, axis: str, *, base: int = 4) -> jnp.ndarray:
     member reduces the part matching its digit; the all-gather phase
     mirrors the stages in reverse. base=2 == recursive halving-doubling.
     """
-    n = _n(axis)
+    n = jax.lax.axis_size(axis)
     k, m = 0, n
     while m > 1:
         if m % base:

@@ -25,14 +25,8 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.kernels.masked_sum import masked_mean as _masked_mean_kernel
 from repro.kernels.masked_sum import masked_mean_ref
-
-
-def axis_size(axis: str) -> int:
-    return compat.axis_size(axis)
 
 
 def pad_for_tar(x: jnp.ndarray, n: int, block: int = 1) -> tuple[jnp.ndarray, int]:
@@ -68,7 +62,7 @@ def tar_reduce_scatter(x: jnp.ndarray, axis: str, *,
     x: flat (L,), L % N == 0. mask: (N, S) — which peers' packets arrived
     at *this* receiver (row self is always 1; see drops.make_mask).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     s = x.shape[0] // n
     shards = x.reshape(n, s)
     received = jax.lax.all_to_all(shards, axis, split_axis=0, concat_axis=0,
@@ -139,7 +133,7 @@ def _grouped_rounds(axis: str, n: int, incast: int, send_for_round,
         live = [p for p in perm if p not in dead_pairs] if dead_pairs else perm
         send = send_for_round(r)
         if token is not None:           # gate on the previous group's recvs
-            send, token = compat.optimization_barrier((send, token))
+            send, token = jax.lax.optimization_barrier((send, token))
         recv = jax.lax.ppermute(send, axis, live)      # from (i - r) % n
         for (src, dst) in dead_pairs:
             m = relay_via(src, dst, participants
@@ -148,7 +142,7 @@ def _grouped_rounds(axis: str, n: int, incast: int, send_for_round,
             recv = recv + jax.lax.ppermute(mid, axis, [(m, dst)])
         pending.append(recv)
         if len(pending) == incast or r == n - 1:
-            pending = list(compat.optimization_barrier(tuple(pending)))
+            pending = list(jax.lax.optimization_barrier(tuple(pending)))
             rows.extend(pending)
             token = pending[-1]
             pending = []
@@ -307,7 +301,7 @@ def graft_inactive(full: jnp.ndarray, axis: str,
     zeros, so summing the rounds routes each peer exactly its copy), and a
     final select keeps active peers' locally-assembled bytes.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     ejected = [p for p in range(n) if p not in set(active)]
     if not ejected:
         return full
@@ -350,7 +344,7 @@ def tar_exchange_rounds(shards: jnp.ndarray, axis: str, *, incast: int = 1,
     ``dead_links`` reroutes any round traversing a failed directed edge
     through a two-hop relay (see :func:`_grouped_rounds`).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     incast = max(1, int(incast))
     if active is None:
         i = jax.lax.axis_index(axis)
@@ -394,7 +388,7 @@ def tar_broadcast_rounds(own: jnp.ndarray, axis: str, *, incast: int = 1,
     zero-padded (s_max,) row and the reassembly concatenates each
     position's valid slice (:func:`weighted_flat`) instead of reshaping.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     incast = max(1, int(incast))
     if active is None:
         i = jax.lax.axis_index(axis)
@@ -432,7 +426,7 @@ def tar_allreduce_rounds(x: jnp.ndarray, axis: str, *, incast: int = 1,
     (:func:`tar_exchange_rounds` / :func:`tar_broadcast_rounds`) so codecs
     can interpose; this wrapper is the plain value-domain form.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     s = x.shape[0] // n
     received = tar_exchange_rounds(x.reshape(n, s), axis, incast=incast)
     if mask is None:
@@ -453,7 +447,7 @@ def tar_allreduce_2d(x: jnp.ndarray, inner_axis: str, outer_axis: str, *,
     3. intra-group broadcast over ``inner_axis``            (N/G - 1 rounds)
     """
     own = tar_reduce_scatter(x, inner_axis, mask=mask, use_kernel=use_kernel)
-    g = axis_size(outer_axis)
+    g = jax.lax.axis_size(outer_axis)
     if g > 1:
         s = own.shape[0]
         if s % g == 0:

@@ -31,7 +31,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import runtime
 from repro.kernels.dma import SEQUENTIAL_GRID, col_loads, revolving_pipeline
 from repro.kernels.masked_sum.masked_sum import compensated_mean_cols
+from repro.kernels.quant.quant import from_codes
 
+# uint8 arrays in HBM are tiled four rows deep and a DMA slice must cover
+# whole tiles, so the peer axis of the codes is padded to this many rows
+CODE_ROW_TILE = 4
 
 def _slab_pipeline(nblk: int, streams, sem, epilogue):
     """Two-slot revolving-buffer schedule over column slabs (kernels/dma)."""
@@ -41,11 +45,11 @@ def _slab_pipeline(nblk: int, streams, sem, epilogue):
 
 def _dequant_masked_mean_kernel(c_hbm, lo_hbm, step_hbm, m_hbm, o_ref,
                                 cbuf, lobuf, stepbuf, mbuf, sem, *,
-                                nblk: int, tile: int):
+                                nblk: int, tile: int, n: int):
     def epilogue(slot):
-        x = cbuf[slot].astype(jnp.float32)          # (N, TILE)
+        x = from_codes(cbuf[slot])[:n]              # (N, TILE)
         x = x * stepbuf[slot] + lobuf[slot]         # grids broadcast over rows
-        m = mbuf[slot].astype(jnp.float32)          # (N, TILE)
+        m = mbuf[slot][:n].astype(jnp.float32)      # (N, TILE)
         out = compensated_mean_cols(x, m)
         o_ref[...] = out[None, :].astype(o_ref.dtype)
 
@@ -57,9 +61,10 @@ def _dequant_masked_mean_kernel(c_hbm, lo_hbm, step_hbm, m_hbm, o_ref,
 
 
 def _dequant_mean_kernel(c_hbm, lo_hbm, step_hbm, o_ref,
-                         cbuf, lobuf, stepbuf, sem, *, nblk: int, tile: int):
+                         cbuf, lobuf, stepbuf, sem, *, nblk: int, tile: int,
+                         n: int):
     def epilogue(slot):
-        x = cbuf[slot].astype(jnp.float32)
+        x = from_codes(cbuf[slot])[:n]
         x = x * stepbuf[slot] + lobuf[slot]
         o_ref[...] = jnp.mean(x, axis=0, keepdims=True).astype(o_ref.dtype)
 
@@ -97,34 +102,35 @@ def _dequant_masked_mean_call(codes: jnp.ndarray, lo_row: jnp.ndarray,
     n, length = codes.shape
     t = min(tile, length)
     pad = (-length) % t
+    rows = -(-n // CODE_ROW_TILE) * CODE_ROW_TILE   # pad rows: never read
     lo2 = lo_row.reshape(1, length).astype(jnp.float32)
     step2 = step_row.reshape(1, length).astype(jnp.float32)
-    if pad:
-        codes = jnp.pad(codes, ((0, 0), (0, pad)))
+    if pad or rows != n:
+        codes = jnp.pad(codes, ((0, rows - n), (0, pad)))
         lo2 = jnp.pad(lo2, ((0, 0), (0, pad)))
         step2 = jnp.pad(step2, ((0, 0), (0, pad)))
         if mask is not None:
-            mask = jnp.pad(mask, ((0, 0), (0, pad)))
+            mask = jnp.pad(mask, ((0, rows - n), (0, pad)))
     padded = codes.shape[1]
     nblk = padded // t
     col = pl.BlockSpec((1, t), lambda i: (0, i))
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)       # streamed manually
+    hbm = pl.BlockSpec(memory_space=pl.ANY)       # streamed manually
     grid_bufs = [pltpu.VMEM((2, 1, t), jnp.float32),
                  pltpu.VMEM((2, 1, t), jnp.float32)]
     if mask is None:
         kernel, args = _dequant_mean_kernel, (codes, lo2, step2)
         in_specs = [hbm, hbm, hbm]
-        scratch = [pltpu.VMEM((2, n, t), codes.dtype), *grid_bufs,
+        scratch = [pltpu.VMEM((2, rows, t), codes.dtype), *grid_bufs,
                    pltpu.SemaphoreType.DMA((3, 2))]
     else:
         kernel = _dequant_masked_mean_kernel
         args = (codes, lo2, step2, mask)
         in_specs = [hbm, hbm, hbm, hbm]
-        scratch = [pltpu.VMEM((2, n, t), codes.dtype), *grid_bufs,
-                   pltpu.VMEM((2, n, t), mask.dtype),
+        scratch = [pltpu.VMEM((2, rows, t), codes.dtype), *grid_bufs,
+                   pltpu.VMEM((2, rows, t), mask.dtype),
                    pltpu.SemaphoreType.DMA((4, 2))]
     out = pl.pallas_call(
-        functools.partial(kernel, nblk=nblk, tile=t),
+        functools.partial(kernel, nblk=nblk, tile=t, n=n),
         grid=(nblk,),
         in_specs=in_specs,
         out_specs=col,

@@ -18,7 +18,7 @@ from __future__ import annotations
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-SEQUENTIAL_GRID = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+SEQUENTIAL_GRID = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def row_loads(streams, sem, slot: int, idx):

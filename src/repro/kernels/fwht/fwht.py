@@ -1,16 +1,17 @@
-"""Pallas TPU kernel: blocked fast Walsh-Hadamard transform (MXU Kronecker form).
+"""Pallas TPU kernel: blocked fast Walsh-Hadamard transform (MXU, lane chunks).
 
 TPU adaptation of the paper's randomized Hadamard Transform (§3.3, implemented
 on GPU via HazyResearch's CUDA butterfly). A warp-shuffle butterfly does not
-map to the TPU; instead we exploit H_n = H_a (x) H_b so a length-n block,
-reshaped to (a, b), transforms as two dense matmuls ``H_a @ X @ H_b`` that run
-on the 128x128 MXU. For n = 16384 both factors are exactly 128x128.
+map to the TPU; instead we exploit H_n = H_c (x) H_l with l = 128 lanes: each
+128-lane chunk of a row block is one (rows, 128) @ (128, 128) matmul on the
+MXU, and H_c is a butterfly of whole-vreg adds across the c = n/128 chunks.
+Every slice is lane-aligned, so Mosaic never has to split the lane axis.
 
 Grid: one program per tile of ``block_rows`` rows; each program holds
-(block_rows, n) of the input plus the two factor matrices in VMEM.
+(block_rows, n) of the input plus the (l, l) factor matrix in VMEM.
 
-VMEM budget per program (fp32): block_rows*n*4*2 (in+out) + (a^2+b^2)*4,
-e.g. block_rows=128, n=4096 -> 4.2 MB, well within ~16 MB VMEM.
+VMEM budget per program (fp32): block_rows*n*4*2 (in+out) + l^2*4,
+e.g. block_rows=64, n=4096 -> 2.1 MB, well within ~16 MB VMEM.
 """
 from __future__ import annotations
 
@@ -23,45 +24,61 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import runtime
 
-from .ref import hadamard_matrix, split_factors
+from .ref import chunk_butterfly, fwht_scale, hadamard_matrix, lane_factors
 
 # one program per independent row tile: Mosaic may run grid iterations in
 # any order / in parallel windows (no cross-iteration scratch state)
-_ROW_GRID = pltpu.TPUCompilerParams(dimension_semantics=("parallel",))
+_ROW_GRID = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
-def mxu_rotate_block(x, ha, hb, rows: int, a: int, b: int):
+def mxu_rotate_block(x, hl):
     """The blocked-FWHT body shared by every kernel that rotates: (rows, n)
-    fp32 -> (rows, n) via the two Kronecker-factor MXU matmuls. The fused
-    ht_quant kernels reuse this so there is exactly one copy of the
-    rotation math on the Pallas side."""
-    x3 = x.reshape(rows, a, b)
-    t = jax.lax.dot_general(
-        x3, hb, (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (rows, a, b)
-    # y[r, i, k] = sum_j Ha[i, j] t[r, j, k]
-    y = jax.lax.dot_general(
-        t, ha, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (rows, b, a)
-    return y.transpose(0, 2, 1).reshape(rows, a * b)
+    fp32 -> the c (rows, l) lane chunks of the orthonormal transform. The
+    fused ht_quant kernels reuse this so there is exactly one copy of the
+    rotation math on the Pallas side (``fwht_mxu_ref`` runs the same ops)."""
+    n = x.shape[1]
+    lanes = hl.shape[0]
+    t = [jax.lax.dot(x[:, j * lanes:(j + 1) * lanes], hl,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+         for j in range(n // lanes)]
+    scale = fwht_scale(n)
+    return [tj * scale for tj in chunk_butterfly(t)]
 
 
-def _fwht_kernel(x_ref, ha_ref, hb_ref, o_ref, *, rows: int, a: int, b: int):
+def row_block(rows: int, block_rows: int) -> int:
+    """Rows per program for the rotating kernels: ``block_rows``, or all the
+    rows rounded up to whole 8-row sublane tiles. Every rotation matmul then
+    has M >= 8, so no row count sends it down a matrix-vector path whose
+    summation order differs (prefix invariance and jnp parity stay bitwise).
+    """
+    return min(block_rows, -(-rows // 8) * 8)
+
+
+def store_chunks(o_ref, chunks):
+    """Write lane chunks back into their column slices of ``o_ref``."""
+    for j, yj in enumerate(chunks):
+        lanes = yj.shape[1]
+        o_ref[:, j * lanes:(j + 1) * lanes] = yj.astype(o_ref.dtype)
+
+
+def _fwht_kernel(x_ref, hl_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)  # (rows, n)
-    y = mxu_rotate_block(x, ha_ref[...], hb_ref[...], rows, a, b)
-    o_ref[...] = y.astype(o_ref.dtype)
+    store_chunks(o_ref, mxu_rotate_block(x, hl_ref[...]))
 
 
-def _fwht_sign_kernel(x_ref, sign_ref, ha_ref, hb_ref, o_ref, *, rows: int,
-                      a: int, b: int, sign_mode: str):
+def _fwht_sign_kernel(x_ref, sign_ref, hl_ref, o_ref, *, sign_mode: str):
     x = x_ref[...].astype(jnp.float32)
-    sign = sign_ref[...].astype(jnp.float32)         # (1, n)
     if sign_mode == "pre":
-        x = x * sign
-    y = mxu_rotate_block(x, ha_ref[...], hb_ref[...], rows, a, b)
+        x = x * sign_ref[...].astype(jnp.float32)    # (1, n)
+    chunks = mxu_rotate_block(x, hl_ref[...])
     if sign_mode == "post":
-        y = y * sign
-    o_ref[...] = y.astype(o_ref.dtype)
+        # per-chunk loads of the sign row: slicing a loaded (1, n) value
+        # gives a layout Mosaic cannot broadcast
+        lanes = chunks[0].shape[1]
+        chunks = [yj * sign_ref[:, j * lanes:(j + 1) * lanes].astype(
+                      jnp.float32) for j, yj in enumerate(chunks)]
+    store_chunks(o_ref, chunks)
 
 
 def fwht_pallas(x: jnp.ndarray,
@@ -95,37 +112,32 @@ def _fwht_call(x: jnp.ndarray,
     if x.ndim != 2:
         raise ValueError("fwht_pallas expects (rows, n)")
     rows, n = x.shape
-    a, b = split_factors(n)
-    # Fold the orthonormal 1/sqrt(n) into the factor matrices.
-    ha = hadamard_matrix(a)      # 1/sqrt(a)
-    hb = hadamard_matrix(b)      # 1/sqrt(b); product gives 1/sqrt(n)
+    _, lanes = lane_factors(n)
+    hl = hadamard_matrix(lanes, orthonormal=False)   # +-1; 1/sqrt(n) after
 
-    br = min(block_rows, rows)
+    br = row_block(rows, block_rows)
     pad = (-rows) % br
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     grid = (x.shape[0] // br,)
 
     if sign_mode == "none":
-        kernel = functools.partial(_fwht_kernel, rows=br, a=a, b=b)
+        kernel = _fwht_kernel
         in_specs = [
             pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((a, a), lambda i: (0, 0)),
-            pl.BlockSpec((b, b), lambda i: (0, 0)),
+            pl.BlockSpec((lanes, lanes), lambda i: (0, 0)),
         ]
-        args = (x, ha, hb)
+        args = (x, hl)
     else:
         if sign is None:
             raise ValueError("sign required for sign_mode != 'none'")
-        kernel = functools.partial(_fwht_sign_kernel, rows=br, a=a, b=b,
-                                   sign_mode=sign_mode)
+        kernel = functools.partial(_fwht_sign_kernel, sign_mode=sign_mode)
         in_specs = [
             pl.BlockSpec((br, n), lambda i: (i, 0)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((a, a), lambda i: (0, 0)),
-            pl.BlockSpec((b, b), lambda i: (0, 0)),
+            pl.BlockSpec((lanes, lanes), lambda i: (0, 0)),
         ]
-        args = (x, sign.reshape(1, n).astype(jnp.float32), ha, hb)
+        args = (x, sign.reshape(1, n).astype(jnp.float32), hl)
 
     out = pl.pallas_call(
         kernel,

@@ -1,7 +1,7 @@
 """Jit'd public wrappers for the FWHT kernel.
 
 ``fwht(x)`` operates on the last axis (any leading shape); the Pallas kernel
-is used when requested, the Kronecker jnp form otherwise (identical math, so
+is used when requested, the lane-chunk jnp form otherwise (identical math, so
 the dry-run HLO carries the kernel's FLOP structure).  Whether the Pallas
 path runs interpreted or Mosaic-compiled resolves through the process
 kernel-mode policy (kernels/runtime) outside the jit boundary, so the
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.kernels import runtime
 
 from .fwht import fwht_pallas
-from .ref import fwht_mxu_ref, split_factors  # noqa: F401 (re-export)
+from .ref import fwht_mxu_ref
 
 
 @functools.partial(jax.jit,

@@ -3,11 +3,10 @@
 Two reference implementations:
 
 * ``fwht_ref``      — classic O(n log n) butterfly, the ground-truth oracle.
-* ``fwht_mxu_ref``  — the Kronecker/MXU formulation (H_n = H_a (x) H_b, so the
-  transform of a length-n block is two dense matmuls on a (a, b) reshape).
-  This is the *same math the Pallas kernel implements*; it is what the
-  distributed train_step uses under jit on non-TPU backends so that the
-  dry-run HLO carries the kernel's true FLOP structure.
+* ``fwht_mxu_ref``  — the lane-chunk MXU formulation (H_n = H_c (x) H_l with
+  l = 128 lanes: one (l, l) matmul per lane chunk, then a butterfly across
+  the c chunks). These are the *same ops the Pallas kernel runs*, so the two
+  agree bitwise off-chip; it is the sync engine's jnp codec path.
 
 Both are orthonormal: ``fwht(fwht(x)) == x``.
 """
@@ -15,8 +14,11 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+LANES = 128
 
 
 def _log2(n: int) -> int:
@@ -43,12 +45,17 @@ def hadamard_matrix(n: int, *, orthonormal: bool = True) -> jnp.ndarray:
     return jnp.asarray(h)
 
 
-def split_factors(n: int) -> tuple[int, int]:
-    """n = a * b with a, b powers of two and a >= b (a = 2^ceil(k/2))."""
-    k = _log2(n)
-    a = 1 << ((k + 1) // 2)
-    b = 1 << (k // 2)
-    return a, b
+def lane_factors(n: int) -> tuple[int, int]:
+    """n = c * l: l = min(n, 128) lanes per chunk, c chunks."""
+    _log2(n)
+    lanes = min(n, LANES)
+    return n // lanes, lanes
+
+
+def fwht_scale(n: int) -> np.float32:
+    """The orthonormal 1/sqrt(n), applied once after the unnormalized
+    transform (the +-1 factor matrix is exact in every MXU input dtype)."""
+    return np.float32(1.0 / np.sqrt(n))
 
 
 def fwht_ref(x: jnp.ndarray) -> jnp.ndarray:
@@ -69,21 +76,41 @@ def fwht_ref(x: jnp.ndarray) -> jnp.ndarray:
     return y.reshape(orig_shape).astype(orig_dtype)
 
 
-def fwht_mxu_ref(x: jnp.ndarray) -> jnp.ndarray:
-    """Orthonormal FWHT over the last axis, Kronecker-factored (MXU form).
+def chunk_butterfly(t: list) -> list:
+    """In-place-order butterfly applying the unnormalized H_c across a list
+    of c equally shaped chunks (the kernel's cross-chunk stage)."""
+    c = len(t)
+    h = 1
+    while h < c:
+        for i in range(0, c, 2 * h):
+            for k in range(i, i + h):
+                t[k], t[k + h] = t[k] + t[k + h], t[k] - t[k + h]
+        h *= 2
+    return t
 
-    H_n = H_a (x) H_b (Sylvester ordering: index i*b + j), hence for a block
-    reshaped to X[a, b]:  Y = H_a @ X @ H_b.
+
+def fwht_mxu_ref(x: jnp.ndarray) -> jnp.ndarray:
+    """Orthonormal FWHT over the last axis, in the kernel's lane-chunk form.
+
+    H_n = H_c (x) H_l (Sylvester ordering: index j*l + k). Each 128-lane
+    chunk is multiplied by the +-1 H_l on the MXU, a butterfly across the
+    chunks applies H_c, and one 1/sqrt(n) scale makes it orthonormal.
     """
     orig_shape = x.shape
     orig_dtype = x.dtype
     n = orig_shape[-1]
-    a, b = split_factors(n)
-    ha = hadamard_matrix(a)
-    hb = hadamard_matrix(b)
-    xr = x.astype(jnp.float32).reshape(-1, a, b)
-    t = jnp.einsum("rjl,lk->rjk", xr, hb, preferred_element_type=jnp.float32)
-    y = jnp.einsum("ij,rjk->rik", ha, t, preferred_element_type=jnp.float32)
+    c, lanes = lane_factors(n)
+    hl = hadamard_matrix(lanes, orthonormal=False)
+    t = jnp.einsum("rjl,lk->rjk", x.astype(jnp.float32).reshape(-1, c, lanes),
+                   hl, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    h = 1
+    while h < c:                     # chunk_butterfly, vectorized over pairs
+        t = t.reshape(-1, c // (2 * h), 2, h, lanes)
+        a, b = t[:, :, 0], t[:, :, 1]
+        t = jnp.stack([a + b, a - b], axis=2).reshape(-1, c, lanes)
+        h *= 2
+    y = t * fwht_scale(n)
     return y.reshape(orig_shape).astype(orig_dtype)
 
 

@@ -23,7 +23,8 @@ block.  One shared pipeline body (``kernels/dma.py``) carries the DMA
 schedule for both kernels; the per-kernel difference is only the epilogue
 consuming the rotated block (amax-reduce vs quantize), so there is exactly
 one copy of the revolving-buffer logic and of the rotation math
-(``mxu_rotate_block``) on the Pallas side.
+(``mxu_rotate_block``, which yields the rotation as 128-lane chunks) on the
+Pallas side.
 
 The grids arrive as per-row (= per-Hadamard-block) ``lo``/``step`` operands
 because THC needs them pmax-shared across workers *between* the amax and the
@@ -31,8 +32,8 @@ quantization — that collective now rides the pipelined schedule's exchange
 stage (core/pipeline.py) instead of splitting the encode.
 
 VMEM per program (fp32, block_rows=64, n=4096): two x slots (2 MB) + two
-noise slots (2 MB, ht_quant only) + factors + the pipelined output block —
-~5 MB, well within ~16 MB VMEM.
+noise slots (2 MB, ht_quant only) + the factor matrix + the pipelined
+output block — ~5 MB, well within ~16 MB VMEM.
 """
 from __future__ import annotations
 
@@ -45,14 +46,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import runtime
 from repro.kernels.dma import SEQUENTIAL_GRID, revolving_pipeline, row_loads
-from repro.kernels.fwht.fwht import mxu_rotate_block
-from repro.kernels.fwht.ref import hadamard_matrix, split_factors
+from repro.kernels.fwht.fwht import mxu_rotate_block, row_block
+from repro.kernels.fwht.ref import hadamard_matrix, lane_factors
+from repro.kernels.quant.quant import to_codes
 
 
-def _rotate(x, sign, ha, hb, rows: int, a: int, b: int):
-    """sign-flip + blocked FWHT of (rows, n), sharing the fwht kernel's
-    rotation body (single copy of the MXU math on the Pallas side)."""
-    return mxu_rotate_block(x.astype(jnp.float32) * sign, ha, hb, rows, a, b)
+def _rotate(x, sign, hl):
+    """sign-flip + blocked FWHT of (rows, n) as 128-lane chunks, sharing the
+    fwht kernel's rotation body (single copy of the MXU math on the Pallas
+    side)."""
+    return mxu_rotate_block(x.astype(jnp.float32) * sign, hl)
 
 
 def _rotation_pipeline(nblk: int, streams, sem, epilogue):
@@ -61,38 +64,40 @@ def _rotation_pipeline(nblk: int, streams, sem, epilogue):
         nblk, functools.partial(row_loads, streams, sem), epilogue)
 
 
-def _ht_amax_kernel(x_hbm, sign_ref, ha_ref, hb_ref, o_ref, xbuf, sem, *,
-                    nblk: int, rows: int, a: int, b: int):
+def _ht_amax_kernel(x_hbm, sign_ref, hl_ref, o_ref, xbuf, sem, *,
+                    nblk: int, rows: int):
     def epilogue(slot):
-        y = _rotate(xbuf[slot], sign_ref[...].astype(jnp.float32),
-                    ha_ref[...], hb_ref[...], rows, a, b)
-        o_ref[...] = jnp.max(jnp.abs(y), axis=1, keepdims=True)
+        chunks = _rotate(xbuf[slot], sign_ref[...].astype(jnp.float32),
+                         hl_ref[...])
+        amax = [jnp.max(jnp.abs(yj), axis=1, keepdims=True) for yj in chunks]
+        o_ref[...] = functools.reduce(jnp.maximum, amax)
 
     _rotation_pipeline(nblk, [(x_hbm, xbuf, rows)], sem, epilogue)
 
 
-def _ht_quant_kernel(x_hbm, sign_ref, noise_hbm, lo_hbm, step_hbm,
-                     ha_ref, hb_ref, o_ref, xbuf, nbuf, lobuf, stepbuf, sem,
-                     *, nblk: int, rows: int, a: int, b: int, levels: int):
+def _ht_quant_kernel(x_hbm, sign_ref, noise_hbm, lo_ref, step_ref,
+                     hl_ref, o_ref, xbuf, nbuf, sem,
+                     *, nblk: int, rows: int, levels: int):
     def epilogue(slot):
-        y = _rotate(xbuf[slot], sign_ref[...].astype(jnp.float32),
-                    ha_ref[...], hb_ref[...], rows, a, b)
-        u = nbuf[slot].astype(jnp.float32)
-        lo = lobuf[slot]                             # (rows, 1)
-        step = stepbuf[slot]                         # (rows, 1)
-        q = jnp.floor((y - lo) / step + u)
-        o_ref[...] = jnp.clip(q, 0, levels).astype(o_ref.dtype)
+        chunks = _rotate(xbuf[slot], sign_ref[...].astype(jnp.float32),
+                         hl_ref[...])
+        lo = lo_ref[...]                             # (rows, 1)
+        step = step_ref[...]                         # (rows, 1)
+        lanes = chunks[0].shape[1]
+        for j, yj in enumerate(chunks):
+            cols = slice(j * lanes, (j + 1) * lanes)
+            u = nbuf[slot, :, cols].astype(jnp.float32)
+            q = jnp.floor((yj - lo) / step + u)
+            o_ref[:, cols] = to_codes(jnp.clip(q, 0, levels), o_ref.dtype)
 
     _rotation_pipeline(
-        nblk,
-        [(x_hbm, xbuf, rows), (noise_hbm, nbuf, rows),
-         (lo_hbm, lobuf, rows), (step_hbm, stepbuf, rows)],
-        sem, epilogue)
+        nblk, [(x_hbm, xbuf, rows), (noise_hbm, nbuf, rows)], sem, epilogue)
 
 
-def _factors(n: int):
-    a, b = split_factors(n)
-    return a, b, hadamard_matrix(a), hadamard_matrix(b)
+def _factor(n: int):
+    """The +-1 (l, l) lane factor of H_n (the kernels scale by 1/sqrt(n))."""
+    _, lanes = lane_factors(n)
+    return lanes, hadamard_matrix(lanes, orthonormal=False)
 
 
 def ht_amax_pallas(x: jnp.ndarray, sign: jnp.ndarray, *,
@@ -114,20 +119,19 @@ def _ht_amax_call(x: jnp.ndarray, sign: jnp.ndarray, *,
     if x.ndim != 2:
         raise ValueError("ht_amax_pallas expects (rows, n)")
     rows, n = x.shape
-    a, b, ha, hb = _factors(n)
-    br = min(block_rows, rows)
+    lanes, hl = _factor(n)
+    br = row_block(rows, block_rows)
     pad = (-rows) % br
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     nblk = x.shape[0] // br
     out = pl.pallas_call(
-        functools.partial(_ht_amax_kernel, nblk=nblk, rows=br, a=a, b=b),
+        functools.partial(_ht_amax_kernel, nblk=nblk, rows=br),
         grid=(nblk,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # x: streamed manually
+            pl.BlockSpec(memory_space=pl.ANY),    # x: streamed manually
             pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((a, a), lambda i: (0, 0)),
-            pl.BlockSpec((b, b), lambda i: (0, 0)),
+            pl.BlockSpec((lanes, lanes), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32),
@@ -135,7 +139,7 @@ def _ht_amax_call(x: jnp.ndarray, sign: jnp.ndarray, *,
                         pltpu.SemaphoreType.DMA((1, 2))],
         compiler_params=SEQUENTIAL_GRID,
         interpret=interpret,
-    )(x, sign.reshape(1, n).astype(jnp.float32), ha, hb)
+    )(x, sign.reshape(1, n).astype(jnp.float32), hl)
     return out[:rows, 0]
 
 
@@ -164,9 +168,9 @@ def _ht_quant_call(x: jnp.ndarray, sign: jnp.ndarray, noise: jnp.ndarray,
     if x.ndim != 2 or noise.shape != x.shape:
         raise ValueError("x and noise must both be (rows, n)")
     rows, n = x.shape
-    a, b, ha, hb = _factors(n)
+    lanes, hl = _factor(n)
     levels = (1 << bits) - 1
-    br = min(block_rows, rows)
+    br = row_block(rows, block_rows)
     pad = (-rows) % br
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -175,28 +179,27 @@ def _ht_quant_call(x: jnp.ndarray, sign: jnp.ndarray, noise: jnp.ndarray,
         step = jnp.pad(step.reshape(-1), (0, pad), constant_values=1.0)
     nblk = x.shape[0] // br
     out = pl.pallas_call(
-        functools.partial(_ht_quant_kernel, nblk=nblk, rows=br, a=a, b=b,
+        functools.partial(_ht_quant_kernel, nblk=nblk, rows=br,
                           levels=levels),
         grid=(nblk,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # x: streamed manually
+            pl.BlockSpec(memory_space=pl.ANY),    # x: streamed manually
             pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),    # noise: streamed
-            pl.BlockSpec(memory_space=pltpu.ANY),    # lo: streamed
-            pl.BlockSpec(memory_space=pltpu.ANY),    # step: streamed
-            pl.BlockSpec((a, a), lambda i: (0, 0)),
-            pl.BlockSpec((b, b), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),    # noise: streamed
+            # the (rows, 1) grids ride Pallas' own block pipeline: a manual
+            # one-lane DMA slice is not tile-aligned for Mosaic
+            pl.BlockSpec((br, 1), lambda i: (i, 0)),
+            pl.BlockSpec((br, 1), lambda i: (i, 0)),
+            pl.BlockSpec((lanes, lanes), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint8),
         scratch_shapes=[pltpu.VMEM((2, br, n), x.dtype),
                         pltpu.VMEM((2, br, n), noise.dtype),
-                        pltpu.VMEM((2, br, 1), jnp.float32),
-                        pltpu.VMEM((2, br, 1), jnp.float32),
-                        pltpu.SemaphoreType.DMA((4, 2))],
+                        pltpu.SemaphoreType.DMA((2, 2))],
         compiler_params=SEQUENTIAL_GRID,
         interpret=interpret,
     )(x, sign.reshape(1, n).astype(jnp.float32), noise,
       lo.reshape(-1, 1).astype(jnp.float32),
-      step.reshape(-1, 1).astype(jnp.float32), ha, hb)
+      step.reshape(-1, 1).astype(jnp.float32), hl)
     return out[:rows]

@@ -21,7 +21,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import runtime
 
 # independent column tiles: no cross-iteration state, Mosaic may parallelize
-_COL_GRID = pltpu.TPUCompilerParams(dimension_semantics=("parallel",))
+_COL_GRID = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def compensated_mean_cols(x, m):

@@ -19,7 +19,20 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import runtime
 
 # independent row tiles: no cross-iteration state, Mosaic may parallelize
-_ROW_GRID = pltpu.TPUCompilerParams(dimension_semantics=("parallel",))
+_ROW_GRID = pltpu.CompilerParams(dimension_semantics=("parallel",))
+
+
+def to_codes(q, dtype):
+    """Integral fp32 levels -> integer codes of ``dtype`` inside a kernel.
+    Mosaic has no direct float -> uint8 cast, so the codes pass through
+    int32 (exact: every level fits)."""
+    return q.astype(jnp.int32).astype(dtype)
+
+
+def from_codes(c):
+    """Integer codes -> fp32 inside a kernel, through int32 (Mosaic has no
+    direct uint8 -> float cast)."""
+    return c.astype(jnp.int32).astype(jnp.float32)
 
 
 def _quant_kernel(x_ref, n_ref, r_ref, o_ref, *, levels: int):
@@ -29,7 +42,7 @@ def _quant_kernel(x_ref, n_ref, r_ref, o_ref, *, levels: int):
     hi = r_ref[0, 1]
     step = (hi - lo) / levels
     q = jnp.floor((x - lo) / step + u)
-    o_ref[...] = jnp.clip(q, 0, levels).astype(o_ref.dtype)
+    o_ref[...] = to_codes(jnp.clip(q, 0, levels), o_ref.dtype)
 
 
 def _grid_quant_kernel(x_ref, n_ref, lo_ref, step_ref, o_ref, *, levels: int):
@@ -38,7 +51,7 @@ def _grid_quant_kernel(x_ref, n_ref, lo_ref, step_ref, o_ref, *, levels: int):
     lo = lo_ref[...]                                   # (rows, 1) per-row
     step = step_ref[...]
     q = jnp.floor((x - lo) / step + u)
-    o_ref[...] = jnp.clip(q, 0, levels).astype(o_ref.dtype)
+    o_ref[...] = to_codes(jnp.clip(q, 0, levels), o_ref.dtype)
 
 
 def grid_quant_pallas(x: jnp.ndarray, noise: jnp.ndarray, lo: jnp.ndarray,

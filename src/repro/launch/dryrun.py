@@ -33,8 +33,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.configs import ARCHS, SHAPES, get_config, input_specs, shape_applicable
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.allreduce import OptiReduceConfig
@@ -251,7 +249,7 @@ def make_prefill_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
         else:
             st_specs.append(SSMState(conv=P(None, b_ax, None, "model"),
                                      ssm=P(None, b_ax, "model", None, None)))
-    fn = compat.shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(p_specs, batch_spec, P()),
                        out_specs=(P(b_ax, None), st_specs),
                        check_vma=False)

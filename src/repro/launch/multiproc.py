@@ -253,8 +253,11 @@ def _thread_crash(client) -> None:
 
 def _worker_main(args) -> int:
     """``--worker`` subprocess entry (udp backend only)."""
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.net import RendezvousClient, UdpProcessBackend, \
         bernoulli_drops
+
+    enable_compile_cache()
 
     drop_fn = bernoulli_drops(args.drop_rate, seed=args.drop_seed) \
         if args.drop_rate > 0 else None
@@ -277,6 +280,14 @@ def _worker_main(args) -> int:
 # ----------------------------------------------------------------- parent
 def _spawn(args, uid: int, rendezvous: str, report_file: str
            ) -> subprocess.Popen:
+    """Start one worker process.
+
+    Each worker models one host's host-side codec, so it runs JAX on the
+    CPU (``JAX_PLATFORMS=cpu``): a chip belongs to one process, and on a
+    chip host the first worker to reach for it would take it and the rest
+    would fail on the TPU library's lock. The parent itself never
+    initialises a JAX backend.
+    """
     cmd = [sys.executable, "-m", "repro.launch.multiproc", "--worker",
            "--uid", str(uid), "--rendezvous", rendezvous,
            "--report-file", report_file,
@@ -297,7 +308,7 @@ def _spawn(args, uid: int, rendezvous: str, report_file: str
         cmd += ["--ckpt-dir", args.ckpt_dir]
     if args.trace_dir:
         cmd += ["--trace-dir", args.trace_dir]
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     # make `python -m repro.launch.multiproc` resolvable in the child even
     # when the parent found `repro` via a sys.path edit (demo scripts)
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(

@@ -1,9 +1,11 @@
 """Training launcher: end-to-end training with OptiReduce gradient sync.
 
-On this CPU container it drives reduced (smoke) configs over a host-device
-mesh; on a real cluster the same entrypoint runs the full configs over the
-production mesh (jax.distributed handles multi-host initialization — the
-launcher is host-count agnostic).
+With ``--smoke`` it drives a reduced same-family config (CPU tests and
+examples); without it, the full published config, e.g. gpt2-paper on one
+TPU v5e chip or 4-way replicated DP on a 2x2 host (``chip_smoke.py`` drives
+both). ``main`` returns the per-step losses and wall times so an in-process
+caller can check the run. Compiles go to the persistent cache chosen by
+``launch/compile_cache.py``.
 
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch gpt2-paper --smoke \\
@@ -14,7 +16,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import os
-import sys
 import time
 
 import jax
@@ -25,6 +26,7 @@ from repro.configs import SHAPES, get_config, get_smoke
 from repro.core.allreduce import OptiReduceConfig, strategies
 from repro.core.safeguards import LossMonitor
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import init_params
 from repro.optim.optimizers import OptimizerConfig
@@ -32,11 +34,17 @@ from repro.train import checkpoint as ckpt_lib
 from repro.train.trainer import TrainConfig, build_train_step
 
 
-def main(argv=None) -> int:
+def main(argv=None) -> dict:
+    """Train; returns per-step ``losses`` and wall seconds ``step_times``
+    (step 0 includes the compile), the jitted ``step`` with its final
+    ``params``, ``opt_state`` and last ``batch``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2-paper")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the config's depth to this many layers "
+                         "(widths unchanged)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
@@ -125,6 +133,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracer = None
     if args.trace is not None:
@@ -133,6 +142,8 @@ def main(argv=None) -> int:
             True, capacity=args.trace_capacity or obs.trace.DEFAULT_CAPACITY)
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    if args.n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
     if args.production_mesh:
         mesh = make_production_mesh()
     else:
@@ -332,6 +343,8 @@ def main(argv=None) -> int:
         step_cache.put(policy_of(tc.sync), (jf, shardings))
         stable_rec, stable_for = None, 0
     report_rows: list[dict] = []
+    losses: list[float] = []
+    step_times: list[float] = []
     t0 = time.time()
     try:
         for step in range(start_step, args.steps):
@@ -348,6 +361,8 @@ def main(argv=None) -> int:
                     params, opt_state, batch, jnp.asarray(step, jnp.int32),
                     key)
             loss_frac = float(metrics["loss_frac"])
+            losses.append(float(metrics["loss"]))
+            step_times.append(time.time() - t_step)
             if with_budget:
                 # phase-aware budget (DESIGN §8): the phase follows the LR
                 # schedule's progress and the observed loss curve; the EMA
@@ -503,8 +518,9 @@ def main(argv=None) -> int:
         print(f"trace: {len(tracer)} records ({tracer.dropped} dropped) "
               f"-> {path}", flush=True)
     print("done")
-    return 0
+    return {"losses": losses, "step_times": step_times, "step": jf,
+            "params": params, "opt_state": opt_state, "batch": batch}
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    main()

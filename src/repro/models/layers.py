@@ -13,8 +13,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from .parallel import ParallelCtx
 
 
@@ -225,7 +223,7 @@ def attention_decode(x: jnp.ndarray, w: dict, cache: KVCache,
                 else tuple(seq_shard_axis))
         shard = jnp.zeros((), jnp.int32)
         for ax in axes:
-            shard = shard * compat.axis_size(ax) + jax.lax.axis_index(ax)
+            shard = shard * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         start = shard * s_local
         local_pos = jnp.clip(pos - start, 0, s_local - 1)
         owns = (pos >= start) & (pos < start + s_local)

@@ -19,8 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 @dataclasses.dataclass(frozen=True)
 class ParallelCtx:
@@ -66,7 +64,7 @@ class ParallelCtx:
         """Linear index over (pod, data) — matches P(('pod','data'))."""
         idx = jnp.zeros((), jnp.int32)
         for ax in self.dp_axes():
-            idx = idx * compat.axis_size(ax) + jax.lax.axis_index(ax)
+            idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         return idx
 
     def psum_dp(self, x):
@@ -74,12 +72,12 @@ class ParallelCtx:
         return jax.lax.psum(x, axes) if axes else x
 
     def tp_size(self) -> int:
-        return compat.axis_size(self.tp_axis) if self.tp_axis else 1
+        return jax.lax.axis_size(self.tp_axis) if self.tp_axis else 1
 
     def dp_size(self) -> int:
-        n = compat.axis_size(self.dp_axis) if self.dp_axis else 1
+        n = jax.lax.axis_size(self.dp_axis) if self.dp_axis else 1
         if self.pod_axis:
-            n *= compat.axis_size(self.pod_axis)
+            n *= jax.lax.axis_size(self.pod_axis)
         return n
 
     def tp_index(self) -> jnp.ndarray:

@@ -29,7 +29,6 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -459,7 +458,7 @@ def forward_hidden(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,
             # too — their layout copies don't depend on xc and would be
             # hoisted) XLA's latency-oriented scheduler overlaps several
             # layers' temporaries (jamba prefill measured 55 GiB/dev)
-            xc, lw = compat.optimization_barrier((xc, stage_params[pos]))
+            xc, lw = jax.lax.optimization_barrier((xc, stage_params[pos]))
             lkey = jax.random.fold_in(key, idx * period + pos)
             xc, st = _apply_layer(xc, lw, cfg, lay, pctx, pos,
                                   positions=positions, key=lkey,
@@ -635,7 +634,7 @@ def decode_step(params, state, tokens, pos, cfg: ModelConfig,
         new_states = []
         for p_ in range(period):
             # see forward_hidden: barrier weights + activations per layer
-            xc, lw = compat.optimization_barrier((xc, stage_params[p_]))
+            xc, lw = jax.lax.optimization_barrier((xc, stage_params[p_]))
             lkey = jax.random.fold_in(key, idx * period + p_)
             xc, ns = _apply_layer(
                 xc, lw, cfg, lay, pctx, p_, positions=None,

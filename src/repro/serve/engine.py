@@ -20,7 +20,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -174,7 +173,7 @@ def build_serve_step(cfg: ModelConfig, mesh, plan: ServePlan, *,
 
     def make(abstract_st):
         st_specs = s_specs(abstract_st)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, st_specs, tok_spec, P(), P()),
             out_specs=(tok_spec, st_specs),

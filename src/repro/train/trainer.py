@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.allreduce import (OptiReduceConfig, SyncContext, rs_spec,
                                   sync_packed, sync_pytree)
@@ -470,7 +469,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
                 rec_specs["ef"] = P(data_axis)
             in_specs = (p_specs, o_specs, rec_specs, batch_spec, P(), P())
             out_specs = (p_specs, o_specs, rec_specs, metric_specs)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False)
         shardings = {

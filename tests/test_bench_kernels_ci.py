@@ -33,7 +33,7 @@ _DEVICE_FAMILIES = (
 
 @pytest.mark.slow
 def test_bench_kernels_quick_schema(tmp_path):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # schema run, never the chip
     env.pop("XLA_FLAGS", None)
     env.pop("REPRO_KERNEL_MODE", None)   # the bench scopes its own modes
     src = os.path.join(_REPO, "src")

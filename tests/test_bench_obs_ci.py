@@ -30,7 +30,7 @@ _MEDIANS = (
 
 @pytest.mark.slow
 def test_bench_obs_quick_schema(tmp_path):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # schema run, never the chip
     env.pop("XLA_FLAGS", None)
     env.pop("REPRO_TRACE", None)          # the bench manages tracing itself
     src = os.path.join(_REPO, "src")

@@ -20,7 +20,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.mark.slow
 def test_bench_pipeline_quick_schema(tmp_path):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # schema run, never the chip
     env.pop("XLA_FLAGS", None)
     src = os.path.join(_REPO, "src")
     env["PYTHONPATH"] = os.pathsep.join(

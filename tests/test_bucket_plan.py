@@ -13,7 +13,7 @@ import pytest
 
 from repro.core import (BucketPlan, OptiReduceConfig, SyncContext,
                         sync_pytree, sync_pytree_unfused)
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 
@@ -60,15 +60,15 @@ def test_pack_unpack_roundtrip_preserves_dtype():
 
 def _sync(fn, tree, cfg, bucket_elems, **kw):
     """Run a sync function under a dp=1 shard_map (single device)."""
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     spec = jax.tree.map(lambda _: P(), tree)
 
     def body(t):
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
         return fn(t, ctx, bucket_elems=bucket_elems, **kw)
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                          check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                              out_specs=spec, check_vma=False))
     return f, f(tree)
 
 
@@ -200,11 +200,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import OptiReduceConfig, SyncContext, sync_pytree, \
     sync_pytree_unfused
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 tree = {"w": jax.random.normal(key, (4, 1024)),
         "b": jax.random.normal(jax.random.fold_in(key, 1), (2048,)),
@@ -216,7 +216,7 @@ def run(fn, cfg):
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
         out = fn(t, ctx, bucket_elems=1024)
         return out, ctx.loss_fraction()
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
                           out_specs=(spec, P()), check_vma=False))
     return f(tree)
 
@@ -237,7 +237,7 @@ for strat, dr, uk in (("optireduce", 0.1, False), ("optireduce", 0.1, True),
 
 @pytest.mark.slow
 def test_bucket_plan_multidevice_bitwise():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # virtual CPU devices
     env.pop("XLA_FLAGS", None)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -15,11 +15,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import OptiReduceConfig, SyncContext, sync_bucket
 from repro.core.allreduce import reduce_scatter_axis
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 L = 10_000
 key = jax.random.PRNGKey(0)
 xs = jax.random.normal(key, (8, L), jnp.float32)
@@ -31,7 +31,7 @@ def run(strategy, drop_rate=0.0, block=1024):
     def body(x):
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(42))
         return sync_bucket(x.reshape(-1), ctx)[None, :]
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data", None),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
                               out_specs=P("data", None), check_vma=False))
     return np.asarray(f(xs))
 
@@ -61,7 +61,7 @@ def rs_body2(x):
     i = jax.lax.axis_index("data")
     local = jnp.take(x, i, axis=0)     # worker i's gradient (64, 48)
     return reduce_scatter_axis(local, "data", 0, ctx, with_drops=False)
-f2 = jax.jit(shard_map(rs_body2, mesh=mesh,
+f2 = jax.jit(jax.shard_map(rs_body2, mesh=mesh,
                            in_specs=P(None, None, None),
                            out_specs=P("data", None),
                            check_vma=False))
@@ -70,14 +70,15 @@ np.testing.assert_allclose(out2, np.asarray(jnp.mean(g, 0)), atol=1e-5)
 print("reduce-scatter OK")
 
 # 4) 2D TAR on a (2, 2, 2) pod mesh
-mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                      axis_types=(AxisType.Auto,) * 3)
 cfg2 = OptiReduceConfig(strategy="optireduce", pod_axis="pod",
                         drop_rate=0.0, hadamard_block=256)
 xs2 = jax.random.normal(key, (4, 2048), jnp.float32)   # per (pod,data)
 def body2(x):
     ctx = SyncContext(cfg=cfg2, key=jax.random.PRNGKey(3))
     return sync_bucket(x.reshape(-1), ctx)[None]
-f3 = jax.jit(shard_map(
+f3 = jax.jit(jax.shard_map(
     body2, mesh=mesh3, in_specs=P(("pod", "data"), None),
     out_specs=P(("pod", "data"), None), check_vma=False))
 out3 = np.asarray(f3(xs2))           # (4, 2048): identical rows
@@ -93,7 +94,8 @@ from repro.models import init_params
 cfg_m = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
                     n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=128,
                     param_dtype=jnp.float32)
-mesh2 = make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 batch = {"tokens": jax.random.randint(key, (8, 16), 0, 128),
          "labels": jax.random.randint(key, (8, 16), 0, 128)}
 losses = {}
@@ -125,7 +127,7 @@ print("trainer-equivalence OK")
 
 @pytest.mark.slow
 def test_collectives_multidevice():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # virtual CPU devices
     env.pop("XLA_FLAGS", None)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

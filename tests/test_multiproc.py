@@ -200,3 +200,37 @@ def test_sigkill_helper_uses_sigkill():
     src = mp._sigkill_self.__code__.co_names
     assert "SIGKILL" in src and "kill" in src
     assert signal.SIGKILL == 9
+
+
+# ----------------------------------------------------- one process per chip
+def test_spawned_workers_run_jax_on_the_cpu(monkeypatch):
+    """Workers model other hosts' host-side codecs: each runs JAX on the
+    CPU, so none reaches for a chip the launching host may own."""
+    seen = {}
+
+    class _Popen:
+        def __init__(self, cmd, env):
+            seen["cmd"], seen["env"] = cmd, env
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(mp.subprocess, "Popen", _Popen)
+    mp._spawn(mp.build_parser().parse_args([]), 0, "127.0.0.1:1", "r.json")
+    assert seen["env"]["JAX_PLATFORMS"] == "cpu"
+    assert "--worker" in seen["cmd"]
+
+
+@needs_udp
+def test_udp_parent_initialises_no_jax_backend():
+    """A whole udp launch leaves the parent without a JAX backend (it
+    would hold the chip its workers are kept off)."""
+    code = ("from jax._src import xla_bridge\n"
+            "from repro.launch import multiproc\n"
+            "multiproc.main(['--nprocs', '2', '--steps', '1',\n"
+            "                '--elems', '1024', '--timeout', '240'])\n"
+            "print('BACKENDS', len(xla_bridge._backends))\n")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "BACKENDS 0" in proc.stdout

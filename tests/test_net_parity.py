@@ -31,14 +31,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core.allreduce import OptiReduceConfig, SyncContext, sync_bucket
 from repro.core import drops as drops_lib, tar as tar_lib
 from repro.core.pipeline import resolve_spec
 from repro.net import HostRing, InprocBackend, mask_scripted_drops, wire_spec
 
 N, L = 4, 1000            # not block-aligned: pad + tail-packet paths on
-mesh = make_mesh((N,), ("data",))
+mesh = jax.make_mesh((N,), ("data",), axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(5)
 buckets = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (N, L)),
                      np.float32)
@@ -47,7 +47,7 @@ def run(cfg, spec=None):
     def body(x):
         ctx = SyncContext(cfg=cfg, key=key)
         return sync_bucket(x[0], ctx, spec=spec), ctx.loss_fraction()
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
                           out_specs=(P("data"), P()), check_vma=False))
     out, frac = f(buckets)
     return np.asarray(out).reshape(N, L), float(frac)
@@ -109,7 +109,7 @@ _marker_cache: dict = {}
 
 def _child_output() -> str:
     if "out" not in _marker_cache:
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")  # virtual CPU devices
         env.pop("XLA_FLAGS", None)
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(_REPO, "src"), env.get("PYTHONPATH", "")])

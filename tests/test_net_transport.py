@@ -244,7 +244,7 @@ def test_launcher_transport_inproc_feeds_peer_stage_times(tmp_path):
     StepTelemetry.peer_stage_times — one entry per peer, consumed by the
     ControlPlane/StragglerDetector — closing the ROADMAP item that the
     launcher only ever fed step wall-clock."""
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # virtual CPU devices
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_REPO, "src"), env.get("PYTHONPATH", "")])

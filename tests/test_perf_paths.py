@@ -12,7 +12,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import OptiReduceConfig, SyncContext, sync_bucket
 from repro.core.allreduce import reduce_scatter_axis
 from repro.configs.base import ModelConfig
@@ -22,7 +22,7 @@ from repro.models.parallel import ParallelCtx
 key = jax.random.PRNGKey(0)
 
 # 1) optireduce_q (quantized TAR): bounded error, replica-consistent
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 xs = jax.random.normal(key, (8, 20000), jnp.float32)
 expected = np.asarray(jnp.mean(xs, 0))
 cfg = OptiReduceConfig(strategy="optireduce_q", drop_rate=0.0,
@@ -30,7 +30,7 @@ cfg = OptiReduceConfig(strategy="optireduce_q", drop_rate=0.0,
 def body(x):
     ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(7))
     return sync_bucket(x.reshape(-1), ctx)[None]
-f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data", None),
+f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
                           out_specs=P("data", None), check_vma=False))
 out = np.asarray(f(xs))
 rel = np.sqrt(np.mean((out[0]-expected)**2)) / np.std(expected)
@@ -46,7 +46,7 @@ def rs_body(x):
     i = jax.lax.axis_index("data")
     return reduce_scatter_axis(jnp.take(x, i, 0), "data", 0, ctx,
                                with_drops=False)
-fr = jax.jit(shard_map(rs_body, mesh=mesh, in_specs=P(None, None, None),
+fr = jax.jit(jax.shard_map(rs_body, mesh=mesh, in_specs=P(None, None, None),
                            out_specs=P("data", None), check_vma=False))
 rs_out = np.asarray(fr(g))
 true = np.asarray(jnp.mean(g, 0))
@@ -58,7 +58,8 @@ print("rs_wire_q8 OK")
 mcfg = ModelConfig(name="t", family="moe", n_layers=2, d_model=64, n_heads=4,
                    n_kv_heads=2, d_ff=96, vocab_size=128, n_experts=8,
                    top_k=2, param_dtype=jnp.float32)
-mesh2 = make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 params = init_params(key, mcfg)
 tok = jax.random.randint(key, (8, 1), 0, 128)
 def run(moe_stat):
@@ -76,7 +77,7 @@ def run(moe_stat):
     def b(p, st, t):
         return decode_step(p, st, t, jnp.int32(0), mcfg, pctx,
                            key=jax.random.PRNGKey(1))
-    fj = jax.jit(shard_map(b, mesh=mesh2,
+    fj = jax.jit(jax.shard_map(b, mesh=mesh2,
                  in_specs=(p_specs, st_specs, P("data", None)),
                  out_specs=(P("data", None), st_specs), check_vma=False))
     nxt, _ = fj(params, state, tok)
@@ -138,7 +139,7 @@ print("seq_parallel OK")
 
 @pytest.mark.slow
 def test_perf_paths_multidevice():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # virtual CPU devices
     env.pop("XLA_FLAGS", None)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

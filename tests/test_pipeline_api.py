@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import (OptiReduceConfig, SyncContext, strategies,
                         sync_bucket)
 from repro.core import pipeline as pl
@@ -48,20 +48,20 @@ def test_stageless_topology_gets_descriptive_pipelined_error():
             return jax.lax.pmean(bucket, ctx.data_axes())
 
     spec = pl.CollectiveSpec(AllReduceOnly(), pl.Reliable(), pl.Identity())
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     tree = {"g": jnp.ones((2048,))}
 
     def body(t, mode):
         ctx = SyncContext(cfg=OptiReduceConfig(), key=jax.random.PRNGKey(0))
         return sync_pytree(t, ctx, bucket_elems=1024, mode=mode, spec=spec)
 
-    f = shard_map(lambda t: body(t, "scan"), mesh=mesh,
+    f = jax.shard_map(lambda t: body(t, "scan"), mesh=mesh,
                   in_specs=({"g": P()},), out_specs={"g": P()},
                   check_vma=False)
     np.testing.assert_array_equal(np.asarray(f(tree)["g"]),
                                   np.asarray(tree["g"]))
     with pytest.raises(NotImplementedError, match="pipelined.*AllReduceOnly"):
-        shard_map(lambda t: body(t, "pipelined"), mesh=mesh,
+        jax.shard_map(lambda t: body(t, "pipelined"), mesh=mesh,
                   in_specs=({"g": P()},), out_specs={"g": P()},
                   check_vma=False)(tree)
 
@@ -112,14 +112,14 @@ def test_invalid_compositions_rejected_at_spec_time():
 def test_every_registered_spec_is_identity_at_dp1(strategy):
     """dp=1 degenerates every composition to (approximately) the identity —
     a coherence check of the whole Topology x Transport x Codec dispatch."""
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cfg = OptiReduceConfig(strategy=strategy, drop_rate=0.0,
                            hadamard_block=256)
 
     def body(x):
         return sync_bucket(x, SyncContext(cfg=cfg, key=jax.random.PRNGKey(0)))
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
                           check_vma=False))
     x = jax.random.normal(jax.random.PRNGKey(1), (2048,))
     out = np.asarray(f(x))
@@ -145,7 +145,7 @@ def test_masked_mean_is_public_and_matches_ref():
 def test_rounds_split_composes_to_allreduce():
     """tar_exchange_rounds + mean + tar_broadcast_rounds == the one-shot
     tar_allreduce_rounds wrapper (single device: schedule degenerates)."""
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (1024,))
 
     def a(v):
@@ -156,9 +156,9 @@ def test_rounds_split_composes_to_allreduce():
         return tar_lib.tar_broadcast_rounds(jnp.mean(rec, 0), "data",
                                             incast=2)
 
-    fa = jax.jit(shard_map(a, mesh=mesh, in_specs=P(), out_specs=P(),
+    fa = jax.jit(jax.shard_map(a, mesh=mesh, in_specs=P(), out_specs=P(),
                            check_vma=False))
-    fb = jax.jit(shard_map(b, mesh=mesh, in_specs=P(), out_specs=P(),
+    fb = jax.jit(jax.shard_map(b, mesh=mesh, in_specs=P(), out_specs=P(),
                            check_vma=False))
     np.testing.assert_array_equal(np.asarray(fa(x)), np.asarray(fb(x)))
 
@@ -203,7 +203,7 @@ def test_adaptive_transport_controllers():
 def test_sync_pytree_accepts_explicit_spec():
     """An unregistered ad-hoc spec can drive sync_pytree directly."""
     from repro.core import sync_pytree
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     spec = pl.CollectiveSpec(pl.TarTopology(), pl.Reliable(), pl.Hadamard())
     tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (2048,))}
     cfg = OptiReduceConfig(strategy="does_not_matter_with_spec",
@@ -213,7 +213,7 @@ def test_sync_pytree_accepts_explicit_spec():
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
         return sync_pytree(t, ctx, bucket_elems=1024, spec=spec)
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=({"w": P()},),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=({"w": P()},),
                           out_specs={"w": P()}, check_vma=False))
     out = f(tree)
     np.testing.assert_allclose(np.asarray(out["w"]),

@@ -40,12 +40,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.core import (OptiReduceConfig, SyncContext, sync_pytree,
                         sync_pytree_unfused)
 from repro.core.allreduce import reduce_scatter_axis, rs_spec
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 tree = {"w": jax.random.normal(key, (2, 1024)),
         "b": jax.random.normal(jax.random.fold_in(key, 1), (1024,)),
@@ -58,7 +58,7 @@ def run(fn, cfg, bucket_elems=1024):
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
         out = fn(t, ctx, bucket_elems=bucket_elems)
         return out, ctx.loss_fraction()
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
                           out_specs=(spec, P()), check_vma=False))
     return f(tree)
 
@@ -139,7 +139,7 @@ def run_scaled(cfg):
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
         out = sync_pytree(local, ctx, bucket_elems=1024)
         return out["w"][None], ctx.loss_fraction()[None]
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
                           out_specs=(P("data"), P("data")), check_vma=False))
     return f(xflat)
 
@@ -172,7 +172,7 @@ def _n_perms(cfg):
     def body(xx):
         ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
         return sync_pytree({"w": xx}, ctx, bucket_elems=4096)["w"]
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
                           out_specs=P(), check_vma=False))
     return f.lower(xflat).as_text().count("stablehlo.collective_permute")
 full_perms = _n_perms(OptiReduceConfig(strategy="optireduce_rounds",
@@ -244,7 +244,8 @@ assert dead_perms == 18, dead_perms              # 14 + 2 relays * 2 stages
 print("DEADLINK OK %%d perms" %% dead_perms)
 
 # ---- 2D (pod, data) reduce-scatter: cross-pod replica consistency --------
-mesh2 = make_mesh((2, 4), ("pod", "data"))
+mesh2 = jax.make_mesh((2, 4), ("pod", "data"),
+                      axis_types=(AxisType.Auto,) * 2)
 g = jax.random.normal(key, (4, 64, 48))        # same gradient on every node
 cfg2 = OptiReduceConfig(drop_rate=0.05, pod_axis="pod", hadamard_block=256,
                         rs_wire_bits=8, use_kernels=True)
@@ -254,7 +255,7 @@ def rs_body(x):
     i = jax.lax.axis_index("data")
     local = jnp.take(x, i, axis=0)             # pod-replicated input
     return reduce_scatter_axis(local, "data", 0, ctx)[None]
-f2 = jax.jit(shard_map(rs_body, mesh=mesh2, in_specs=P(None, None, None),
+f2 = jax.jit(jax.shard_map(rs_body, mesh=mesh2, in_specs=P(None, None, None),
                        out_specs=P(("pod", "data"), None, None),
                        check_vma=False))
 out2 = np.asarray(f2(g))                       # (8, 16, 48): pod-major rows
@@ -271,7 +272,7 @@ def grid_body(x):
     p = jax.lax.axis_index("pod")
     local = x * (1.0 + p)                      # pod-dependent scale
     return enc_codec.encode(local.reshape(-1), ctx, "data").lo[None]
-f3 = jax.jit(shard_map(grid_body, mesh=mesh2, in_specs=P(None),
+f3 = jax.jit(jax.shard_map(grid_body, mesh=mesh2, in_specs=P(None),
                        out_specs=P(("pod", "data"), None), check_vma=False))
 lo = np.asarray(f3(jax.random.normal(key, (2048,))))
 assert np.all(lo == lo[0:1]), "quant grids differ across pods"
@@ -281,7 +282,7 @@ print("RS2D grids-shared OK")
 
 @pytest.fixture(scope="module")
 def parity_output():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # virtual CPU devices
     env.pop("XLA_FLAGS", None)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
