@@ -8,6 +8,9 @@ instruments:
   Must stay in the low tens of ns.
 * ``enabled_complete_us`` / ``enabled_event_us`` — one ring-buffer record
   with tracing ON (span with explicit ts/dur; instant event).
+* ``enabled_span_us`` — one ``with tracer.span(...)`` opened and closed with
+  tracing ON: two clock reads, the record, and the profiler annotation the
+  span opens (no profiler session running).
 * ``hist_record_us`` — one :class:`TailHistogram` sample.
 * ``wire_step_untraced/traced_median_us`` — a real 4-peer inproc HostRing
   allreduce step, tracing off vs on: the end-to-end overhead on the wire
@@ -74,6 +77,15 @@ def _bench_primitives(rows: Rows, *, calls: int, reps: int) -> None:
     rows.add("obs/enabled_event_median_us", med,
              "one instant record into the ring")
     rows.add("obs/enabled_event_iqr_us", iqr, "")
+
+    def enabled_span(n):
+        for _ in range(n):
+            with tr.span("step"):
+                pass
+    med, iqr = _per_call_us(enabled_span, calls, reps)
+    rows.add("obs/enabled_span_median_us", med,
+             "one span opened and closed (record + profiler annotation)")
+    rows.add("obs/enabled_span_iqr_us", iqr, "")
     trace.reset()
 
     h = TailHistogram()

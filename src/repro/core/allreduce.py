@@ -54,6 +54,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from .bucket_plan import BucketPlan, bucket_keys
 from .pipeline import (CollectiveSpec, Hadamard, HTQuant, Identity, Lossy,
                        OptiReduceConfig, Reliable, SyncContext, TarTopology,
@@ -135,14 +137,16 @@ def _sync_pipelined(batch, keys, ctx: SyncContext, spec: CollectiveSpec,
         # the per-bucket pairing survives because encode/exchange/decode of
         # one bucket share the carried tuple, not the loop index
         sctx = SyncContext(cfg=cfg, key=key, stale=stale_b)
-        return (key, spec.encode_stage(bucket, sctx))
+        with jax.named_scope(scopes.ENCODE):
+            return (key, spec.encode_stage(bucket, sctx))
 
     def exch(state):
         nonlocal recorded
         key, inner = state
         stats: dict = {}
         sctx = SyncContext(cfg=cfg, key=key, stats=stats)
-        out = spec.exchange_stage(inner, sctx)
+        with jax.named_scope(scopes.EXCHANGE):
+            out = spec.exchange_stage(inner, sctx)
         recorded = recorded or ("total" in stats)
         return ((key, out), (stats.get("dropped", jnp.zeros(())),
                              stats.get("total", jnp.zeros(()))))
@@ -150,7 +154,8 @@ def _sync_pipelined(batch, keys, ctx: SyncContext, spec: CollectiveSpec,
     def dec(state):
         key, inner = state
         sctx = SyncContext(cfg=cfg, key=key)
-        return spec.decode_stage(inner, length, sctx)
+        with jax.named_scope(scopes.DECODE):
+            return spec.decode_stage(inner, length, sctx)
 
     def stale_at(it):
         return None if stale is None else stale[it]

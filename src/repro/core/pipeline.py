@@ -59,6 +59,7 @@ from .hadamard import ht_decode, ht_encode, ht_encode_amax, ht_encode_quant
 from .ubt import UbtState
 from repro.kernels.dequant_reduce import dequant_masked_mean
 from repro.kernels.quant import grid_quant
+from repro.obs import scopes
 
 
 # ------------------------------------------------------------- configuration
@@ -633,10 +634,13 @@ class Topology:
 
     def all_reduce(self, bucket: jnp.ndarray, transport: Reliable,
                    codec: Codec, ctx: SyncContext) -> jnp.ndarray:
-        state = self.encode_stage(bucket, transport, codec, ctx)
-        state = self.exchange_stage(state, transport, codec, ctx)
-        return self.decode_stage(state, bucket.shape[-1], transport, codec,
-                                 ctx)
+        with jax.named_scope(scopes.ENCODE):
+            state = self.encode_stage(bucket, transport, codec, ctx)
+        with jax.named_scope(scopes.EXCHANGE):
+            state = self.exchange_stage(state, transport, codec, ctx)
+        with jax.named_scope(scopes.DECODE):
+            return self.decode_stage(state, bucket.shape[-1], transport,
+                                     codec, ctx)
 
     def reduce_scatter(self, g, axis, dim, transport, codec, ctx):
         raise NotImplementedError(

@@ -29,9 +29,49 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import init_params
+from repro.obs import span
 from repro.optim.optimizers import OptimizerConfig
 from repro.train import checkpoint as ckpt_lib
 from repro.train.trainer import TrainConfig, build_train_step
+
+DEVICE_TRACED_STEPS = 8
+
+
+class DeviceCapture:
+    """A JAX device trace of ``n`` steps from step ``first`` into
+    ``<out_dir>/device``, each step annotated ``train`` as the benchmark
+    annotates its steps. The host spans (``batch``, ``step``, ``control``,
+    ``rebuild``, ``ckpt``) are profiler annotations too, so every idle gap
+    of the device lines up with what the host was doing."""
+
+    def __init__(self, out_dir: str, first: int, n: int = DEVICE_TRACED_STEPS):
+        self.dir = os.path.join(out_dir, "device")
+        self.first, self.last = first, first + n - 1
+        self.active = False
+        self._step = None
+
+    def begin(self, step: int) -> None:
+        if step == self.first:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0      # host annotations, no calls
+            jax.profiler.start_trace(self.dir, profiler_options=opts)
+            self.active = True
+        if self.active:
+            self._step = jax.profiler.StepTraceAnnotation("train",
+                                                          step_num=step)
+            self._step.__enter__()
+
+    def end(self, step: int) -> None:
+        if self._step is not None:
+            self._step.__exit__(None, None, None)
+            self._step = None
+        if step >= self.last:
+            self.close()
+
+    def close(self) -> None:
+        if self.active:
+            jax.profiler.stop_trace()
+            self.active = False
 
 
 def main(argv=None) -> dict:
@@ -102,11 +142,14 @@ def main(argv=None) -> dict:
                          "offline analysis")
     ap.add_argument("--trace", nargs="?", const=".", default=None,
                     metavar="DIR",
-                    help="record a structured trace (DESIGN §12) — step "
-                         "spans, wire round/phase spans, every ControlPlane "
+                    help="record a structured trace (DESIGN §12) — host "
+                         "spans of each step (batch/step/control/rebuild/"
+                         "ckpt), wire round/phase spans, every ControlPlane "
                          "transition — and write Perfetto trace_event JSON "
                          "into DIR (default '.') at exit; merge/inspect "
-                         "with python -m repro.obs.report")
+                         "with python -m repro.obs.report. Also captures a "
+                         "JAX device trace of 8 steps from a third of the "
+                         "run into DIR/device")
     ap.add_argument("--trace-capacity", type=int, default=None,
                     help="trace ring-buffer capacity in records (default "
                          "65536; oldest records drop on wraparound)")
@@ -345,23 +388,31 @@ def main(argv=None) -> dict:
     report_rows: list[dict] = []
     losses: list[float] = []
     step_times: list[float] = []
+    capture = None
+    if tracer is not None:
+        capture = DeviceCapture(
+            args.trace, start_step + (args.steps - start_step) // 3)
     t0 = time.time()
     try:
         for step in range(start_step, args.steps):
-            batch = data.host_batch(step, 0, 1)
-            batch = jax.device_put(batch, shardings["batch"])
-            st0 = tracer.now() if tracer is not None else 0.0
+            if capture is not None:
+                capture.begin(step)
+            with span("batch"):
+                batch = data.host_batch(step, 0, 1)
+                batch = jax.device_put(batch, shardings["batch"])
             t_step = time.time()
-            if rec_state is not None:
-                params, opt_state, rec_state, metrics = jf(
-                    params, opt_state, rec_state, batch,
-                    jnp.asarray(step, jnp.int32), key)
-            else:
-                params, opt_state, metrics = jf(
-                    params, opt_state, batch, jnp.asarray(step, jnp.int32),
-                    key)
-            loss_frac = float(metrics["loss_frac"])
-            losses.append(float(metrics["loss"]))
+            with span("step", step=step) as sp:
+                if rec_state is not None:
+                    params, opt_state, rec_state, metrics = jf(
+                        params, opt_state, rec_state, batch,
+                        jnp.asarray(step, jnp.int32), key)
+                else:
+                    params, opt_state, metrics = jf(
+                        params, opt_state, batch,
+                        jnp.asarray(step, jnp.int32), key)
+                loss_frac = float(metrics["loss_frac"])
+                losses.append(float(metrics["loss"]))
+                sp.set(loss_frac=round(loss_frac, 6))
             step_times.append(time.time() - t_step)
             if with_budget:
                 # phase-aware budget (DESIGN §8): the phase follows the LR
@@ -377,110 +428,122 @@ def main(argv=None) -> dict:
                       f"gnorm {m['grad_norm']:.3f} loss_frac {m['loss_frac']:.5f}"
                       f" skipped {int(m['skipped'])} ({rate:.2f} it/s)",
                       flush=True)
-            if control is not None:
-                wire_t = None
-                if ring is not None:
-                    # let in-flight exchanges land; a wedged or dead wire layer
-                    # must be loud, not silently degrade to all-ones masks
-                    if not ring.flush():
-                        print(f"wire[{args.transport}] WARNING: exchanges still "
-                              f"in flight at step {step} (flush timed out)",
+            with span("control"):
+                if control is not None:
+                    wire_t = None
+                    if ring is not None:
+                        # let in-flight exchanges land; a wedged or dead
+                        # wire layer must be loud, not silently degrade to
+                        # all-ones masks
+                        if not ring.flush():
+                            print(f"wire[{args.transport}] WARNING: "
+                                  f"exchanges still in flight at step "
+                                  f"{step} (flush timed out)", flush=True)
+                        if ring.bridge_error is not None:
+                            print(f"wire[{args.transport}] ERROR: bridge "
+                                  f"worker died: {ring.bridge_error!r} — "
+                                  "masks degrade to all-ones and telemetry "
+                                  "stops", flush=True)
+                            ring.bridge_error = None
+                        wire_t = ring.drain_telemetry(step)
+                    if wire_t is not None:
+                        # the real thing the ROADMAP asked for: per-peer
+                        # stage times, per-round deadlines/timeouts/received
+                        # fractions — observed on the wire, consumed by
+                        # detector + controllers
+                        control.observe(wire_t)
+                        if (step % args.log_every == 0
+                                or step == args.steps - 1):
+                            pst = ", ".join(
+                                f"{t:.3g}"
+                                for t in (wire_t.peer_stage_times or ()))
+                            print(f"wire[{args.transport}] peers="
+                                  f"{len(wire_t.peer_stage_times or ())} "
+                                  f"stage_times=[{pst}] "
+                                  f"loss_frac={wire_t.loss_frac:.5f} "
+                                  f"deadline="
+                                  f"{ring.peers[0].round_deadline():.4g}"
+                                  + (f" misses={ring.bridge_misses}"
+                                     if ring.bridge_misses else ""),
+                                  flush=True)
+                    elif ring is None:
+                        # wall-clock only makes sense for the synthetic
+                        # transport: a wire-fed AdaptiveTimeout is profiled in
+                        # the backend's clock units, and one wall-clock sample
+                        # during warmup would inflate t_B/t_C by orders of
+                        # magnitude
+                        control.observe(StepTelemetry(
+                            step=step, loss_frac=loss_frac,
+                            step_time=time.time() - t_step))
+                    if args.report:
+                        det = control.detector
+                        report_rows.append({
+                            "step": step,
+                            "scores": [float(s) for s in det.scores()],
+                            "weights": [int(w) for w in det.weights()],
+                            "active": [int(p) for p in det.active_peers()],
+                            "dead_links": [list(l)
+                                           for l in control.dead_links()],
+                            "dead_link_events": [
+                                list(l) for l in
+                                ((wire_t.dead_link_events or ())
+                                 if wire_t is not None else ())],
+                        })
+                if args.adaptive:
+                    new_sync = control.apply(tc.sync)
+                    if not incast_matters:   # incast only lowers rounds forms
+                        new_sync = dataclasses.replace(
+                            new_sync, incast=tc.sync.incast)
+                    if not ht_matters:
+                        new_sync = dataclasses.replace(
+                            new_sync, use_hadamard=tc.sync.use_hadamard)
+                    if not participation_matters:
+                        new_sync = dataclasses.replace(
+                            new_sync, active_peers=tc.sync.active_peers)
+                    if not rebalance_matters:
+                        new_sync = dataclasses.replace(new_sync,
+                                                       shard_weights=None)
+                    if not deadlink_matters:
+                        new_sync = dataclasses.replace(new_sync,
+                                                       dead_links=())
+                    # debounce: a growing incast ramps one step at a time, and
+                    # each rebuild recompiles the whole step — wait for the
+                    # controller to settle. A Hadamard toggle is an accuracy
+                    # decision and an ejection stops the straggler wait: both
+                    # immediate.
+                    stable_for = (stable_for + 1 if new_sync == stable_rec
+                                  else 1)
+                    stable_rec = new_sync
+                    # a link failure (or recovery probe) reroutes immediately
+                    # — waiting three steps on a dead edge loses three
+                    # deadlines
+                    urgent = (new_sync.use_hadamard != tc.sync.use_hadamard or
+                              new_sync.active_peers != tc.sync.active_peers or
+                              new_sync.dead_links != tc.sync.dead_links)
+                    if new_sync != tc.sync and (urgent or stable_for >= 3):
+                        tc = dataclasses.replace(tc, sync=new_sync)
+                        cached = step_cache.get(policy_of(new_sync))
+                        if cached is not None:
+                            jf, shardings = cached
+                            how = "cached step reused"
+                        else:
+                            with span("rebuild"):
+                                make_step, opt, _ = build_train_step(
+                                    cfg, tc, mesh)
+                                step_fn, shardings = make_step(
+                                    jax.eval_shape(opt.init, params), batch0)
+                                jf = jax.jit(step_fn, donate_argnums=donate)
+                                step_cache.put(policy_of(new_sync),
+                                               (jf, shardings))
+                            how = "step rebuilt"
+                        print(f"adaptive: "
+                              f"use_hadamard={new_sync.use_hadamard} "
+                              f"incast={new_sync.incast} "
+                              f"active={new_sync.active_peers} "
+                              f"weights={new_sync.shard_weights} "
+                              f"dead={new_sync.dead_links} ({how})",
                               flush=True)
-                    if ring.bridge_error is not None:
-                        print(f"wire[{args.transport}] ERROR: bridge worker "
-                              f"died: {ring.bridge_error!r} — masks degrade to "
-                              "all-ones and telemetry stops", flush=True)
-                        ring.bridge_error = None
-                    wire_t = ring.drain_telemetry(step)
-                if wire_t is not None:
-                    # the real thing the ROADMAP asked for: per-peer stage
-                    # times, per-round deadlines/timeouts/received fractions —
-                    # observed on the wire, consumed by detector + controllers
-                    control.observe(wire_t)
-                    if step % args.log_every == 0 or step == args.steps - 1:
-                        pst = ", ".join(f"{t:.3g}" for t
-                                        in (wire_t.peer_stage_times or ()))
-                        print(f"wire[{args.transport}] peers="
-                              f"{len(wire_t.peer_stage_times or ())} "
-                              f"stage_times=[{pst}] "
-                              f"loss_frac={wire_t.loss_frac:.5f} "
-                              f"deadline="
-                              f"{ring.peers[0].round_deadline():.4g}"
-                              + (f" misses={ring.bridge_misses}"
-                                 if ring.bridge_misses else ""),
-                              flush=True)
-                elif ring is None:
-                    # wall-clock only makes sense for the synthetic transport:
-                    # a wire-fed AdaptiveTimeout is profiled in the backend's
-                    # clock units, and one wall-clock sample during warmup
-                    # would inflate t_B/t_C by orders of magnitude
-                    control.observe(StepTelemetry(
-                        step=step, loss_frac=loss_frac,
-                        step_time=time.time() - t_step))
-                if args.report:
-                    det = control.detector
-                    report_rows.append({
-                        "step": step,
-                        "scores": [float(s) for s in det.scores()],
-                        "weights": [int(w) for w in det.weights()],
-                        "active": [int(p) for p in det.active_peers()],
-                        "dead_links": [list(l)
-                                       for l in control.dead_links()],
-                        "dead_link_events": [
-                            list(l) for l in
-                            ((wire_t.dead_link_events or ())
-                             if wire_t is not None else ())],
-                    })
-            if args.adaptive:
-                new_sync = control.apply(tc.sync)
-                if not incast_matters:       # incast only lowers rounds forms
-                    new_sync = dataclasses.replace(new_sync,
-                                                   incast=tc.sync.incast)
-                if not ht_matters:
-                    new_sync = dataclasses.replace(
-                        new_sync, use_hadamard=tc.sync.use_hadamard)
-                if not participation_matters:
-                    new_sync = dataclasses.replace(
-                        new_sync, active_peers=tc.sync.active_peers)
-                if not rebalance_matters:
-                    new_sync = dataclasses.replace(new_sync,
-                                                   shard_weights=None)
-                if not deadlink_matters:
-                    new_sync = dataclasses.replace(new_sync, dead_links=())
-                # debounce: a growing incast ramps one step at a time, and each
-                # rebuild recompiles the whole step — wait for the controller to
-                # settle. A Hadamard toggle is an accuracy decision and an
-                # ejection stops the straggler wait: both immediate.
-                stable_for = stable_for + 1 if new_sync == stable_rec else 1
-                stable_rec = new_sync
-                # a link failure (or recovery probe) reroutes immediately —
-                # waiting three steps on a dead edge loses three deadlines
-                urgent = (new_sync.use_hadamard != tc.sync.use_hadamard or
-                          new_sync.active_peers != tc.sync.active_peers or
-                          new_sync.dead_links != tc.sync.dead_links)
-                if new_sync != tc.sync and (urgent or stable_for >= 3):
-                    tc = dataclasses.replace(tc, sync=new_sync)
-                    cached = step_cache.get(policy_of(new_sync))
-                    if cached is not None:
-                        jf, shardings = cached
-                        how = "cached step reused"
-                    else:
-                        make_step, opt, _ = build_train_step(cfg, tc, mesh)
-                        step_fn, shardings = make_step(
-                            jax.eval_shape(opt.init, params), batch0)
-                        jf = jax.jit(step_fn, donate_argnums=donate)
-                        step_cache.put(policy_of(new_sync), (jf, shardings))
-                        how = "step rebuilt"
-                    print(f"adaptive: use_hadamard={new_sync.use_hadamard} "
-                          f"incast={new_sync.incast} "
-                          f"active={new_sync.active_peers} "
-                          f"weights={new_sync.shard_weights} "
-                          f"dead={new_sync.dead_links} ({how})", flush=True)
             if tracer is not None:
-                tracer.complete("step", "trainer", ts=st0,
-                                dur=tracer.now() - st0,
-                                args={"step": step,
-                                      "loss_frac": round(loss_frac, 6)})
                 tracer.counter("loss_frac", loss_frac)
             monitor.observe(step, loss_frac, bool(metrics["skipped"] > 0))
             if monitor.halted:
@@ -489,13 +552,20 @@ def main(argv=None) -> dict:
                 if rb is not None:
                     _, params = rb
             if ckpt and step > 0 and step % args.ckpt_every == 0:
-                ckpt.save(step, (params, opt_state) if rec_state is None
-                          else (params, opt_state, rec_state))
+                with span("ckpt"):
+                    ckpt.save(step, (params, opt_state) if rec_state is None
+                              else (params, opt_state, rec_state))
+            if capture is not None:
+                capture.end(step)
         if ckpt:
-            ckpt.save(args.steps, (params, opt_state) if rec_state is None
-                      else (params, opt_state, rec_state))
-            ckpt.wait()
+            with span("ckpt"):
+                ckpt.save(args.steps, (params, opt_state)
+                          if rec_state is None
+                          else (params, opt_state, rec_state))
+                ckpt.wait()
     finally:
+        if capture is not None:
+            capture.close()
         if ring is not None:
             ring.close()          # UDP sockets + the bridge worker
         for c in rdv_clients:
@@ -516,7 +586,7 @@ def main(argv=None) -> dict:
                                       meta={"transport": args.transport,
                                             "strategy": args.strategy})
         print(f"trace: {len(tracer)} records ({tracer.dropped} dropped) "
-              f"-> {path}", flush=True)
+              f"-> {path}; device trace -> {capture.dir}", flush=True)
     print("done")
     return {"losses": losses, "step_times": step_times, "step": jf,
             "params": params, "opt_state": opt_state, "batch": batch}

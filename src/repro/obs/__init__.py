@@ -8,14 +8,14 @@ recording on.
 """
 from .trace import (TraceConfig, Tracer, Span, configure, configure_thread,
                     get_tracer, is_enabled, span, event, reset)
-from .hist import TailHistogram, Counter, Gauge, MetricsRegistry, metrics
+from .hist import TailHistogram
 from .export import (TraceSchemaError, to_trace_events, trace_payload,
                      write_trace, validate_trace, trace_path)
 
 __all__ = [
     "TraceConfig", "Tracer", "Span", "configure", "configure_thread",
     "get_tracer", "is_enabled", "span", "event", "reset",
-    "TailHistogram", "Counter", "Gauge", "MetricsRegistry", "metrics",
+    "TailHistogram",
     "TraceSchemaError", "to_trace_events", "trace_payload", "write_trace",
     "validate_trace", "trace_path",
 ]

@@ -1,4 +1,4 @@
-"""Streaming tail histograms and a small metrics registry (DESIGN §12).
+"""Streaming tail histograms (DESIGN §12).
 
 :class:`TailHistogram` is a log-bucketed (HDR-style) streaming histogram:
 values land in geometrically-spaced buckets — ``bins_per_octave`` buckets
@@ -9,19 +9,14 @@ samples stream through.  That is the p999 contract the tail tables need:
 recording a million round times costs the same memory as recording ten,
 and per-rank histograms :meth:`merge` associatively into the cross-rank
 aggregate (bucket counts add — order never matters).
-
-:class:`MetricsRegistry` fronts counters / gauges / histograms behind
-get-or-create names, so instrumented code never branches on "was this
-metric registered"; :func:`metrics` is the process-global instance.
 """
 from __future__ import annotations
 
 import math
-import threading
 
 import numpy as np
 
-__all__ = ["TailHistogram", "Counter", "Gauge", "MetricsRegistry", "metrics"]
+__all__ = ["TailHistogram"]
 
 
 class TailHistogram:
@@ -148,75 +143,3 @@ class TailHistogram:
                             self.bins_per_octave)
         out.merge(self)
         return out
-
-
-class Counter:
-    """Monotone accumulator."""
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = 0.0
-
-    def inc(self, n: float = 1.0) -> None:
-        self.value += n
-
-
-class Gauge:
-    """Last-write-wins sample."""
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = math.nan
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
-
-
-class MetricsRegistry:
-    """Named counters / gauges / tail histograms, get-or-create."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._counters: dict[str, Counter] = {}
-        self._gauges: dict[str, Gauge] = {}
-        self._hists: dict[str, TailHistogram] = {}
-
-    def counter(self, name: str) -> Counter:
-        with self._lock:
-            return self._counters.setdefault(name, Counter())
-
-    def gauge(self, name: str) -> Gauge:
-        with self._lock:
-            return self._gauges.setdefault(name, Gauge())
-
-    def histogram(self, name: str, **kw) -> TailHistogram:
-        with self._lock:
-            h = self._hists.get(name)
-            if h is None:
-                h = self._hists[name] = TailHistogram(**kw)
-            return h
-
-    def snapshot(self) -> dict:
-        """JSON-safe dump: counters/gauges by value, histograms by
-        :meth:`TailHistogram.summary`."""
-        with self._lock:
-            return {
-                "counters": {k: c.value for k, c in self._counters.items()},
-                "gauges": {k: g.value for k, g in self._gauges.items()},
-                "histograms": {k: h.summary()
-                               for k, h in self._hists.items()},
-            }
-
-    def clear(self) -> None:
-        with self._lock:
-            self._counters.clear()
-            self._gauges.clear()
-            self._hists.clear()
-
-
-_metrics = MetricsRegistry()
-
-
-def metrics() -> MetricsRegistry:
-    """The process-global registry."""
-    return _metrics

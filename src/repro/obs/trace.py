@@ -69,13 +69,26 @@ class TraceConfig:
         self.clock = clock
 
 
+def _trace_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, imported on first use so that
+    the recorder itself never imports JAX."""
+    global _trace_annotation
+    from jax.profiler import TraceAnnotation
+    _trace_annotation = TraceAnnotation
+    return TraceAnnotation(name)
+
+
 class Span:
     """Context manager emitting one complete ("X") record at exit.
+
+    While open it is also a ``jax.profiler.TraceAnnotation`` of its name, so
+    a device trace taken meanwhile shows it on the host's timeline, on the
+    clock of the device's operations.
 
     ``set(key=value)`` attaches args discovered mid-span (e.g. the round's
     observed loss fraction).
     """
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int,
                  args: dict | None):
@@ -93,11 +106,14 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._ann = _trace_annotation(self.name)
+        self._ann.__enter__()
         self._t0 = self._tracer.now()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = self._tracer.now()
+        self._ann.__exit__(*exc)
         self._tracer.complete(self.name, self.cat, ts=self._t0,
                               dur=t1 - self._t0, tid=self.tid,
                               args=self.args)

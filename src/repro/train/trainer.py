@@ -51,6 +51,7 @@ from repro.kernels import runtime as kernel_runtime
 from repro.models import lm_loss, param_specs, param_table
 from repro.models.parallel import ParallelCtx
 from repro.models.transformer import _tree_map_table
+from repro.obs import scopes
 from repro.optim.optimizers import OptimizerConfig, make_optimizer
 
 
@@ -113,8 +114,9 @@ def make_fsdp_gather(sync_cfg: OptiReduceConfig, fsdp_axes: tuple[str, ...]):
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
     def gather(w, dim, key):
-        for ax in inner_to_outer:
-            w = jax.lax.all_gather(w, ax, axis=dim, tiled=True)
+        with jax.named_scope(scopes.SYNC):
+            for ax in inner_to_outer:
+                w = jax.lax.all_gather(w, ax, axis=dim, tiled=True)
         return w
 
     def fwd(w, dim, key):
@@ -123,9 +125,11 @@ def make_fsdp_gather(sync_cfg: OptiReduceConfig, fsdp_axes: tuple[str, ...]):
     def bwd(dim, key, g):
         ctx = SyncContext(cfg=sync_cfg, key=key)
         out_dtype = g.dtype
-        for ax in fsdp_axes:              # outer (pod) first, mirrors fwd
-            g = axis_specs[ax].reduce_scatter(g, ax, dim, ctx)
-        return (g.astype(out_dtype), None)
+        with jax.named_scope(scopes.SYNC):
+            for ax in fsdp_axes:          # outer (pod) first, mirrors fwd
+                g = axis_specs[ax].reduce_scatter(g, ax, dim, ctx)
+            g = g.astype(out_dtype)
+        return (g, None)
 
     gather.defvjp(fwd, bwd)
     return gather
@@ -280,7 +284,8 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
         if dp_axes else P()
 
     def _body(params, opt_state, batch, step, key, rec_state):
-        skey = jax.random.fold_in(key, step)
+        with jax.named_scope(scopes.FWD_BWD):
+            skey = jax.random.fold_in(key, step)
         new_rec = None
 
         def loss_fn(p, mb):
@@ -291,7 +296,8 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
         b_local = batch["tokens"].shape[0]
         mb = tc.microbatch or b_local
         n_micro = max(1, b_local // mb)
-        ctx = SyncContext(cfg=sync_cfg, key=jax.random.fold_in(skey, 7))
+        with jax.named_scope(scopes.SYNC):
+            ctx = SyncContext(cfg=sync_cfg, key=jax.random.fold_in(skey, 7))
         if n_micro > 1:
             mbatches = jax.tree.map(
                 lambda x: x.reshape(n_micro, mb, *x.shape[1:]), batch)
@@ -303,44 +309,52 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
             if n_micro > 1:
                 def micro(carry, mbatch):
                     gacc, lacc = carry
-                    l, g = jax.value_and_grad(loss_fn)(params, mbatch)
-                    gacc = jax.tree.map(
-                        lambda a, b_: a + b_.astype(tc.accum_dtype), gacc, g)
+                    with jax.named_scope(scopes.FWD_BWD):
+                        l, g = jax.value_and_grad(loss_fn)(params, mbatch)
+                    with jax.named_scope(scopes.SYNC):
+                        gacc = jax.tree.map(
+                            lambda a, b_: a + b_.astype(tc.accum_dtype),
+                            gacc, g)
                     return (gacc, lacc + l), None
 
                 zeros = jax.tree.map(
                     lambda p: jnp.zeros(p.shape, tc.accum_dtype), params)
                 (grads, loss), _ = jax.lax.scan(
                     micro, (zeros, jnp.zeros(())), mbatches)
-                grads = jax.tree.map(lambda g: g / n_micro, grads)
+                with jax.named_scope(scopes.SYNC):
+                    grads = jax.tree.map(lambda g: g / n_micro, grads)
                 loss = loss / n_micro
             else:
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                with jax.named_scope(scopes.FWD_BWD):
+                    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
 
-            flat_g, tdef = jax.tree.flatten(grads)
-            flat_m = jax.tree.leaves(fsdp_mask)
-            small = [g for g, m_ in zip(flat_g, flat_m) if not m_]
-            if small:
-                synced_small = sync_pytree(small, ctx,
-                                           bucket_elems=tc.bucket_elems,
-                                           mode=tc.sync_mode, spec=sync_spec)
-                it = iter(synced_small)
-                flat_g = [next(it) if not m_ else g
-                          for g, m_ in zip(flat_g, flat_m)]
-            grads = jax.tree.unflatten(tdef, flat_g)
-            loss_frac = ctx.loss_fraction()
+            with jax.named_scope(scopes.SYNC):
+                flat_g, tdef = jax.tree.flatten(grads)
+                flat_m = jax.tree.leaves(fsdp_mask)
+                small = [g for g, m_ in zip(flat_g, flat_m) if not m_]
+                if small:
+                    synced_small = sync_pytree(
+                        small, ctx, bucket_elems=tc.bucket_elems,
+                        mode=tc.sync_mode, spec=sync_spec)
+                    it = iter(synced_small)
+                    flat_g = [next(it) if not m_ else g
+                              for g, m_ in zip(flat_g, flat_m)]
+                grads = jax.tree.unflatten(tdef, flat_g)
+                loss_frac = ctx.loss_fraction()
 
             # ---- safeguards (§3.4), clip, optimizer ----------------------
-            if tc.guard:
-                grads, skipped = guard_update(
-                    grads, loss_frac, skip_threshold=sync_cfg.skip_threshold)
-            else:
-                skipped = jnp.zeros((), jnp.bool_)
-            gnorm = sharded_global_norm(grads, p_specs)
-            clip_scale = jnp.minimum(
-                1.0, tc.optimizer.grad_clip / jnp.maximum(gnorm, 1e-9))
-            grads = jax.tree.map(lambda g: g * clip_scale.astype(g.dtype),
-                                 grads)
+            with jax.named_scope(scopes.OPTIMIZER):
+                if tc.guard:
+                    grads, skipped = guard_update(
+                        grads, loss_frac,
+                        skip_threshold=sync_cfg.skip_threshold)
+                else:
+                    skipped = jnp.zeros((), jnp.bool_)
+                gnorm = sharded_global_norm(grads, p_specs)
+                clip_scale = jnp.minimum(
+                    1.0, tc.optimizer.grad_clip / jnp.maximum(gnorm, 1e-9))
+                grads = jax.tree.map(
+                    lambda g: g * clip_scale.astype(g.dtype), grads)
         else:
             # ---- packed gradient arena (replicated DP) -------------------
             # the (B, bucket_elems) batch IS the accumulator: micro-batch
@@ -353,9 +367,11 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
             if n_micro > 1:
                 def micro(carry, mbatch):
                     acc, lacc = carry
-                    l, g = jax.value_and_grad(loss_fn)(params, mbatch)
-                    return (acc + plan.pack(g, dtype=tc.accum_dtype),
-                            lacc + l), None
+                    with jax.named_scope(scopes.FWD_BWD):
+                        l, g = jax.value_and_grad(loss_fn)(params, mbatch)
+                    with jax.named_scope(scopes.SYNC):
+                        acc = acc + plan.pack(g, dtype=tc.accum_dtype)
+                    return (acc, lacc + l), None
 
                 arena0 = jnp.zeros((plan.num_buckets, plan.bucket_elems),
                                    tc.accum_dtype)
@@ -365,60 +381,68 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
                 # accumulator), then take the micro-batch mean in fp32 wire
                 # space: identical for fp32 accum, and for bf16 it drops the
                 # seed's extra accum-dtype rounding of the mean
-                arena = arena.astype(jnp.float32) / n_micro
+                with jax.named_scope(scopes.SYNC):
+                    arena = arena.astype(jnp.float32) / n_micro
                 loss = loss / n_micro
             else:
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-                arena = plan.pack(grads)
+                with jax.named_scope(scopes.FWD_BWD):
+                    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                with jax.named_scope(scopes.SYNC):
+                    arena = plan.pack(grads)
 
-            if rec_state is not None and "ef" in rec_state:
-                # the carried EF residual joins this rank's contribution
-                # (per-rank state: each data rank dropped different wire
-                # spans last step; the local shard is (1, B, E))
-                arena = arena + rec_state["ef"][0]
+            with jax.named_scope(scopes.SYNC):
+                if rec_state is not None and "ef" in rec_state:
+                    # the carried EF residual joins this rank's contribution
+                    # (per-rank state: each data rank dropped different wire
+                    # spans last step; the local shard is (1, B, E))
+                    arena = arena + rec_state["ef"][0]
 
-            synced = sync_packed(arena, ctx, mode=tc.sync_mode,
-                                 spec=sync_spec,
-                                 stale=None if rec_state is None
-                                 else rec_state.get("stale"))
-            loss_frac = ctx.loss_fraction()
+                synced = sync_packed(arena, ctx, mode=tc.sync_mode,
+                                     spec=sync_spec,
+                                     stale=None if rec_state is None
+                                     else rec_state.get("stale"))
+                loss_frac = ctx.loss_fraction()
 
-            if rec_state is not None:
-                new_rec = dict(rec_state)
-                if "stale" in new_rec:
-                    # next step's prediction for lost wire spans: this
-                    # step's decoded arena, pre-guard/clip (the sync output
-                    # is replicated — every rank caches identical buckets)
-                    new_rec["stale"] = synced
-                if "ef" in new_rec:
-                    n_dp = mesh.shape[data_axis]
-                    me = jax.lax.axis_index(data_axis)
-                    # residual vs the *pre-update* stale cache: that is
-                    # what the fill applied in this rank's stead, so the
-                    # carried mass is only the gap (no double counting)
-                    new_rec["ef"] = recovery_lib.ef_residual_arena(
-                        arena, ctx.key, sync_cfg, n_dp, me,
-                        stale=rec_state["stale"])[None]
+                if rec_state is not None:
+                    new_rec = dict(rec_state)
+                    if "stale" in new_rec:
+                        # next step's prediction for lost wire spans: this
+                        # step's decoded arena, pre-guard/clip (the sync
+                        # output is replicated — every rank caches identical
+                        # buckets)
+                        new_rec["stale"] = synced
+                    if "ef" in new_rec:
+                        n_dp = mesh.shape[data_axis]
+                        me = jax.lax.axis_index(data_axis)
+                        # residual vs the *pre-update* stale cache: that is
+                        # what the fill applied in this rank's stead, so the
+                        # carried mass is only the gap (no double counting)
+                        new_rec["ef"] = recovery_lib.ef_residual_arena(
+                            arena, ctx.key, sync_cfg, n_dp, me,
+                            stale=rec_state["stale"])[None]
 
             # ---- safeguards (§3.4), clip: fused over the arena -----------
             # norm and clip read the fp32 wire values with ONE param-dtype
             # round at unpack; for non-fp32 params the seed instead rounded
             # at unpack and then squared/multiplied in param dtype — same
             # math, one fewer low-bit rounding here (fp32 params: identical)
-            if tc.guard:
-                gscale, skipped = guard_scale(
-                    loss_frac, skip_threshold=sync_cfg.skip_threshold)
-            else:
-                gscale = jnp.ones(())
-                skipped = jnp.zeros((), jnp.bool_)
-            # norm-after-guard == guard_scale * norm (the scale is 0 or 1)
-            gnorm = gscale * packed_global_norm(synced, plan, p_specs)
-            clip_scale = jnp.minimum(
-                1.0, tc.optimizer.grad_clip / jnp.maximum(gnorm, 1e-9))
-            synced = synced * (gscale * clip_scale)
-            grads = plan.unpack(synced)
-        lr = jnp.asarray(tc.optimizer.lr, jnp.float32)
-        new_params, new_opt = opt.update(grads, opt_state, params, lr, step)
+            with jax.named_scope(scopes.OPTIMIZER):
+                if tc.guard:
+                    gscale, skipped = guard_scale(
+                        loss_frac, skip_threshold=sync_cfg.skip_threshold)
+                else:
+                    gscale = jnp.ones(())
+                    skipped = jnp.zeros((), jnp.bool_)
+                # norm-after-guard == guard_scale * norm (the scale is 0 or 1)
+                gnorm = gscale * packed_global_norm(synced, plan, p_specs)
+                clip_scale = jnp.minimum(
+                    1.0, tc.optimizer.grad_clip / jnp.maximum(gnorm, 1e-9))
+                synced = synced * (gscale * clip_scale)
+                grads = plan.unpack(synced)
+        with jax.named_scope(scopes.OPTIMIZER):
+            lr = jnp.asarray(tc.optimizer.lr, jnp.float32)
+            new_params, new_opt = opt.update(grads, opt_state, params, lr,
+                                             step)
 
         metrics = {
             "loss": jax.lax.pmean(loss, dp_axes) if dp_axes else loss,

@@ -22,6 +22,7 @@ _MEDIANS = (
     "obs/disabled_gate_median_us",
     "obs/enabled_complete_median_us",
     "obs/enabled_event_median_us",
+    "obs/enabled_span_median_us",
     "obs/hist_record_median_us",
     "obs/wire_step_untraced_median_us",
     "obs/wire_step_traced_median_us",

@@ -55,3 +55,19 @@ def test_entries_land_in_the_env_dir(tmp_path):
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert any(tmp_path.iterdir())
+
+
+def test_cache_key_includes_metadata(monkeypatch, tmp_path):
+    """Programs that differ only in their named scopes get separate
+    entries, so a cached executable never carries another program's
+    operation names into a device trace."""
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_include_metadata_in_key
+    try:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          before)

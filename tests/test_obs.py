@@ -15,9 +15,8 @@ try:
 except ImportError:
     from _hypothesis_fallback import given, settings, strategies as st
 
-from repro.obs import (Counter, Gauge, MetricsRegistry, TailHistogram,
-                       TraceSchemaError, metrics, to_trace_events, trace,
-                       trace_payload, validate_trace, write_trace)
+from repro.obs import (TailHistogram, TraceSchemaError, to_trace_events,
+                       trace, trace_payload, validate_trace, write_trace)
 from repro.obs import report as obs_report
 
 
@@ -224,32 +223,6 @@ def test_hist_recorded_count_equals_fed(n, sigma):
     assert h.count == n
     assert int(h.counts.sum()) == n
     assert h.summary()["count"] == n
-
-
-class TestMetricsRegistry:
-    def test_get_or_create_and_snapshot(self):
-        reg = MetricsRegistry()
-        reg.counter("drops").inc()
-        reg.counter("drops").inc(2.0)
-        reg.gauge("phase").set(0.4)
-        reg.histogram("round_us").record(10.0)
-        snap = reg.snapshot()
-        assert snap["counters"]["drops"] == 3.0
-        assert snap["gauges"]["phase"] == 0.4
-        assert snap["histograms"]["round_us"]["count"] == 1
-        reg.clear()
-        assert reg.snapshot() == {"counters": {}, "gauges": {},
-                                  "histograms": {}}
-
-    def test_process_global_singleton(self):
-        assert metrics() is metrics()
-
-    def test_counter_gauge_primitives(self):
-        c, g = Counter(), Gauge()
-        assert c.value == 0.0 and math.isnan(g.value)
-        c.inc(5)
-        g.set(2)
-        assert c.value == 5.0 and g.value == 2.0
 
 
 # --------------------------------------------------------------------- export
