@@ -13,6 +13,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.attention import ops as attention_ops
+from repro.obs import scopes
+
 from .parallel import ParallelCtx
 
 
@@ -157,17 +160,14 @@ def attention_train(x: jnp.ndarray, w: dict, pctx: ParallelCtx, *,
 
     scale = 1.0 / math.sqrt(dh)
     attn_chunk = w.get("attn_chunk", 0)
-    if attn_chunk and s > attn_chunk:
-        ctx = _chunked_causal_attention(q, k, v, scale=scale,
-                                        chunk=attn_chunk)
-    else:
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            mask = jnp.tril(jnp.ones((s, s), jnp.bool_))
-            logits = jnp.where(mask[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    with jax.named_scope(scopes.ATTENTION):
+        if attn_chunk and s > attn_chunk:
+            ctx = _chunked_causal_attention(q, k, v, scale=scale,
+                                            chunk=attn_chunk)
+        else:
+            # the fused kernel on a TPU where it applies, else dense
+            ctx = attention_ops.attention(q, k, v, scale=scale,
+                                          causal=causal)
     ctx = ctx.reshape(b, s, hq * dh)
     out = jnp.einsum("bsh,hd->bsd", ctx, w["wo"].astype(x.dtype))
     out = pctx.reduce_output(out)   # psum, or psum_scatter(seq) under SP

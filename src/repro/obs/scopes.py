@@ -13,3 +13,8 @@ DECODE = "decode"
 OPTIMIZER = "optimizer"    # guard, global norm, clip, unpack, update
 
 ALL = (FWD_BWD, SYNC, ENCODE, EXCHANGE, DECODE, OPTIMIZER)
+
+# Inside ``fwd_bwd``, not a layer of the step: the attention core of
+# models/layers.py ``attention_train`` (scores, mask, softmax, P·V, or the
+# fused kernel that replaces them, and their gradients).
+ATTENTION = "attention"

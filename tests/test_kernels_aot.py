@@ -6,13 +6,16 @@ bucket gives it (6,553,600 fp32 = 6400 Hadamard blocks of 1024; 4-way DP
 shards of 1,638,400) and must come out as a ``tpu_custom_call``. This
 catches what interpret mode cannot: lane-splitting reshapes, casts Mosaic
 lacks, unaligned DMA slices. Nothing runs, so it says nothing about
-results or speed.
+results or speed. The fused attention kernel of the train step compiles
+here too, forward and backward, at a gpt2-paper layer of the benchmark's
+64 x 1024 and at a head size of 128.
 
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker given this
 file loads the TPU library. The persistent compile cache is off around
 these compiles: entries written without a chip cannot be read back.
 """
+import math
 import os
 
 import jax
@@ -20,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.attention import block_size, causal_attention_pallas
 from repro.kernels.dequant_reduce.dequant_reduce import \
     dequant_masked_mean_pallas
 from repro.kernels.fwht.fwht import fwht_pallas
@@ -98,3 +102,24 @@ def test_kernel_compiles_for_v5e_at_one_bucket(name, one_chip):
     args = [_sds(one_chip, *spec) for spec in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# (B, S, H, dh) of the attention kernel's q, k and v
+ATTENTION_SHAPES = {"gpt2_paper": (64, 1024, 12, 64),
+                    "head_dim_128": (8, 2048, 32, 128)}
+
+
+@pytest.mark.parametrize("name", list(ATTENTION_SHAPES))
+def test_attention_kernel_compiles_for_v5e(name, one_chip):
+    b, s, h, dh = ATTENTION_SHAPES[name]
+    arg = _sds(one_chip, (b, s, h, dh), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: causal_attention_pallas(
+            q, k, v, 1.0 / math.sqrt(dh), block_size(s), False), q, k, v)
+        return (o,) + vjp(do)
+
+    text = jax.jit(fwd_bwd).lower(arg, arg, arg, arg).compile().as_text()
+    for kernel in ("causal_attention_fwd", "causal_attention_bwd"):
+        assert any(kernel in line and "tpu_custom_call" in line
+                   for line in text.splitlines()), kernel

@@ -92,6 +92,29 @@ def test_every_scope_reaches_the_compiled_step(path):
                                            unscoped[:10])
 
 
+def test_attention_scope_lies_inside_the_step_scope():
+    """The attention core's scope names instructions of the optimized
+    step, forward and backward. Each of them also lies under the step's
+    forward/backward scope, whose time therefore still holds attention's,
+    except the causal mask's constant, which JAX builds outside autodiff
+    and which lies under no layer of the step. (The test's name must not
+    hold the step scope's name: it reaches the HLO's stack frames.)"""
+    attention = re.compile(rf"(^|\(){scopes.ATTENTION}(\)|$)")
+    paths = set()
+    for line in _compiled_text(
+            *PATHS["replicated-optireduce_q-pipelined"]).splitlines():
+        name = OP_NAME.search(line)
+        if name and any(attention.search(c)
+                        for c in name.group(1).split("/")):
+            paths.add(name.group(1))
+    inside = {p for p in paths if _innermost_scope(p) == scopes.FWD_BWD}
+    assert any("transpose(jvp(" in p for p in inside), sorted(paths)[:5]
+    outside = paths - inside
+    assert all(_innermost_scope(p) is None
+               and ("/jit(tril)/" in p or "/jit(_where)/" in p)
+               for p in outside), sorted(outside)
+
+
 def _strip_metadata(hlo: str) -> str:
     """The HLO text without ``metadata={...}`` and the source-location
     tables."""
