@@ -33,6 +33,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.obs import scopes
 from .layers import (KVCache, attention_decode, attention_train, gated_mlp,
                      rms_norm)
 from .moe import moe_block
@@ -410,16 +411,25 @@ def _apply_layer(x, lw, cfg: ModelConfig, lay: TpLayout, pctx: ParallelCtx,
 
 def embed_tokens(params, tokens, cfg: ModelConfig, lay: TpLayout,
                  pctx: ParallelCtx, key=None) -> jnp.ndarray:
-    """Vocab-sharded embedding lookup: local-range gather + psum over tp."""
+    """Vocab-sharded embedding lookup: local-range gather + psum over tp.
+
+    Rows are gathered from a float32 view of the table, so the gather's
+    transpose, the scatter-add of the gradient, sums a token's
+    contributions in float32 and casts to the table's dtype once. (A bf16
+    scatter-add loses much of a frequent token's thousands of same-signed
+    contributions.) The rows looked up are unchanged: a bf16 value is
+    exact in float32.
+    """
     emb = _maybe_gather(pctx, params["embed"], 1, key)   # (V_local, D)
-    v_local = emb.shape[0]
-    shard = pctx.tp_index()
-    lo = shard * v_local
-    local = tokens - lo
-    valid = (local >= 0) & (local < v_local)
-    safe = jnp.clip(local, 0, v_local - 1)
-    out = jnp.take(emb, safe, axis=0)
-    out = jnp.where(valid[..., None], out, 0).astype(cfg.param_dtype)
+    with jax.named_scope(scopes.EMBED):
+        v_local = emb.shape[0]
+        shard = pctx.tp_index()
+        lo = shard * v_local
+        local = tokens - lo
+        valid = (local >= 0) & (local < v_local)
+        safe = jnp.clip(local, 0, v_local - 1)
+        out = jnp.take(emb.astype(jnp.float32), safe, axis=0)
+        out = jnp.where(valid[..., None], out, 0).astype(cfg.param_dtype)
     return pctx.psum_tp(out)
 
 
@@ -512,50 +522,56 @@ def lm_loss(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,
         head_l = emb.T                    # (D, V_local)
     else:
         head_l = _maybe_gather(pctx, head, 0, key)
-    v_local = head_l.shape[1]
-    shard = pctx.tp_index()
-    lo = shard * v_local
+    with jax.named_scope(scopes.LM_HEAD):
+        v_local = head_l.shape[1]
+        shard = pctx.tp_index()
+        lo = shard * v_local
 
-    b, s, d = x.shape
-    chunk = math.gcd(min(seq_chunk, s), s)   # frontend prefixes may leave
-    # a token count that is not a multiple of the requested chunk
-    xc = x.reshape(b, s // chunk, chunk, d).transpose(1, 0, 2, 3)
-    yc = labels.reshape(b, s // chunk, chunk).transpose(1, 0, 2)
+        b, s, d = x.shape
+        # frontend prefixes may leave a token count that is not a multiple
+        # of the requested chunk
+        chunk = math.gcd(min(seq_chunk, s), s)
+        xc = x.reshape(b, s // chunk, chunk, d).transpose(1, 0, 2, 3)
+        yc = labels.reshape(b, s // chunk, chunk).transpose(1, 0, 2)
 
-    def chunk_loss(x_chunk, y_chunk):
-        logits = jnp.einsum("bcd,dv->bcv", x_chunk.astype(jnp.float32),
-                            head_l.astype(jnp.float32))
-        # max-shift is gradient-neutral; pmax has no VJP, so detach first
-        m = pctx.pmax_tp(jnp.max(jax.lax.stop_gradient(logits), axis=-1))
-        z = pctx.psum_tp(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
-        local_y = y_chunk - lo
-        valid = (local_y >= 0) & (local_y < v_local)
-        safe = jnp.clip(local_y, 0, v_local - 1)
-        picked = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        correct = pctx.psum_tp(jnp.where(valid, picked, 0.0))
-        weight = (y_chunk >= 0).astype(jnp.float32)
-        nll = (jnp.log(z) + m - correct) * weight
-        return jnp.sum(nll), jnp.sum(weight)
+        def chunk_loss(x_chunk, y_chunk):
+            logits = jnp.einsum("bcd,dv->bcv", x_chunk.astype(jnp.float32),
+                                head_l.astype(jnp.float32))
+            # max-shift is gradient-neutral; pmax has no VJP, so detach
+            # first
+            m = pctx.pmax_tp(jnp.max(jax.lax.stop_gradient(logits),
+                                     axis=-1))
+            z = pctx.psum_tp(jnp.sum(jnp.exp(logits - m[..., None]),
+                                     axis=-1))
+            local_y = y_chunk - lo
+            valid = (local_y >= 0) & (local_y < v_local)
+            safe = jnp.clip(local_y, 0, v_local - 1)
+            picked = jnp.take_along_axis(logits, safe[..., None],
+                                         axis=-1)[..., 0]
+            correct = pctx.psum_tp(jnp.where(valid, picked, 0.0))
+            weight = (y_chunk >= 0).astype(jnp.float32)
+            nll = (jnp.log(z) + m - correct) * weight
+            return jnp.sum(nll), jnp.sum(weight)
 
-    if remat:
-        chunk_loss = jax.checkpoint(chunk_loss)
+        if remat:
+            chunk_loss = jax.checkpoint(chunk_loss)
 
-    if xc.shape[0] == 1 or unroll:
-        total = jnp.zeros(())
-        count = jnp.zeros(())
-        for i in range(xc.shape[0]):
-            l, w = chunk_loss(xc[i], yc[i])
-            total = total + l
-            count = count + w
-    else:
-        def scan_body(carry, inp):
-            tot, cnt = carry
-            l, w = chunk_loss(*inp)
-            return (tot + l, cnt + w), None
+        if xc.shape[0] == 1 or unroll:
+            total = jnp.zeros(())
+            count = jnp.zeros(())
+            for i in range(xc.shape[0]):
+                l, w = chunk_loss(xc[i], yc[i])
+                total = total + l
+                count = count + w
+        else:
+            def scan_body(carry, inp):
+                tot, cnt = carry
+                l, w = chunk_loss(*inp)
+                return (tot + l, cnt + w), None
 
-        (total, count), _ = jax.lax.scan(
-            scan_body, (jnp.zeros(()), jnp.zeros(())), (xc, yc))
-    return total / jnp.maximum(count, 1.0)
+            (total, count), _ = jax.lax.scan(
+                scan_body, (jnp.zeros(()), jnp.zeros(())), (xc, yc))
+        return total / jnp.maximum(count, 1.0)
 
 
 def prefill_step(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,

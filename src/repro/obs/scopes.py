@@ -14,7 +14,11 @@ OPTIMIZER = "optimizer"    # guard, global norm, clip, unpack, update
 
 ALL = (FWD_BWD, SYNC, ENCODE, EXCHANGE, DECODE, OPTIMIZER)
 
-# Inside ``fwd_bwd``, not a layer of the step: the attention core of
+# Inside ``fwd_bwd``, not layers of the step: the attention core of
 # models/layers.py ``attention_train`` (scores, mask, softmax, P·V, or the
-# fused kernel that replaces them, and their gradients).
+# fused kernel that replaces them, and their gradients); the token lookup
+# of models/transformer.py ``embed_tokens`` (and its gradient's float32
+# scatter-add); the head, logits and cross-entropy of ``lm_loss``.
 ATTENTION = "attention"
+EMBED = "embed"
+LM_HEAD = "lm_head"

@@ -115,6 +115,29 @@ def test_attention_scope_lies_inside_the_step_scope():
                for p in outside), sorted(outside)
 
 
+def _op_names(text: str, scope: str) -> set:
+    """The ``op_name`` paths of a compiled step that have a component
+    naming ``scope``."""
+    pat = re.compile(rf"(^|\(){scope}(\)|$)")
+    return {m.group(1) for m in OP_NAME.finditer(text)
+            if any(pat.search(c) for c in m.group(1).split("/"))}
+
+
+@pytest.mark.parametrize("path", ["replicated-optireduce_q-pipelined",
+                                  "fsdp-optireduce-pipelined"])
+def test_embed_and_lm_head_scopes_lie_inside_the_step_scope(path):
+    """The token lookup's and the LM head's scopes name instructions of
+    the optimized step, forward and gradient (a ``transpose(jvp(...))``
+    path), each under the step's forward/backward scope, and no
+    instruction of the sync or the optimizer."""
+    text = _compiled_text(*PATHS[path])
+    for scope in (scopes.EMBED, scopes.LM_HEAD):
+        paths = _op_names(text, scope)
+        assert any("transpose(jvp(" in p for p in paths), (scope, paths)
+        assert {_innermost_scope(p) for p in paths} == {scopes.FWD_BWD}, \
+            (scope, sorted(paths)[:5])
+
+
 def _strip_metadata(hlo: str) -> str:
     """The HLO text without ``metadata={...}`` and the source-location
     tables."""
@@ -133,8 +156,9 @@ def _strip_metadata(hlo: str) -> str:
 
 
 def test_scopes_change_only_metadata(monkeypatch):
-    """The optimized step with every named scope replaced by a no-op is
-    the same program once metadata is stripped."""
+    """The optimized step with every named scope (the step's layers and
+    those inside ``fwd_bwd``: attention, embed, lm_head) replaced by a
+    no-op is the same program once metadata is stripped."""
     path = PATHS["replicated-optireduce_q-pipelined"]
     scoped = _compiled_text(*path)
     assert "fwd_bwd" in scoped
@@ -142,6 +166,8 @@ def test_scopes_change_only_metadata(monkeypatch):
                         lambda name: contextlib.nullcontext())
     plain = _compiled_text(*path)
     assert "fwd_bwd" not in plain
+    for scope in (scopes.EMBED, scopes.LM_HEAD):
+        assert _op_names(scoped, scope) and not _op_names(plain, scope)
     assert _strip_metadata(scoped) == _strip_metadata(plain)
 
 
