@@ -164,11 +164,11 @@ def run(quick: bool = True) -> Rows:
     us = _t(lambda: dequant_masked_mean(codes, lo_b, step_b, mk2, block=blk))
     rows.add(f"kernels/dequant_masked_mean_L{s}", us,
              f"us/call one-pass jnp form; pallas_vs_oracle_err={err:.2e}")
-    lo_r = jnp.broadcast_to(lo_b.reshape(nblk, 1), (nblk, blk)).reshape(-1)
-    step_r = jnp.broadcast_to(step_b.reshape(nblk, 1),
-                              (nblk, blk)).reshape(-1)
     _device_rows(rows, f"kernels/dequant_masked_mean_L{s}",
-                 lambda: dequant_masked_mean_pallas(codes, lo_r, step_r, mk2))
+                 lambda: dequant_masked_mean_pallas(
+                     codes.reshape(n_peers, nblk, blk),
+                     lo_b.reshape(nblk, 1), step_b.reshape(nblk, 1),
+                     mk2.reshape(n_peers, nblk, blk)))
     return rows
 
 

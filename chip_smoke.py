@@ -10,8 +10,8 @@
     process (its mesh, shardings, donation and data path). Losses must be
     finite and the last below step 0's.
 (b) ``sync_packed`` with ``optireduce_q`` at 1% drop over a
-    gpt2-paper-shaped gradient arena (``BucketPlan.for_tree`` of its params:
-    24 buckets of 25 MB), once through the
+    gpt2-paper-shaped gradient arena (the trainer's ``arena_plan`` of its
+    params: 24 buckets of 25 MB, each 6400 x 1024), once through the
     Mosaic-compiled codec kernels and once through the jnp codec path, on
     the same arena and key. The two must agree (``KERNEL_REL_TOL``), and
     each must reproduce the arena to within 8-bit quantization noise.
@@ -141,19 +141,20 @@ def phase_kernels(*, seed: int):
     from repro.configs import get_config
     from repro.core.allreduce import OptiReduceConfig, SyncContext, \
         sync_packed
-    from repro.core.bucket_plan import BucketPlan
     from repro.launch.mesh import make_host_mesh
     from repro.models import init_params
-    from repro.train.trainer import TrainConfig
+    from repro.train.trainer import TrainConfig, arena_plan
 
     cfg = get_config(ARCH)
     key = jax.random.PRNGKey(seed)
     shapes = jax.eval_shape(lambda: init_params(key, cfg, tp=1))
-    plan = BucketPlan.for_tree(shapes, TrainConfig().bucket_elems)
+    # the trainer's bucket-major arena (both codecs share the 1024 block)
+    plan = arena_plan(shapes, TrainConfig(sync=OptiReduceConfig(
+        strategy="optireduce_q", hadamard_block=1024)))
     smoke(f"(b) arena: {plan.total} gradient elements in "
           f"{plan.num_buckets} buckets of {plan.bucket_elems}")
     arena = 1e-3 * jax.random.normal(jax.random.fold_in(key, 1),
-                                     (plan.num_buckets, plan.bucket_elems))
+                                     plan.arena_shape)
     mesh = make_host_mesh(dp=1)
     outs = {}
     for use_kernels in (True, False):

@@ -13,10 +13,11 @@ quantization, kernel-dispatched under ``cfg.use_kernels``).  See DESIGN.md
 This module keeps the stable, config-driven surface:
 
   ``OptiReduceConfig`` / ``SyncContext``  — static knobs + per-step context
-  ``sync_bucket``        — one flat bucket through the resolved spec
-  ``sync_packed``        — the fused engine core on a pre-packed
-                           (B, bucket_elems) batch: scan / vmap / the
-                           stage-skewed ``pipelined`` software schedule
+  ``sync_bucket``        — one bucket through the resolved spec
+  ``sync_packed``        — the fused engine core on a pre-packed arena,
+                           bucket-major (B, R, L) or flat (B, bucket_elems):
+                           scan / vmap / the stage-skewed ``pipelined``
+                           software schedule (in place over the arena)
   ``sync_pytree``        — pack -> ``sync_packed`` -> unpack over a static
                            :class:`BucketPlan`
   ``sync_pytree_unfused``— the seed bucketing loop, kept as the bitwise
@@ -59,7 +60,7 @@ from repro.obs import scopes
 from .bucket_plan import BucketPlan, bucket_keys
 from .pipeline import (CollectiveSpec, Hadamard, HTQuant, Identity, Lossy,
                        OptiReduceConfig, Reliable, SyncContext, TarTopology,
-                       Topology, register_strategy, resolve_spec,
+                       Topology, arena_lane, register_strategy, resolve_spec,
                        strategy_names)
 
 __all__ = [
@@ -76,7 +77,8 @@ def strategies() -> tuple[str, ...]:
 
 def sync_bucket(bucket: jnp.ndarray, ctx: SyncContext,
                 spec: CollectiveSpec | None = None) -> jnp.ndarray:
-    """Reduce one flat bucket to its (approximate) DP mean.
+    """Reduce one bucket (flat, or a tile-dense (R, L) slab) to its
+    (approximate) DP mean, in the bucket's shape.
 
     Resolves ``ctx.cfg.strategy`` through the spec registry unless an
     explicit ``spec`` is given (e.g. an unregistered composition or one
@@ -95,15 +97,20 @@ def sync_pytree(grads, ctx: SyncContext, *, bucket_elems: int = 6_553_600,
 
     Buckets follow a static :class:`BucketPlan` (leaf->bucket layout from
     the treedef/shapes, computed once — pass ``plan`` to reuse it): leaves
-    are packed into one ``(B, bucket_elems)`` batch and the strategy
+    are packed into one batch — the tile-dense ``(B, R, L)`` arena at the
+    spec's :func:`~repro.core.pipeline.arena_lane` where ``L`` divides the
+    bucket, else ``(B, bucket_elems)`` — and the strategy
     pipeline runs as a single traced body — constant HLO size in B and no
     second full-gradient materialization. ``mode`` picks the schedule
     tradeoff (see :func:`sync_packed`); all modes are bitwise-identical to
     :func:`sync_pytree_unfused`.
     """
+    if spec is None:
+        spec = resolve_spec(ctx.cfg)
     if plan is None:
-        plan = BucketPlan.for_tree(grads, bucket_elems)
-    batch = plan.pack(grads)                         # (B, bucket_elems)
+        plan = BucketPlan.for_tree(grads, bucket_elems,
+                                   lane=arena_lane(spec, ctx.cfg))
+    batch = plan.pack(grads)                         # plan.arena_shape
     return plan.unpack(sync_packed(batch, ctx, mode=mode, spec=spec))
 
 
@@ -112,7 +119,7 @@ def _sync_pipelined(batch, keys, ctx: SyncContext, spec: CollectiveSpec,
     """Stage-skewed software pipeline over the bucket axis (depth-2 skew).
 
     Iteration k *encodes* bucket k, *exchanges* bucket k-1, and *decodes*
-    bucket k-2; the in-flight encoded/exchanged payloads ride in the scan
+    bucket k-2; the in-flight encoded/exchanged payloads ride in the loop
     carry, so within each traced step the exchange collectives of bucket
     k-1 have no data dependency on the encode/decode kernels of buckets
     k / k-2 and XLA's async collectives genuinely overlap with neighboring
@@ -121,6 +128,12 @@ def _sync_pipelined(batch, keys, ctx: SyncContext, spec: CollectiveSpec,
     window is empty and the whole schedule unrolls (the skew is deeper than
     the bucket count).
 
+    The arena itself rides the carry and is updated in place: iteration k
+    reads bucket k with a dynamic index and writes decoded bucket k-2 back
+    with a ``dynamic_update_slice`` into the same buffer.  Every write is
+    two buckets behind the read, so no bucket is overwritten before it is
+    encoded, and no stacked outputs or final concatenation exist.
+
     Per-bucket computation is the same encode/exchange/decode composition
     ``CollectiveSpec.all_reduce`` runs, so results are bitwise-identical to
     the scan/vmap modes and the unfused oracle (pinned by the ``parity``
@@ -128,7 +141,7 @@ def _sync_pipelined(batch, keys, ctx: SyncContext, spec: CollectiveSpec,
     """
     cfg = ctx.cfg
     nbuckets = batch.shape[0]
-    length = batch.shape[-1]
+    shape = batch.shape[1:]
     recorded = False
 
     def enc(bucket, key, stale_b=None):
@@ -155,62 +168,71 @@ def _sync_pipelined(batch, keys, ctx: SyncContext, spec: CollectiveSpec,
         key, inner = state
         sctx = SyncContext(cfg=cfg, key=key)
         with jax.named_scope(scopes.DECODE):
-            return spec.decode_stage(inner, length, sctx)
+            return spec.decode_stage(inner, shape, sctx)
 
-    def stale_at(it):
-        return None if stale is None else stale[it]
+    def at(x, k):
+        return None if x is None else jax.lax.dynamic_index_in_dim(
+            x, k, 0, keepdims=False)
 
+    def put(arena, k, bucket):
+        # the barrier keeps the decode kernel a custom call of its own: the
+        # TPU compiler would otherwise fuse it into the update, and that
+        # fusion takes the kernel's name, which the trace's per-kernel
+        # accounting (chipbench/codec_kernels.py) reads as the kernel
+        return jax.lax.dynamic_update_index_in_dim(
+            arena, jax.lax.optimization_barrier(bucket), k, 0)
+
+    arena = batch
     dropped = total = jnp.zeros(())
     if nbuckets <= 3:
         # fully unrolled: prologue/epilogue swallow the steady-state window
         enc_live: dict = {}
         exch_live: dict = {}
-        outs = [None] * nbuckets
         for it in range(nbuckets + 2):
             if it < nbuckets:
-                enc_live[it] = enc(batch[it], keys[it], stale_at(it))
+                enc_live[it] = enc(arena[it], keys[it],
+                                   None if stale is None else stale[it])
             if 0 <= it - 1 < nbuckets:
                 exch_live[it - 1], (d, t) = exch(enc_live.pop(it - 1))
                 dropped, total = dropped + d, total + t
             if 0 <= it - 2 < nbuckets:
-                outs[it - 2] = dec(exch_live.pop(it - 2))
-        return jnp.stack(outs), (dropped, total), recorded
+                arena = put(arena, it - 2, dec(exch_live.pop(it - 2)))
+        return arena, (dropped, total), recorded
 
     # prologue: fill the two pipeline registers
-    e_carry = enc(batch[0], keys[0], stale_at(0))
-    e_next = enc(batch[1], keys[1], stale_at(1))
+    e_carry = enc(arena[0], keys[0], None if stale is None else stale[0])
+    e_next = enc(arena[1], keys[1], None if stale is None else stale[1])
     x_carry, (d, t) = exch(e_carry)
     dropped, total = dropped + d, total + t
 
-    def body(carry, inp):
-        (cd, ct), e_prev, x_prev = carry
-        bucket, key = inp[0], inp[1]
-        e_k = enc(bucket, key,                 # encode bucket k
-                  inp[2] if stale is not None else None)
-        x_k, (d, t) = exch(e_prev)             # exchange bucket k-1
-        out = dec(x_prev)                      # decode bucket k-2
-        return ((cd + d, ct + t), e_k, x_k), out
+    def body(k, carry):
+        (cd, ct), e_prev, x_prev, arena = carry
+        e_k = enc(at(arena, k), at(keys, k), at(stale, k))  # encode k
+        x_k, (d, t) = exch(e_prev)                          # exchange k-1
+        arena = put(arena, k - 2, dec(x_prev))              # decode k-2
+        return ((cd + d, ct + t), e_k, x_k, arena)
 
-    xs = (batch[2:], keys[2:]) if stale is None else \
-        (batch[2:], keys[2:], stale[2:])
-    ((d2, t2), e_last, x_last), mid = jax.lax.scan(
-        body, ((jnp.zeros(()), jnp.zeros(())), e_next, x_carry), xs)
+    (d2, t2), e_last, x_last, arena = jax.lax.fori_loop(
+        2, nbuckets, body,
+        ((jnp.zeros(()), jnp.zeros(())), e_next, x_carry, arena))
     dropped, total = dropped + d2, total + t2
 
     # epilogue: drain the registers for the last two buckets
     x_fin, (d, t) = exch(e_last)
     dropped, total = dropped + d, total + t
-    tail = jnp.stack([dec(x_last), dec(x_fin)])
-    return jnp.concatenate([mid, tail]), (dropped, total), recorded
+    arena = put(arena, nbuckets - 2, dec(x_last))
+    arena = put(arena, nbuckets - 1, dec(x_fin))
+    return arena, (dropped, total), recorded
 
 
 def sync_packed(batch: jnp.ndarray, ctx: SyncContext, *, mode: str = "scan",
                 spec: CollectiveSpec | None = None,
                 stale: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Sync an already-packed ``(B, bucket_elems)`` batch — the engine core
-    behind :func:`sync_pytree`, exposed so the trainer's packed gradient
-    arena can feed its accumulator straight in (no pack/unpack HBM passes
-    around the sync).
+    """Sync an already-packed batch — the bucket-major ``(B, R, L)`` arena
+    or a flat ``(B, bucket_elems)`` one; the result has its shape — the
+    engine core behind :func:`sync_pytree`, exposed so the trainer's packed
+    gradient arena can feed its accumulator straight in (no pack/unpack HBM
+    passes around the sync).
 
     ``mode``:
       ``'scan'``      one ``lax.scan``'d strategy body; buckets strictly
@@ -221,7 +243,8 @@ def sync_packed(batch: jnp.ndarray, ctx: SyncContext, *, mode: str = "scan",
                       bucket k, exchanges bucket k-1, decodes bucket k-2,
                       so the exchange collectives overlap neighboring
                       buckets' encode/decode kernels (depth-2 skew with
-                      unrolled prologue/epilogue).
+                      unrolled prologue/epilogue), writing each decoded
+                      bucket back into the arena in place.
     All modes are bitwise-identical per bucket (same stage composition).
     Per-bucket PRNG keys are ``fold_in(ctx.key, bucket_index)``, the seed
     loop's derivation.

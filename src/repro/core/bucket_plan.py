@@ -7,8 +7,11 @@ materializing a second full-size gradient copy via concatenate-then-slice.
 
 * built once from the pytree treedef + leaf shapes (hashable, so it can ride
   in jit static args or be cached by the trainer),
-* ``pack`` lays the flat gradient stream into ONE ``(B, bucket_elems)``
-  batch (a single full-size buffer; the last bucket zero-padded),
+* ``pack`` lays the flat gradient stream into ONE batch (a single
+  full-size buffer; the last bucket zero-padded): ``(B, R, L)`` when the
+  plan has a lane width ``L`` dividing ``bucket_elems`` (bucket k is then a
+  contiguous ``(R, L)`` slab of whole (8, 128) tiles), else the flat
+  ``(B, bucket_elems)`` view,
 * the engine then runs the strategy body once under ``lax.scan`` over the
   leading bucket axis (or vectorized via ``vmap``, or stage-skewed across
   buckets via ``mode="pipelined"`` — see ``allreduce.sync_packed``) — one
@@ -37,10 +40,18 @@ class BucketPlan:
     dtypes: tuple[str, ...]
     bucket_elems: int
     num_buckets: int
+    lane: int = 0                     # L of the (B, R, L) arena; 0: (B, E)
 
     @classmethod
-    def for_tree(cls, tree, bucket_elems: int) -> "BucketPlan":
-        """Plan from a pytree of arrays (or ShapeDtypeStructs)."""
+    def for_tree(cls, tree, bucket_elems: int,
+                 lane: int = 0) -> "BucketPlan":
+        """Plan from a pytree of arrays (or ShapeDtypeStructs).
+
+        ``lane`` asks for the bucket-major ``(B, R, L)`` arena (the sync
+        engine's :func:`~repro.core.pipeline.arena_lane`); it is kept only
+        when it divides the bucket length, else the plan falls back to the
+        flat ``(B, bucket_elems)`` view (e.g. a single bucket of odd
+        length)."""
         leaves, treedef = jax.tree.flatten(tree)
         shapes = tuple(tuple(leaf.shape) for leaf in leaves)
         dtypes = tuple(jnp.dtype(leaf.dtype).name for leaf in leaves)
@@ -48,8 +59,11 @@ class BucketPlan:
         num_buckets = max(1, -(-total // bucket_elems))
         if num_buckets == 1:
             bucket_elems = total        # single bucket: no tail padding
+        if lane <= 0 or bucket_elems % lane:
+            lane = 0
         return cls(treedef=treedef, shapes=shapes, dtypes=dtypes,
-                   bucket_elems=bucket_elems, num_buckets=num_buckets)
+                   bucket_elems=bucket_elems, num_buckets=num_buckets,
+                   lane=lane)
 
     @property
     def sizes(self) -> tuple[int, ...]:
@@ -64,6 +78,14 @@ class BucketPlan:
         return self.num_buckets * self.bucket_elems
 
     @property
+    def arena_shape(self) -> tuple[int, ...]:
+        """``(B, R, L)`` with a lane width, else ``(B, bucket_elems)``."""
+        if self.lane:
+            return (self.num_buckets, self.bucket_elems // self.lane,
+                    self.lane)
+        return (self.num_buckets, self.bucket_elems)
+
+    @property
     def offsets(self) -> tuple[int, ...]:
         """Flat-stream start offset of each leaf (pytree order) — the arena
         coordinates consumers like ``packed_global_norm`` reduce over."""
@@ -75,8 +97,8 @@ class BucketPlan:
         return tuple(offs)
 
     def pack(self, tree, dtype=jnp.float32) -> jnp.ndarray:
-        """Flatten leaves (pytree order) into one (B, bucket_elems) batch —
-        the engine's only full-size buffer.  ``dtype`` defaults to fp32 (the
+        """Flatten leaves (pytree order) into one :attr:`arena_shape` batch
+        — the engine's only full-size buffer.  ``dtype`` defaults to fp32 (the
         sync engine's wire dtype); the trainer's packed gradient arena packs
         micro-batch grads in ``accum_dtype`` and accumulates in packed space
         (the per-leaf cast-then-concatenate is elementwise identical to the
@@ -87,16 +109,46 @@ class BucketPlan:
         if pad:
             parts.append(jnp.zeros((pad,), dtype))
         flat = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-        return flat.reshape(self.num_buckets, self.bucket_elems)
+        return flat.reshape(self.arena_shape)
+
+    def leaf_view(self, batch: jnp.ndarray, off: int,
+                  size: int) -> jnp.ndarray:
+        """Flat ``[off, off + size)`` of the stream, read from the arena.
+
+        The dense arena is never flattened whole: the range is cut from
+        the rows it spans in the ``(B * R, L)`` row view (a free reshape of
+        ``(B, R, L)``), so each leaf's relayout is its own size and can
+        fuse into its consumer."""
+        if not self.lane:
+            return batch.reshape(-1)[off:off + size]
+        lane = self.lane
+        r0, r1 = off // lane, -(-(off + size) // lane)
+        rows = batch.reshape(-1, lane)[r0:r1]
+        start = off - r0 * lane
+        return rows.reshape(-1)[start:start + size]
+
+    def sum_squares(self, batch: jnp.ndarray, a: int,
+                    b: int) -> jnp.ndarray:
+        """fp32 sum of squares of the stream's ``[a, b)``, read in place:
+        the dense arena's rows it spans, edges cut by a flat-index mask
+        (no relayout of the arena)."""
+        if not self.lane:
+            seg = batch.reshape(-1)[a:b].astype(jnp.float32)
+            return jnp.sum(jnp.square(seg))
+        lane = self.lane
+        r0, r1 = a // lane, -(-b // lane)
+        rows = batch.reshape(-1, lane)[r0:r1].astype(jnp.float32)
+        idx = ((r0 + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0))
+               * lane + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1))
+        inside = (idx >= a) & (idx < b)
+        return jnp.sum(jnp.where(inside, jnp.square(rows), 0.0))
 
     def unpack(self, batch: jnp.ndarray):
-        """Inverse of ``pack``: (B, bucket_elems) -> original pytree."""
-        flat = batch.reshape(-1)
-        leaves = []
-        off = 0
-        for shape, dtype, size in zip(self.shapes, self.dtypes, self.sizes):
-            leaves.append(flat[off:off + size].reshape(shape).astype(dtype))
-            off += size
+        """Inverse of ``pack``: :attr:`arena_shape` -> original pytree."""
+        leaves = [self.leaf_view(batch, off, size).reshape(shape)
+                  .astype(dtype)
+                  for shape, dtype, size, off in zip(
+                      self.shapes, self.dtypes, self.sizes, self.offsets)]
         return jax.tree.unflatten(self.treedef, leaves)
 
     def bucket_keys(self, key: jax.Array) -> jax.Array:

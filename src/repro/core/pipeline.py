@@ -58,6 +58,7 @@ from . import tar as tar_lib
 from .hadamard import ht_decode, ht_encode, ht_encode_amax, ht_encode_quant
 from .ubt import UbtState
 from repro.kernels.dequant_reduce import dequant_masked_mean
+from repro.kernels.masked_sum.masked_sum import LANES
 from repro.kernels.quant import grid_quant
 from repro.obs import scopes
 
@@ -214,6 +215,22 @@ def _mask_for(ctx: SyncContext, n: int, s: int, axis: str,
                                else self_index)
 
 
+def arena_lane(spec: "CollectiveSpec", cfg: OptiReduceConfig) -> int:
+    """Lane width L of the sync engine's bucket-major ``(B, R, L)`` arena.
+
+    The codec's block when it is whole 128-lane vregs — each arena row is
+    then one codec row, so the kernels' ``(rows, block)`` view of a bucket
+    is the arena's own slab, with no relayout — 128 for a codec without
+    blocks, and 0 (the flat ``(B, E)`` view) for a block that does not fill
+    whole vregs.  ``BucketPlan`` keeps it only where it divides the bucket
+    length; the TAR stages keep the rows only where the padded shard is
+    whole rows (:func:`~repro.core.tar.tar_view`)."""
+    block = spec.codec.block(cfg)
+    if block <= 1:
+        return LANES
+    return block if block % LANES == 0 else 0
+
+
 # ------------------------------------------------------------------- codecs
 @dataclasses.dataclass
 class Encoded:
@@ -365,15 +382,16 @@ class HTQuant(Codec):
         lo = -amax
         noise = jax.random.uniform(
             jax.random.fold_in(ctx.key, self.noise_salt),
-            (x1.shape[0] // block, block))
+            (x1.size // block, block))
         if cfg.use_kernels:
             codes = ht_encode_quant(x1, ctx.key, noise, lo, step, block=block,
-                                    bits=bits, use_kernel=True).reshape(-1)
+                                    bits=bits, use_kernel=True)
         else:
             xb = x1.reshape(-1, block)
             q = jnp.floor((xb - lo[:, None]) / step[:, None] + noise)
-            codes = jnp.clip(q, 0, levels).astype(jnp.uint8).reshape(-1)
-        return Encoded(codes, lo=lo, step=step)
+            codes = jnp.clip(q, 0, levels).astype(jnp.uint8)
+        # the codes keep the bucket's layout: flat, or its (rows, L) slab
+        return Encoded(codes.reshape(x1.shape), lo=lo, step=step)
 
     def encode(self, x, ctx, axis):
         x1, amax = self.local_amax(x, ctx)
@@ -384,35 +402,36 @@ class HTQuant(Codec):
         return self.encode_given_amax(x1, amax, ctx)
 
     def reduce(self, received, mask, shard_index, enc, ctx):
+        # received: (N, S) flat or (N, S/block, block) tile-dense codes
         cfg = ctx.cfg
         block = cfg.hadamard_block
-        n, s = received.shape
-        nblk = s // block
+        n = received.shape[0]
+        nblk = received[0].size // block
         my_lo, my_step = self._grids(enc, shard_index, nblk)
         if cfg.use_kernels:
             return dequant_masked_mean(received, my_lo, my_step, mask,
                                        block=block, use_kernel=True)
         vals = (received.reshape(n, nblk, block).astype(jnp.float32)
                 * my_step[None, :, None] + my_lo[None, :, None]
-                ).reshape(n, s)
+                ).reshape(received.shape)
         return tar_lib.masked_mean(vals, mask, cfg.use_kernels)
 
     def encode_shard(self, own, shard_index, enc, ctx):
         cfg = ctx.cfg
         block = cfg.hadamard_block
-        nblk = own.shape[0] // block
+        nblk = own.size // block
         my_lo, my_step = self._grids(enc, shard_index, nblk)
         noise = jax.random.uniform(
             jax.random.fold_in(ctx.key, self.stage2_salt), (nblk, block))
         codes = grid_quant(own.reshape(nblk, block), noise, my_lo, my_step,
                            bits=self._bits(cfg), use_kernel=cfg.use_kernels)
-        return codes.reshape(-1)
+        return codes.reshape(own.shape)
 
     def decode_gathered(self, gathered, enc, ctx):
         cfg = ctx.cfg
         block = cfg.hadamard_block
         out = (gathered.reshape(-1, block).astype(jnp.float32)
-               * enc.step[:, None] + enc.lo[:, None]).reshape(-1)
+               * enc.step[:, None] + enc.lo[:, None]).reshape(gathered.shape)
         return ht_decode(out, ctx.key, block=block,
                          use_kernel=cfg.use_kernels)
 
@@ -585,9 +604,11 @@ class WireTransport(Lossy):
         # XLA.)  Running several wire-bridged sync calls concurrently in
         # one program WOULD break the pairing; the launcher's fsdp/vmap/tp
         # guards rule those out.
+        # the host wire needs each shard's bytes in order: flatten a
+        # tile-dense payload to (n, S) here, and only here
         mask = io_callback(self._host_mask,
                            jax.ShapeDtypeStruct((n, s), jnp.float32),
-                           me, payload, ordered=False)
+                           me, payload.reshape(n, s), ordered=False)
         ctx.stats["dropped"] = ctx.stats.get("dropped", 0.0) + \
             jnp.sum(1.0 - mask)
         ctx.stats["total"] = ctx.stats.get("total", 0.0) + mask.size
@@ -607,8 +628,13 @@ class Topology:
       ``exchange_stage``  wire state -> gathered state (every collective of
                           the schedule, plus the small per-shard reduce
                           between TAR's two stages)
-      ``decode_stage``    gathered state -> flat synced bucket (codec decode
-                          + the unpad slice; kernel-heavy consumer side)
+      ``decode_stage``    gathered state -> synced bucket of the input's
+                          shape (codec decode + the unpad slice; kernel-
+                          heavy consumer side)
+
+    A bucket is flat ``(E,)`` or a tile-dense ``(R, L)`` slab of the
+    ``(B, R, L)`` arena; ``decode_stage`` takes the bucket's shape (or a
+    flat bucket's length) and returns that shape.
 
     Stage state is a flat tuple of arrays-or-None with a bucket-independent
     structure, so it can ride in a ``lax.scan`` carry.  ``all_reduce`` is
@@ -628,7 +654,7 @@ class Topology:
                        codec: Codec, ctx: SyncContext) -> tuple:
         raise NotImplementedError
 
-    def decode_stage(self, state: tuple, length: int, transport: Reliable,
+    def decode_stage(self, state: tuple, shape, transport: Reliable,
                      codec: Codec, ctx: SyncContext) -> jnp.ndarray:
         raise NotImplementedError
 
@@ -639,8 +665,8 @@ class Topology:
         with jax.named_scope(scopes.EXCHANGE):
             state = self.exchange_stage(state, transport, codec, ctx)
         with jax.named_scope(scopes.DECODE):
-            return self.decode_stage(state, bucket.shape[-1], transport,
-                                     codec, ctx)
+            return self.decode_stage(state, bucket.shape, transport, codec,
+                                     ctx)
 
     def reduce_scatter(self, g, axis, dim, transport, codec, ctx):
         raise NotImplementedError(
@@ -672,7 +698,7 @@ class PsumTopology(Topology):
         (bucket,) = state
         return (jax.lax.pmean(bucket, ctx.data_axes()),)
 
-    def decode_stage(self, state, length, transport, codec, ctx):
+    def decode_stage(self, state, shape, transport, codec, ctx):
         return state[0]
 
 
@@ -744,7 +770,9 @@ class RingTopology(Topology):
             pad_n = len(order)
         else:
             pad_n = n
-        x, _ = tar_lib.pad_for_tar(bucket, pad_n, codec.block(cfg))
+        # the ring schedules cut flat segments: a dense slab is flattened
+        x, _ = tar_lib.pad_for_tar(bucket.reshape(-1), pad_n,
+                                   codec.block(cfg))
         enc = codec.encode(x, ctx, cfg.data_axis)
         return (enc.data, enc.lo, enc.step)
 
@@ -772,12 +800,12 @@ class RingTopology(Topology):
             out = jax.lax.pmean(out, cfg.pod_axis)
         return (out, lo, step)
 
-    def decode_stage(self, state, length, transport, codec, ctx):
+    def decode_stage(self, state, shape, transport, codec, ctx):
         data, lo, step = state
         # the stage-1 encode output is gone by now — only the grids survive
         # the exchange, so the Encoded carries data=None rather than lying
         out = codec.decode_values(data, Encoded(None, lo=lo, step=step), ctx)
-        return out[:length]
+        return tar_lib.unpad_to(out, shape)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -819,9 +847,13 @@ class TarTopology(Topology):
         g = jax.lax.axis_size(cfg.pod_axis)
         if g <= 1:
             return own
-        if self.outer == "tar" and own.shape[0] % g == 0:
-            return tar_lib.tar_allreduce(own, cfg.pod_axis,
-                                         use_kernel=cfg.use_kernels)
+        if self.outer == "tar" and own.size % g == 0:
+            # the pods' split is the flat one: dense rows only if they cut
+            # into g whole-row chunks (the same elements)
+            x = own if own.shape[0] % g == 0 else own.reshape(-1)
+            return tar_lib.tar_allreduce(
+                x, cfg.pod_axis, use_kernel=cfg.use_kernels
+            ).reshape(own.shape)
         return jax.lax.pmean(own, cfg.pod_axis)
 
     def _participation(self, cfg: OptiReduceConfig, n: int):
@@ -861,7 +893,10 @@ class TarTopology(Topology):
             self._check_weighted(cfg, codec)
             # pad so the bucket cuts into sum(weights) block-aligned units
             n_shards = sum(weights)
-        x, _ = tar_lib.pad_for_tar(bucket, n_shards, codec.block(cfg))
+        # tile-dense rows where the shards are whole rows; weighted shards'
+        # unit rows take the flat view
+        x = tar_lib.tar_view(bucket, n_shards, codec.block(cfg),
+                             flat=weights is not None)
         if hasattr(codec, "local_amax"):
             # split encode (quantizing codec): emit only the pre-collective
             # half here; the grid pmax and the quantize ride the exchange
@@ -907,8 +942,8 @@ class TarTopology(Topology):
             s = plan.s_max
         else:
             plan = None
-            s = data.shape[0] // n_shards
-            shards = data.reshape(n_shards, s)
+            shards = tar_lib.shard_rows(data, n_shards)
+            s = shards[0].size
         if self.schedule == "rounds":
             received = tar_lib.tar_exchange_rounds(
                 shards, axis, incast=transport.incast(ctx), active=active,
@@ -931,14 +966,18 @@ class TarTopology(Topology):
         else:
             shard_index = i
             mask = transport.arrival_mask(ctx, n, s, axis, payload=shards)
-            if active is not None:
-                # a2a: exclude ejected senders' rows at EVERY receiver (the
-                # ejected peer's own row included, so replicas agree) — the
-                # masked-mean machinery compensates exactly like a drop
-                _, is_active = tar_lib.peer_lookup(active, n)
-                rows = is_active[:, None]
-                mask = jnp.broadcast_to(rows, (n, s)) if mask is None \
-                    else mask * rows
+        if mask is not None and received.ndim == 3:
+            # transports mask (n, S) shards; the tile-dense shard view takes
+            # the same values as (n, S/L, L)
+            mask = mask.reshape(received.shape)
+        if active is not None and self.schedule != "rounds":
+            # a2a: exclude ejected senders' rows at EVERY receiver (the
+            # ejected peer's own row included, so replicas agree) — the
+            # masked-mean machinery compensates exactly like a drop
+            _, is_active = tar_lib.peer_lookup(active, n)
+            rows = is_active.reshape((n,) + (1,) * (received.ndim - 1))
+            mask = jnp.broadcast_to(rows, received.shape) \
+                if mask is None else mask * rows
         own = codec.reduce(received, mask, shard_index, enc, ctx)
         if cfg.pod_axis is not None:
             own = self._outer_reduce(own, codec, ctx)
@@ -953,13 +992,13 @@ class TarTopology(Topology):
             gathered = jax.lax.all_gather(wire, axis, axis=0, tiled=True)
         return (gathered, lo, step, None, None)  # stale consumed in reduce
 
-    def decode_stage(self, state, length, transport, codec, ctx):
+    def decode_stage(self, state, shape, transport, codec, ctx):
         data, lo, step, _, _ = state
         # only the quantization grids survive the exchange; data=None marks
         # the stage-1 encode output as unavailable at decode time
         out = codec.decode_gathered(data, Encoded(None, lo=lo, step=step),
                                     ctx)
-        return out[:length]
+        return tar_lib.unpad_to(out, shape)
 
     def reduce_scatter(self, g, axis, dim, transport, codec, ctx):
         """TAR stage 1 + compensated reduce on an arbitrary tensor,
@@ -1017,7 +1056,8 @@ class CollectiveSpec:
         self.topology.validate(self.transport, self.codec)
 
     def all_reduce(self, bucket: jnp.ndarray, ctx: SyncContext) -> jnp.ndarray:
-        """Reduce one flat bucket to its (approximate) DP mean."""
+        """Reduce one bucket (flat, or a tile-dense (R, L) slab) to its
+        (approximate) DP mean, in the bucket's shape."""
         return self.topology.all_reduce(bucket, self.transport, self.codec,
                                         ctx)
 
@@ -1031,9 +1071,9 @@ class CollectiveSpec:
         return self.topology.exchange_stage(state, self.transport,
                                             self.codec, ctx)
 
-    def decode_stage(self, state: tuple, length: int,
+    def decode_stage(self, state: tuple, shape,
                      ctx: SyncContext) -> jnp.ndarray:
-        return self.topology.decode_stage(state, length, self.transport,
+        return self.topology.decode_stage(state, shape, self.transport,
                                           self.codec, ctx)
 
     def reduce_scatter(self, g: jnp.ndarray, axis: str, dim: int,

@@ -116,20 +116,24 @@ class StaleFill:
         enc = self.inner.encode(x, ctx, axis)
         if ctx.stale is None:
             return enc
+        # the cache has the bucket's shape; ``x`` is the padded bucket in
+        # the TAR view (flat, or rows of a tile-dense slab)
         stale = ctx.stale.astype(x.dtype)
+        if x.ndim == 1:
+            stale = stale.reshape(-1)
         pad = x.shape[0] - stale.shape[0]
         if pad < 0:
             raise ValueError(f"stale cache ({stale.shape[0]}) longer than "
                              f"the padded bucket ({x.shape[0]})")
         if pad:
-            stale = jnp.pad(stale, (0, pad))
+            stale = jnp.pad(stale, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
         enc_st = self.inner.encode(stale, ctx, axis)
         return dataclasses.replace(enc, stale=enc_st.data)
 
     def reduce(self, received, mask, shard_index, enc, ctx):
         if mask is None or enc.stale is None:
             return self.inner.reduce(received, mask, shard_index, enc, ctx)
-        s = received.shape[1]
+        s = received.shape[1]          # shard elements, or dense rows
         stale_shard = jax.lax.dynamic_slice_in_dim(
             enc.stale, shard_index * s, s, 0).astype(received.dtype)
         filled = mask * received + (1.0 - mask) * stale_shard[None, :]
@@ -210,7 +214,9 @@ def ef_residual(bucket: jnp.ndarray, key: jax.Array, cfg, n: int,
         return jnp.zeros_like(bucket)
     basis = bucket if stale is None else bucket - stale.astype(bucket.dtype)
     block = cfg.hadamard_block if cfg.use_hadamard else 1
-    x, length = tar_lib.pad_for_tar(basis, n, block)
+    # the flat shard geometry: a tile-dense (R, L) bucket's dense view cuts
+    # the same shards (tar.tar_view), so its arrival masks are these
+    x, length = tar_lib.pad_for_tar(basis.reshape(-1), n, block)
     s = x.shape[0] // n
     arrival = sender_arrival_masks(cfg, key, n, s)
     mine = jax.lax.dynamic_slice_in_dim(arrival, me, 1, 0)[0]
@@ -220,34 +226,37 @@ def ef_residual(bucket: jnp.ndarray, key: jax.Array, cfg, n: int,
                           use_kernel=cfg.use_kernels)
     else:
         resid = (1.0 - mine) * x
-    return resid[:length].astype(bucket.dtype)
+    return resid[:length].reshape(bucket.shape).astype(bucket.dtype)
 
 
 def ef_residual_arena(arena: jnp.ndarray, step_key: jax.Array, cfg, n: int,
                       me, stale: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Per-bucket :func:`ef_residual` over a packed (B, bucket_elems) arena,
-    with the sync engine's per-bucket key derivation (fold_in by index).
-    ``stale`` is the (B, bucket_elems) prediction cache the fill consumed
-    *this* step (pre-update)."""
+    """Per-bucket :func:`ef_residual` over a packed arena ((B, bucket_elems)
+    or the tile-dense (B, R, L)), with the sync engine's per-bucket key
+    derivation (fold_in by index). ``stale`` is the arena-shaped prediction
+    cache the fill consumed *this* step (pre-update)."""
     from .bucket_plan import bucket_keys
     keys = bucket_keys(step_key, arena.shape[0])
     basis = arena if stale is None else arena - stale.astype(arena.dtype)
     return jax.vmap(lambda g, k: ef_residual(g, k, cfg, n, me))(basis, keys)
 
 
-def init_state(policy: RecoveryPolicy, nbuckets: int, bucket_elems: int,
+def init_state(policy: RecoveryPolicy, arena_shape: tuple[int, ...],
                n_dp: int = 1) -> dict:
     """Zero-initialized recovery state matching the trainer's threading.
 
-    ``stale`` — previous step's decoded arena, shape (B, E), replicated
-    (every rank decodes identical buckets); ``ef`` — the carried residual,
-    shape (n_dp, B, E), sharded over the data axis (each data rank drops
+    ``arena_shape`` is the packed arena's (``BucketPlan.arena_shape``:
+    ``(B, E)`` or the tile-dense ``(B, R, L)``). ``stale`` — previous
+    step's decoded arena, of that shape, replicated (every rank decodes
+    identical buckets); ``ef`` — the carried residual, the same with a
+    leading n_dp axis, sharded over the data axis (each data rank drops
     different wire spans). A zero stale cache makes step 0 behave exactly
     like zero-fill.
     """
+    arena_shape = tuple(arena_shape)
     state = {}
     if policy.stale:
-        state["stale"] = jnp.zeros((nbuckets, bucket_elems), jnp.float32)
+        state["stale"] = jnp.zeros(arena_shape, jnp.float32)
     if policy.ef:
-        state["ef"] = jnp.zeros((n_dp, nbuckets, bucket_elems), jnp.float32)
+        state["ef"] = jnp.zeros((n_dp,) + arena_shape, jnp.float32)
     return state

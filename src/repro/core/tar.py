@@ -20,6 +20,7 @@ reduce-scatter, inter-pod same-rank aggregation, intra-pod broadcast —
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Sequence
 
 import jax
@@ -39,9 +40,49 @@ def pad_for_tar(x: jnp.ndarray, n: int, block: int = 1) -> tuple[jnp.ndarray, in
     return x, length
 
 
+def tar_view(bucket: jnp.ndarray, n: int, block: int = 1, *,
+             flat: bool = False) -> jnp.ndarray:
+    """A bucket padded for an ``n``-shard TAR exchange, in the layout the
+    sync engine moves it in.
+
+    A tile-dense ``(rows, L)`` bucket (the ``(B, R, L)`` arena's slab) stays
+    ``(rows', L)`` — rows zero-padded — when the padded shard length that
+    :func:`pad_for_tar` gives (a multiple of ``n * block``) is whole rows
+    and each row holds whole codec blocks (``block`` 1 or ``L``): shard j is
+    then rows ``[j * rows'/n, (j+1) * rows'/n)``, the same elements as the
+    flat cut, and never crosses the sublane axis. Otherwise — or with
+    ``flat`` (weighted shards' unit rows) — the flat padded vector, as for
+    a flat bucket."""
+    if bucket.ndim == 2 and not flat:
+        rows, lane = bucket.shape
+        quantum = n * block
+        padded = -(-(rows * lane) // quantum) * quantum
+        if block in (1, lane) and (padded // n) % lane == 0:
+            pad = padded // lane - rows
+            return jnp.pad(bucket, ((0, pad), (0, 0))) if pad else bucket
+    x, _ = pad_for_tar(bucket.reshape(-1), n, block)
+    return x
+
+
+def unpad_to(out: jnp.ndarray, shape) -> jnp.ndarray:
+    """Cut a decoded, padded bucket (dense rows or flat) back to the
+    bucket's own ``shape`` (an int for a flat bucket's length)."""
+    shape = (shape,) if isinstance(shape, int) else tuple(shape)
+    if len(shape) == 2 and out.ndim == 2 and out.shape[1] == shape[1]:
+        return out[:shape[0]]
+    return out.reshape(-1)[:math.prod(shape)].reshape(shape)
+
+
+def shard_rows(x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Cut a padded bucket into its ``n`` TAR shards along axis 0:
+    ``(n, S)`` flat, ``(n, S / L, L)`` tile-dense."""
+    return x.reshape((n, x.shape[0] // n) + x.shape[1:])
+
+
 def masked_mean(received: jnp.ndarray, mask: jnp.ndarray | None,
                 use_kernel: bool = False) -> jnp.ndarray:
-    """Drop-compensated mean over the peer axis. received: (N, S).
+    """Drop-compensated mean over the peer axis. received: (N, S), or the
+    tile-dense (N, S / L, L) shard view.
 
     The public reduction every codec shares: no mask -> plain mean; with an
     arrival mask -> the masked compensated mean, dispatching to the Pallas
@@ -59,12 +100,12 @@ def tar_reduce_scatter(x: jnp.ndarray, axis: str, *,
                        use_kernel: bool = False) -> jnp.ndarray:
     """TAR stage 1 + reduce: returns this node's aggregated shard (S,).
 
-    x: flat (L,), L % N == 0. mask: (N, S) — which peers' packets arrived
-    at *this* receiver (row self is always 1; see drops.make_mask).
+    x: flat (L,), L % N == 0, or tile-dense rows (N divides them). mask:
+    (N, S) — which peers' packets arrived at *this* receiver (row self is
+    always 1; see drops.make_mask).
     """
     n = jax.lax.axis_size(axis)
-    s = x.shape[0] // n
-    shards = x.reshape(n, s)
+    shards = shard_rows(x, n)
     received = jax.lax.all_to_all(shards, axis, split_axis=0, concat_axis=0,
                                   tiled=True)          # (N, S): row p = peer p's shard for me
     return masked_mean(received, mask, use_kernel)
@@ -325,8 +366,8 @@ def tar_exchange_rounds(shards: jnp.ndarray, axis: str, *, incast: int = 1,
                         dead_links=()) -> jnp.ndarray:
     """Stage-1 shard exchange on the explicit round schedule (Fig 5b).
 
-    shards: (N, S), row j = this node's contribution to peer j's shard.
-    Returns the (N, S) received matrix in *sender* order (row p = peer p's
+    shards: (N, S), row j = this node's contribution to peer j's shard (or
+    the tile-dense (N, S/L, L)). Returns the (N, S) received matrix in *sender* order (row p = peer p's
     shard for me) — the same layout the tiled all_to_all form produces.
 
     With a degraded-participation set ``active`` (a proper subset of the
@@ -379,8 +420,9 @@ def tar_broadcast_rounds(own: jnp.ndarray, axis: str, *, incast: int = 1,
                          plan: ShardPlan | None = None) -> jnp.ndarray:
     """Stage-2 broadcast of the aggregated shard, mirrored round schedule.
 
-    own: (S,) this node's aggregated shard. Returns the reassembled flat
-    (N*S,) bucket — the same layout the tiled all_gather form produces.
+    own: (S,) this node's aggregated shard (or its tile-dense (S/L, L)
+    rows). Returns the reassembled flat (N*S,) bucket (or (N*S/L, L)) —
+    the same layout the tiled all_gather form produces.
     With ``active`` set, the mirror of the degraded exchange: A-1 rounds on
     the virtual ring assembling the flat (A*S,) bucket on active peers
     (virtual-position order); route it to ejected peers afterwards with
@@ -400,7 +442,7 @@ def tar_broadcast_rounds(own: jnp.ndarray, axis: str, *, incast: int = 1,
         out = jnp.zeros_like(got_by_dist).at[senders].set(got_by_dist)
         if plan is not None:
             return weighted_flat(out, plan)
-        return out.reshape(n * own.shape[0])
+        return out.reshape((n * own.shape[0],) + own.shape[1:])
     a = len(active)
     vpos, _ = peer_lookup(active, n)
     k = jnp.take(vpos, jax.lax.axis_index(axis))
@@ -415,7 +457,7 @@ def tar_broadcast_rounds(own: jnp.ndarray, axis: str, *, incast: int = 1,
     out = jnp.zeros_like(got_by_dist).at[senders].set(got_by_dist)
     if plan is not None:
         return weighted_flat(out, plan)
-    return out.reshape(a * own.shape[0])
+    return out.reshape((a * own.shape[0],) + own.shape[1:])
 
 
 def tar_allreduce_rounds(x: jnp.ndarray, axis: str, *, incast: int = 1,

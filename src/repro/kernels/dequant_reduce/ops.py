@@ -1,11 +1,12 @@
 """Jit'd public wrapper for the fused dequant + compensated reduction.
 
 Accepts the per-Hadamard-block grids the collective layer carries
-((nblk,)-shaped ``lo``/``step``) and expands them to per-column rows before
-dispatching to the Pallas kernel or the jnp oracle.  Whether the Pallas path
-runs interpreted or Mosaic-compiled resolves through the process kernel-mode
-policy (kernels/runtime) outside the jit boundary, so the resolved flag is
-part of the cache key.
+((nblk,)-shaped ``lo``/``step``) and views the codes one block a row,
+``(N, nblk, block)``, with ``(nblk, 1)`` grids, before dispatching to the
+Pallas kernel or the jnp oracle.  Whether the Pallas path runs interpreted
+or Mosaic-compiled resolves through the process kernel-mode policy
+(kernels/runtime) outside the jit boundary, so the resolved flag is part of
+the cache key.
 """
 from __future__ import annotations
 
@@ -20,34 +21,39 @@ from .dequant_reduce import dequant_masked_mean_pallas
 from .ref import dequant_masked_mean_ref
 
 
-@functools.partial(jax.jit, static_argnames=("block", "use_kernel", "tile",
+@functools.partial(jax.jit, static_argnames=("block", "use_kernel",
                                              "interpret"))
 def _dequant_masked_mean(codes: jnp.ndarray, lo: jnp.ndarray,
                          step: jnp.ndarray,
                          mask: jnp.ndarray | None = None, *, block: int,
-                         use_kernel: bool, tile: int,
-                         interpret: bool) -> jnp.ndarray:
-    n, length = codes.shape
-    nblk = length // block
-    lo_row = jnp.broadcast_to(lo.reshape(nblk, 1), (nblk, block)).reshape(-1)
-    step_row = jnp.broadcast_to(step.reshape(nblk, 1),
-                                (nblk, block)).reshape(-1)
+                         use_kernel: bool, interpret: bool) -> jnp.ndarray:
+    # one Hadamard block a row: a flat (N, S) is a free reshape, and the
+    # tile-dense shard view already has this shape
+    n = codes.shape[0]
+    nblk = codes[0].size // block
+    rows = codes.reshape(n, nblk, block)
+    mask_rows = None if mask is None else mask.reshape(rows.shape)
+    lo_col = lo.reshape(nblk, 1)
+    step_col = step.reshape(nblk, 1)
     if use_kernel:
-        return dequant_masked_mean_pallas(codes, lo_row, step_row, mask,
-                                          tile=tile, interpret=interpret)
-    return dequant_masked_mean_ref(codes, lo_row, step_row, mask)
+        out = dequant_masked_mean_pallas(rows, lo_col, step_col, mask_rows,
+                                         interpret=interpret)
+    else:
+        out = dequant_masked_mean_ref(rows, lo_col, step_col, mask_rows)
+    return out.reshape(codes.shape[1:])
 
 
 def dequant_masked_mean(codes: jnp.ndarray, lo: jnp.ndarray,
                         step: jnp.ndarray,
                         mask: jnp.ndarray | None = None, *, block: int,
-                        use_kernel: bool = False,
-                        tile: int = 2048) -> jnp.ndarray:
+                        use_kernel: bool = False) -> jnp.ndarray:
     """Drop-compensated mean over N peers' dequantized codes.
 
-    codes: (N, S) with S = nblk*block; lo/step: (nblk,) or (nblk, 1)
-    per-block grids; mask: (N, S) arrivals or None. Returns (S,) fp32.
+    codes: (N, S) with S = nblk*block, or the tile-dense shard view
+    (N, nblk, block); lo/step: (nblk,) or (nblk, 1) per-block grids; mask:
+    arrivals of the codes' shape or None. Returns (S,) or (nblk, block)
+    fp32, the shape of one peer's codes.
     """
     return _dequant_masked_mean(
-        codes, lo, step, mask, block=block, use_kernel=use_kernel, tile=tile,
+        codes, lo, step, mask, block=block, use_kernel=use_kernel,
         interpret=runtime.interpret_flag() if use_kernel else True)

@@ -1,12 +1,11 @@
 """Two-slot revolving-buffer DMA schedule for double-buffered Pallas kernels.
 
-The double-buffered codec kernels (ht_quant, dequant_reduce) keep their bulk
-operands in ``ANY`` (HBM) memory space and stream one grid block at a time
-into two-slot VMEM scratch buffers with explicit async copies: while block i
+The double-buffered ht_quant kernels keep their bulk operands in ``ANY``
+(HBM) memory space and stream one grid block of rows at a time into
+two-slot VMEM scratch buffers with explicit async copies: while block i
 computes out of slot ``i % 2``, block i+1's loads are already in flight into
 slot ``(i + 1) % 2``.  This module holds the single copy of that schedule —
-kernels differ only in how a block is sliced (rows vs column slabs) and in
-the epilogue consuming the landed slots.
+kernels differ only in the epilogue consuming the landed slots.
 
 Because the revolving slots and in-flight DMAs are threaded through scratch
 refs *across* grid iterations, any grid using this schedule must be marked
@@ -32,19 +31,11 @@ def row_loads(streams, sem, slot: int, idx):
             for k, (hbm, buf, br) in enumerate(streams)]
 
 
-def col_loads(streams, sem, slot: int, idx):
-    """Column-slab sibling of :func:`row_loads`: stream k is a
-    (hbm_ref, vmem_buf, cols_per_slab) triple sliced along axis 1."""
-    return [pltpu.make_async_copy(hbm.at[:, pl.ds(idx * t, t)],
-                                  buf.at[slot], sem.at[k, slot])
-            for k, (hbm, buf, t) in enumerate(streams)]
-
-
 def revolving_pipeline(nblk: int, loads, epilogue):
     """One grid iteration of the two-slot revolving-buffer schedule.
 
     ``loads(slot, idx)`` returns the async copies landing block ``idx`` in
-    ``slot`` (see :func:`row_loads` / :func:`col_loads`); block i+1's loads
+    ``slot`` (see :func:`row_loads`); block i+1's loads
     are issued *before* block i's are awaited, so the next block's HBM
     traffic overlaps this block's compute.  ``epilogue(slot)`` consumes the
     landed VMEM slots.

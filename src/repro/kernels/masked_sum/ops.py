@@ -17,19 +17,18 @@ from .masked_sum import masked_mean_pallas
 from .ref import masked_mean_ref
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("use_kernel", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def _masked_mean(shards: jnp.ndarray, mask: jnp.ndarray, *,
-                 use_kernel: bool, tile: int, interpret: bool) -> jnp.ndarray:
+                 use_kernel: bool, interpret: bool) -> jnp.ndarray:
     if use_kernel:
-        return masked_mean_pallas(shards, mask, tile=tile,
-                                  interpret=interpret)
+        return masked_mean_pallas(shards, mask, interpret=interpret)
     return masked_mean_ref(shards, mask)
 
 
 def masked_mean(shards: jnp.ndarray, mask: jnp.ndarray, *,
-                use_kernel: bool = False, tile: int = 2048) -> jnp.ndarray:
-    """Drop-compensated mean over N peer shards. (N, L) x (N, L) -> (L,)."""
+                use_kernel: bool = False) -> jnp.ndarray:
+    """Drop-compensated mean over N peer shards. (N, L) x (N, L) -> (L,);
+    the tile-dense (N, rows, L) shard view reduces to (rows, L)."""
     return _masked_mean(
-        shards, mask, use_kernel=use_kernel, tile=tile,
+        shards, mask, use_kernel=use_kernel,
         interpret=runtime.interpret_flag() if use_kernel else True)

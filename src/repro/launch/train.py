@@ -32,7 +32,7 @@ from repro.models import init_params
 from repro.obs import span
 from repro.optim.optimizers import OptimizerConfig
 from repro.train import checkpoint as ckpt_lib
-from repro.train.trainer import TrainConfig, build_train_step
+from repro.train.trainer import TrainConfig, arena_plan, build_train_step
 
 DEVICE_TRACED_STEPS = 8
 
@@ -302,11 +302,10 @@ def main(argv=None) -> dict:
     rec_state = None
     if args.recovery != "none":
         from repro.core import recovery as recovery_lib
-        from repro.core.bucket_plan import BucketPlan
-        plan = BucketPlan.for_tree(params, tc.bucket_elems)
+        plan = arena_plan(params, tc)
         rec_state = recovery_lib.init_state(
-            recovery_lib.parse(args.recovery), plan.num_buckets,
-            plan.bucket_elems, n_dp=mesh.shape.get("data", 1))
+            recovery_lib.parse(args.recovery), plan.arena_shape,
+            n_dp=mesh.shape.get("data", 1))
         rec_state = jax.device_put(rec_state, shardings["rec"])
 
     start_step = 0

@@ -25,10 +25,13 @@ kernels by construction (see PERF.md for the skew diagram).
 
 The replicated path also keeps the whole gradient stream in a **packed
 arena**: micro-batch accumulation adds each microbatch's grads directly
-into the ``(B, bucket_elems)`` batch (no per-leaf zeros tree, one pack
-fused into the first add), the arena feeds ``sync_packed`` without a
-repack, and the §3.4 guard + global-norm + clip run as one fused reduction
-and one multiply over the arena before a single unpack for the optimizer.
+into the batch (no per-leaf zeros tree, one pack fused into the first
+add), the arena feeds ``sync_packed`` without a repack, and the §3.4 guard
++ global-norm + clip run as one fused reduction and one multiply over the
+arena before a single unpack for the optimizer.  The arena is bucket-major
+``(B, R, L)`` with ``L`` the codec's lane width (:func:`arena_plan`): each
+bucket a slab of whole (8, 128) tiles, so no bucket's data crosses the
+sublane axis (DESIGN §3).
 """
 from __future__ import annotations
 
@@ -45,7 +48,7 @@ from repro.configs.base import ModelConfig
 from repro.core.allreduce import (OptiReduceConfig, SyncContext, rs_spec,
                                   sync_packed, sync_pytree)
 from repro.core.bucket_plan import BucketPlan
-from repro.core.pipeline import resolve_spec
+from repro.core.pipeline import arena_lane, resolve_spec
 from repro.core.safeguards import guard_scale, guard_update
 from repro.kernels import runtime as kernel_runtime
 from repro.models import lm_loss, param_specs, param_table
@@ -190,6 +193,14 @@ def sharded_global_norm(grads, specs) -> jnp.ndarray:
     return jnp.sqrt(_psum_group_total(groups))
 
 
+def arena_plan(tree, tc: "TrainConfig") -> BucketPlan:
+    """The replicated path's packed-arena plan for a parameter tree: the
+    bucket-major ``(B, R, L)`` layout at the sync codec's lane width (the
+    recovery state takes its shape)."""
+    return BucketPlan.for_tree(tree, tc.bucket_elems,
+                               lane=arena_lane(resolve_spec(tc.sync), tc.sync))
+
+
 def packed_global_norm(batch: jnp.ndarray, plan: BucketPlan,
                        specs) -> jnp.ndarray:
     """:func:`sharded_global_norm` over the packed gradient arena.
@@ -200,8 +211,8 @@ def packed_global_norm(batch: jnp.ndarray, plan: BucketPlan,
     Python loop) with no psum at all; mixed-sharding trees pay one reduction
     per contiguous run and one psum per distinct axes set.  The zero-padded
     arena tail is excluded (runs stop at ``plan.total`` — after a quantized
-    sync the tail carries codec noise, not zeros)."""
-    flat = batch.reshape(-1)
+    sync the tail carries codec noise, not zeros); each run is read in
+    place (:meth:`BucketPlan.sum_squares`)."""
     s_leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     runs: list[tuple[tuple[str, ...], int, int]] = []
     for off, size, s in zip(plan.offsets, plan.sizes, s_leaves):
@@ -211,8 +222,7 @@ def packed_global_norm(batch: jnp.ndarray, plan: BucketPlan,
         else:
             runs.append((axes, off, off + size))
     groups = _summed_groups(
-        (axes, jnp.sum(jnp.square(flat[a:b].astype(jnp.float32))))
-        for axes, a, b in runs)
+        (axes, plan.sum_squares(batch, a, b)) for axes, a, b in runs)
     return jnp.sqrt(_psum_group_total(groups))
 
 
@@ -357,13 +367,13 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
                     lambda g: g * clip_scale.astype(g.dtype), grads)
         else:
             # ---- packed gradient arena (replicated DP) -------------------
-            # the (B, bucket_elems) batch IS the accumulator: micro-batch
+            # the (B, R, L) batch IS the accumulator: micro-batch
             # grads pack straight into it (the pack concat fuses into the
             # add — no per-leaf zeros tree, no second full-gradient copy),
             # the sync engine consumes it without a repack, and guard +
             # global-norm + clip are one fused reduction and one multiply
             # over the arena before the single unpack the optimizer needs
-            plan = BucketPlan.for_tree(params, tc.bucket_elems)
+            plan = arena_plan(params, tc)
             if n_micro > 1:
                 def micro(carry, mbatch):
                     acc, lacc = carry
@@ -373,8 +383,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
                         acc = acc + plan.pack(g, dtype=tc.accum_dtype)
                     return (acc, lacc + l), None
 
-                arena0 = jnp.zeros((plan.num_buckets, plan.bucket_elems),
-                                   tc.accum_dtype)
+                arena0 = jnp.zeros(plan.arena_shape, tc.accum_dtype)
                 (arena, loss), _ = jax.lax.scan(
                     micro, (arena0, jnp.zeros(())), mbatches)
                 # accumulate in accum_dtype (bitwise vs the seed per-leaf
@@ -394,7 +403,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
                 if rec_state is not None and "ef" in rec_state:
                     # the carried EF residual joins this rank's contribution
                     # (per-rank state: each data rank dropped different wire
-                    # spans last step; the local shard is (1, B, E))
+                    # spans last step; the local shard is (1, B, R, L))
                     arena = arena + rec_state["ef"][0]
 
                 synced = sync_packed(arena, ctx, mode=tc.sync_mode,

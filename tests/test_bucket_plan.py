@@ -58,6 +58,34 @@ def test_pack_unpack_roundtrip_preserves_dtype():
         np.asarray(tree["b"].astype(jnp.float32)))
 
 
+@pytest.mark.parametrize("lane", [0, 128])
+def test_arena_views_read_the_flat_stream(lane):
+    """The bucket-major (B, R, L) arena and the flat (B, E) one hold the
+    same stream: unpack restores every leaf, and ``sum_squares`` of a flat
+    range (leaf-aligned or not, the zero tail excluded) reads it in place;
+    a lane that does not divide the bucket falls back to the flat view."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    tree = {"a": jax.random.normal(ks[0], (17, 13)),
+            "b": jax.random.normal(ks[1], (300,)).astype(jnp.bfloat16),
+            "c": jax.random.normal(ks[2], (5, 128))}
+    plan = BucketPlan.for_tree(tree, 256, lane=lane)
+    assert plan.arena_shape == ((5, 2, 128) if lane else (5, 256))
+    assert BucketPlan.for_tree(tree, 1000, lane=128).lane == 0
+    arena = plan.pack(tree)
+    out = plan.unpack(arena)
+    for k in tree:
+        assert out[k].dtype == tree[k].dtype
+        np.testing.assert_array_equal(np.asarray(out[k], np.float32),
+                                      np.asarray(tree[k], np.float32))
+    flat = np.concatenate([np.asarray(leaf, np.float32).ravel()
+                           for leaf in jax.tree.leaves(tree)])
+    for a, b in ((0, plan.total), (221, 521), (5, 6), (130, 1000)):
+        np.testing.assert_allclose(float(plan.sum_squares(arena, a, b)),
+                                   np.sum(np.square(flat[a:b],
+                                                    dtype=np.float64)),
+                                   rtol=1e-5)
+
+
 def _sync(fn, tree, cfg, bucket_elems, **kw):
     """Run a sync function under a dp=1 shard_map (single device)."""
     mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
@@ -129,8 +157,7 @@ def test_arena_accumulation_bitwise_vs_per_leaf(accum_dtype):
     def arena_path(gs):
         def micro(acc, g):
             return acc + plan.pack(g, dtype=accum_dtype), None
-        arena0 = jnp.zeros((plan.num_buckets, plan.bucket_elems),
-                           accum_dtype)
+        arena0 = jnp.zeros(plan.arena_shape, accum_dtype)
         arena, _ = jax.lax.scan(micro, arena0, gs)
         return arena.astype(jnp.float32), arena.astype(jnp.float32) / n_micro
 

@@ -83,16 +83,21 @@ KERNELS = {
                       [((ROWS, BLOCK),), ((ROWS, BLOCK),), ((2,),)]),
     "masked_mean": (lambda x, m: masked_mean_pallas(x, m, interpret=False),
                     [((DP, SHARD),), ((DP, SHARD),)]),
+    # the sync engine's tile-dense shard view of one chip's bucket
+    "masked_mean_one_peer_rows": (
+        lambda x, m: masked_mean_pallas(x, m, interpret=False),
+        [((1, ROWS, BLOCK),), ((1, ROWS, BLOCK),)]),
     "dequant_masked_mean": (lambda c, lo, st, m: dequant_masked_mean_pallas(
                                 c, lo, st, m, interpret=False),
-                            [((DP, SHARD), jnp.uint8), ((SHARD,),),
-                             ((SHARD,),), ((DP, SHARD),)]),
+                            [((DP, ROWS // DP, BLOCK), jnp.uint8),
+                             ((ROWS // DP, 1),), ((ROWS // DP, 1),),
+                             ((DP, ROWS // DP, BLOCK),)]),
     # one peer (a single chip): fewer code rows than a uint8 HBM tile
     "dequant_masked_mean_one_peer": (
         lambda c, lo, st, m: dequant_masked_mean_pallas(
             c, lo, st, m, interpret=False),
-        [((1, BUCKET), jnp.uint8), ((BUCKET,),), ((BUCKET,),),
-         ((1, BUCKET),)]),
+        [((1, ROWS, BLOCK), jnp.uint8), ((ROWS, 1),), ((ROWS, 1),),
+         ((1, ROWS, BLOCK),)]),
 }
 
 

@@ -93,13 +93,15 @@ def test_reduce_kernels_compiled_match_interpret():
     mask = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.8,
                                 shards.shape).astype(jnp.float32)
     interp_m, comp_m = _both_modes(
-        lambda: masked_mean_pallas(shards, mask, tile=1024))
+        lambda: masked_mean_pallas(shards, mask, block_rows=8))
     np.testing.assert_allclose(comp_m, interp_m, rtol=1e-6, atol=1e-6)
-    codes = jax.random.randint(jax.random.fold_in(key, 2), (8, 4096),
+    # codes one 1024-wide quantization block a row, with per-row grids
+    codes = jax.random.randint(jax.random.fold_in(key, 2), (8, 4, 1024),
                                0, 256, jnp.int32).astype(jnp.uint8)
-    lo = jax.random.normal(jax.random.fold_in(key, 3), (4096,))
-    step = jax.random.uniform(jax.random.fold_in(key, 4), (4096,),
+    lo = jax.random.normal(jax.random.fold_in(key, 3), (4, 1))
+    step = jax.random.uniform(jax.random.fold_in(key, 4), (4, 1),
                               minval=0.01, maxval=0.1)
     interp_d, comp_d = _both_modes(
-        lambda: dequant_masked_mean_pallas(codes, lo, step, mask, tile=1024))
+        lambda: dequant_masked_mean_pallas(codes, lo, step,
+                                           mask.reshape(codes.shape)))
     np.testing.assert_allclose(comp_d, interp_d, rtol=1e-6, atol=1e-6)

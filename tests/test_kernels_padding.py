@@ -1,7 +1,7 @@
 """Padding-edge property tests for every Pallas kernel package.
 
 Each kernel pads its streaming axis up to a whole number of grid blocks
-(rows to ``block_rows``, columns to ``tile``) and slices the pad back off.
+(rows to ``block_rows``) and slices the pad back off.
 Because every output row/column depends only on its own input row/column,
 the padded tail block must not perturb the kept prefix: for any prefix
 length r — including r % block != 0, the tail-block path, and the
@@ -102,41 +102,54 @@ def test_uniform_quant_prefix_invariant(r, seed):
         lambda: uniform_quant_pallas(x[:r], noise[:r], lohi, block_rows=BR))
 
 
-# ---- column-streamed kernels: the pad is on the length axis ---------------
-TILE = 64
-L = 3 * TILE
+# ---- the reduce kernels: the pad is on the row axis (the flat form of
+# masked_mean also pads its columns to whole 128-lane rows) -----------------
+LANES = 128
+COLS = 2 * BR * LANES + 64     # flat shards: 17 rows of 128, 3 row blocks
+CR = 32                        # codes' block_rows: one uint8 sublane tile
+CODE_ROWS = 3 * CR
 
-cols_st = st.integers(min_value=1, max_value=L)
+cols_st = st.integers(min_value=1, max_value=COLS)
+code_rows_st = st.integers(min_value=1, max_value=CODE_ROWS)
 
 
 def _cols_data(seed):
     key = jax.random.PRNGKey(seed)
-    shards = jax.random.normal(key, (4, L), jnp.float32)
+    shards = jax.random.normal(key, (4, COLS), jnp.float32)
     mask = jax.random.bernoulli(jax.random.fold_in(key, 1),
-                                0.8, (4, L)).astype(jnp.float32)
-    codes = jax.random.randint(jax.random.fold_in(key, 2), (4, L),
+                                0.8, (4, COLS)).astype(jnp.float32)
+    return shards, mask
+
+
+def _codes_data(seed):
+    key = jax.random.PRNGKey(seed)
+    shape = (4, CODE_ROWS, LANES)
+    mask = jax.random.bernoulli(jax.random.fold_in(key, 1),
+                                0.8, shape).astype(jnp.float32)
+    codes = jax.random.randint(jax.random.fold_in(key, 2), shape,
                                0, 256, jnp.int32).astype(jnp.uint8)
-    lo_row = jax.random.normal(jax.random.fold_in(key, 3), (L,))
-    step_row = jax.random.uniform(jax.random.fold_in(key, 4), (L,),
-                                  minval=0.01, maxval=0.1)
-    return shards, mask, codes, lo_row, step_row
+    lo = jax.random.normal(jax.random.fold_in(key, 3), (CODE_ROWS, 1))
+    step = jax.random.uniform(jax.random.fold_in(key, 4), (CODE_ROWS, 1),
+                              minval=0.01, maxval=0.1)
+    return mask, codes, lo, step
 
 
 @given(cols_st, seed_st)
 def test_masked_mean_prefix_invariant(c, seed):
-    shards, mask, _, _, _ = _cols_data(seed)
+    shards, mask = _cols_data(seed)
     _assert_prefix(
-        lambda: masked_mean_pallas(shards, mask, tile=TILE),
-        lambda: masked_mean_pallas(shards[:, :c], mask[:, :c], tile=TILE))
+        lambda: masked_mean_pallas(shards, mask, block_rows=BR),
+        lambda: masked_mean_pallas(shards[:, :c], mask[:, :c],
+                                   block_rows=BR))
 
 
-@given(cols_st, seed_st)
-def test_dequant_masked_mean_prefix_invariant(c, seed):
-    _, mask, codes, lo_row, step_row = _cols_data(seed)
-    for m, mp in [(mask, lambda: mask[:, :c]), (None, lambda: None)]:
+@given(code_rows_st, seed_st)
+def test_dequant_masked_mean_prefix_invariant(r, seed):
+    mask, codes, lo, step = _codes_data(seed)
+    for m, mp in [(mask, lambda: mask[:, :r]), (None, lambda: None)]:
         _assert_prefix(
-            lambda: dequant_masked_mean_pallas(codes, lo_row, step_row, m,
-                                               tile=TILE),
-            lambda: dequant_masked_mean_pallas(codes[:, :c], lo_row[:c],
-                                               step_row[:c], mp(),
-                                               tile=TILE))
+            lambda: dequant_masked_mean_pallas(codes, lo, step, m,
+                                               block_rows=CR),
+            lambda: dequant_masked_mean_pallas(codes[:, :r], lo[:r],
+                                               step[:r], mp(),
+                                               block_rows=CR))

@@ -86,9 +86,11 @@ def test_inproc_crash_ejection_and_probation_readmission():
     victim is ejected, its restart restores the checkpoint and walks
     EJECTED -> PROBATION -> ACTIVE in the survivors' detectors."""
     kill_rank, kill_step, steps = 1, 1, 6
+    # paced like the udp case below: unpaced survivors can finish every
+    # step before the respawned victim has rejoined, leaving it none to run
     report = mp.main(["--backend", "inproc", "--nprocs", str(N),
                       "--steps", str(steps), "--elems", "1024",
-                      "--drop-rate", str(DROP_RATE),
+                      "--drop-rate", str(DROP_RATE), "--step-sleep", "0.25",
                       "--kill-rank", str(kill_rank),
                       "--kill-step", str(kill_step), "--restart"])
     killed = [w for w in report["workers"] if w.get("exit") == "killed"]

@@ -243,6 +243,92 @@ dead_perms = _n_perms(OptiReduceConfig(strategy="optireduce_rounds",
 assert dead_perms == 18, dead_perms              # 14 + 2 relays * 2 stages
 print("DEADLINK OK %%d perms" %% dead_perms)
 
+# ---- the tile-dense (B, R, L) arena: every layout case, same bits ---------
+# sync_pytree packs the bucket-major arena at the codec's lane width (256
+# here); these pin each path it can take against the flat-bucket oracle
+from repro.core import BucketPlan
+from repro.core import recovery as recovery_lib
+from repro.core.allreduce import sync_packed
+from repro.core.pipeline import arena_lane, resolve_spec
+
+def lane_of(cfg, t, be):
+    return BucketPlan.for_tree(t, be, lane=arena_lane(resolve_spec(cfg),
+                                                      cfg)).lane
+
+def run_tree(fn, cfg, t, be, **kw):
+    tspec = jax.tree.map(lambda _: P(), t)
+    def body(tt):
+        ctx = SyncContext(cfg=cfg, key=jax.random.PRNGKey(5))
+        return fn(tt, ctx, bucket_elems=be, **kw), ctx.loss_fraction()
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(tspec,),
+                              out_specs=(tspec, P()), check_vma=False))
+    return f(t)
+
+def same_bits(case, cfg, t, be, lane, **kw):
+    assert lane_of(cfg, t, be) == lane, (case, lane_of(cfg, t, be))
+    ref, ref_frac = run_tree(sync_pytree_unfused, cfg, t, be)
+    out, out_frac = run_tree(sync_pytree, cfg, t, be, **kw)
+    for k in t:
+        assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k])), \
+            (case, k)
+    np.testing.assert_allclose(float(ref_frac), float(out_frac), atol=1e-6)
+    print("LAYOUT %%s OK" %% case)
+
+cfg_d = OptiReduceConfig(strategy="optireduce", drop_rate=0.1,
+                         hadamard_block=256, use_kernels=True, incast=3)
+# B = 3: the pipelined schedule fully unrolled over a dense (3, 4, 256)
+same_bits("unrolled", cfg_d, {"w": jax.random.normal(key, (3, 1024))},
+          1024, 256, mode="pipelined")
+# B = 6: prologue, the in-place loop over 4 buckets, epilogue
+same_bits("scan", cfg_d, {"w": jax.random.normal(key, (6, 1024))}, 1024,
+          256, mode="pipelined")
+# a bucket the lane does not divide: the plan keeps the flat (B, E) arena
+same_bits("plan_fallback", cfg_d, tree, 1000, 0, mode="pipelined")
+# dense (9, 128) buckets whose 8 shards are not whole rows: the TAR stages
+# take the flat view inside a dense arena (Identity codec, lane 128)
+same_bits("view_fallback", OptiReduceConfig(strategy="tar_tcp",
+                                            use_kernels=True),
+          tree, 1152, 128, mode="pipelined")
+# shard weights on the rounds schedule: unit rows take the flat view
+same_bits("weighted_rounds",
+          OptiReduceConfig(strategy="optireduce_rounds", drop_rate=0.1,
+                           hadamard_block=256, incast=3,
+                           shard_weights=(2,) * 7 + (1,)),
+          tree, 1024, 256, mode="pipelined")
+
+# recovery: the stale cache and EF residuals take the arena's shape; the
+# dense arena gives the flat arena's bits (the oracle has no stale input)
+t6 = {"w": jax.random.normal(key, (6, 1024))}
+st6 = {"w": jax.random.normal(jax.random.fold_in(key, 3), (6, 1024))}
+cfg_st = dataclasses.replace(cfg_d, recovery="stale")
+dense_p = BucketPlan.for_tree(t6, 1024, lane=256)
+flat_p = BucketPlan.for_tree(t6, 1024)
+assert dense_p.arena_shape == (6, 4, 256) and flat_p.arena_shape == (6, 1024)
+
+def run_stale(arena, stale):
+    def body(a, st):
+        ctx = SyncContext(cfg=cfg_st, key=jax.random.PRNGKey(5))
+        return sync_packed(a, ctx, mode="pipelined", stale=st)
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                              out_specs=P(), check_vma=False))
+    return f(arena, stale)
+got_d = dense_p.unpack(run_stale(dense_p.pack(t6), dense_p.pack(st6)))
+got_f = flat_p.unpack(run_stale(flat_p.pack(t6), flat_p.pack(st6)))
+assert np.array_equal(np.asarray(got_d["w"]), np.asarray(got_f["w"]))
+print("LAYOUT stale OK")
+
+cfg_ef = dataclasses.replace(cfg_d, recovery="ef")
+ef_d = recovery_lib.ef_residual_arena(dense_p.pack(t6), key, cfg_ef, 8,
+                                      jnp.int32(3),
+                                      stale=dense_p.pack(st6))
+ef_f = recovery_lib.ef_residual_arena(flat_p.pack(t6), key, cfg_ef, 8,
+                                      jnp.int32(3), stale=flat_p.pack(st6))
+assert ef_d.shape == (6, 4, 256)
+assert np.array_equal(np.asarray(dense_p.unpack(ef_d)["w"]),
+                      np.asarray(flat_p.unpack(ef_f)["w"]))
+assert float(jnp.abs(ef_d).max()) > 0.0
+print("LAYOUT ef OK")
+
 # ---- 2D (pod, data) reduce-scatter: cross-pod replica consistency --------
 mesh2 = jax.make_mesh((2, 4), ("pod", "data"),
                       axis_types=(AxisType.Auto,) * 2)
@@ -382,6 +468,24 @@ def test_degraded_round_schedule_shrinks(parity_output):
     against 2(N-1) at full participation."""
     assert "PARTICIPATION_SCHEDULE OK 14 -> 11" in parity_output, \
         parity_output
+
+
+LAYOUTS = ["unrolled", "scan", "plan_fallback", "view_fallback",
+           "weighted_rounds", "stale", "ef"]
+
+
+@pytest.mark.parity
+@pytest.mark.slow
+@pytest.mark.parametrize("case", LAYOUTS)
+def test_tile_dense_arena_bitwise(parity_output, case):
+    """The bucket-major (B, R, L) arena on 8 devices, drops and kernels on:
+    the unrolled (B = 3) and looped (B = 6) in-place pipelined schedule,
+    the flat fall-backs (a bucket the lane does not divide; shards that are
+    not whole rows), and weighted shards on the rounds schedule are bitwise
+    the flat-bucket ``sync_pytree_unfused`` oracle; stale-fill recovery and
+    the EF residuals on the dense arena are bitwise their flat-arena
+    results."""
+    assert f"LAYOUT {case} OK" in parity_output, parity_output
 
 
 @pytest.mark.parity

@@ -161,10 +161,14 @@ def test_ef_residual_arena_uses_sync_engine_bucket_keys():
 
 def test_init_state_shapes():
     pol = recovery_lib.parse("ef")
-    state = recovery_lib.init_state(pol, nbuckets=5, bucket_elems=32, n_dp=4)
+    state = recovery_lib.init_state(pol, (5, 32), n_dp=4)
     assert state["stale"].shape == (5, 32)
     assert state["ef"].shape == (4, 5, 32)
     assert not np.asarray(state["stale"]).any()
     assert "ef" not in recovery_lib.init_state(recovery_lib.parse("stale"),
-                                               5, 32)
-    assert recovery_lib.init_state(recovery_lib.parse("none"), 5, 32) == {}
+                                               (5, 32))
+    assert recovery_lib.init_state(recovery_lib.parse("none"), (5, 32)) == {}
+    # the tile-dense (B, R, L) arena
+    dense = recovery_lib.init_state(pol, (5, 4, 128), n_dp=2)
+    assert dense["stale"].shape == (5, 4, 128)
+    assert dense["ef"].shape == (2, 5, 4, 128)
