@@ -40,12 +40,23 @@ from repro.kernels.quant.quant import uniform_quant_pallas
 
 from .common import Rows
 
+# the Pallas kernels as this backend runs them
+KERNEL = "mosaic" if jax.default_backend() == "tpu" else "interpret"
+
+
+def _as(impl: str, fn):
+    """``fn()`` with the codec wrappers running ``impl``."""
+    with runtime.impl_scope(impl):
+        return fn()
+
 
 def _t(fn, *a, n=3):
-    jax.block_until_ready(fn(*a))        # one warmup; handles any pytree
-    t0 = time.perf_counter()
-    for _ in range(n):
-        jax.block_until_ready(fn(*a))
+    """Host us/call of ``fn`` with the wrappers in their jnp form."""
+    with runtime.impl_scope("jnp"):
+        jax.block_until_ready(fn(*a))    # one warmup; handles any pytree
+        t0 = time.perf_counter()
+        for _ in range(n):
+            jax.block_until_ready(fn(*a))
     return (time.perf_counter() - t0) / n * 1e6
 
 
@@ -63,14 +74,14 @@ def _steady(fn, reps=5):
 
 def _device_rows(rows: Rows, name: str, fn, reps=5):
     """interpret-mode rows on every backend; Mosaic-compiled rows when a
-    TPU is present (``fn`` must dispatch through the kernel-mode policy)."""
-    with runtime.kernel_mode_scope("interpret"):
+    TPU is present (``fn`` must dispatch through ``kernels.runtime``)."""
+    with runtime.impl_scope("interpret"):
         med, iqr = _steady(fn, reps)
     rows.add(f"{name}_interpret_steady_us", med,
              "Pallas interpreter host wall-clock (structure, not device)")
     rows.add(f"{name}_interpret_steady_iqr_us", iqr, "")
     if jax.default_backend() == "tpu":
-        with runtime.kernel_mode_scope("compile"):
+        with runtime.impl_scope("mosaic"):
             med_c, iqr_c = _steady(fn, reps)
         rows.add(f"{name}_compiled_steady_us", med_c,
                  "Mosaic-compiled, block_until_ready")
@@ -85,7 +96,7 @@ def run(quick: bool = True) -> Rows:
         for dtype in (jnp.float32, jnp.bfloat16):
             x = jax.random.normal(key, (32, block)).astype(dtype)
             ref = fwht_ref(x.astype(jnp.float32))
-            with runtime.kernel_mode_scope("interpret"):
+            with runtime.impl_scope("interpret"):
                 out = fwht_pallas(x.astype(jnp.float32))
             err = float(jnp.max(jnp.abs(out - ref)))
             us = _t(lambda v=x: fwht(v.astype(jnp.float32)))
@@ -100,7 +111,8 @@ def run(quick: bool = True) -> Rows:
         mk = (jax.random.uniform(key, (n_peers, length)) > 0.05).astype(
             jnp.float32)
         err = float(jnp.max(jnp.abs(
-            masked_mean(sh, mk, use_kernel=True) - masked_mean_ref(sh, mk))))
+            _as(KERNEL, lambda: masked_mean(sh, mk))
+            - masked_mean_ref(sh, mk))))
         us = _t(lambda: masked_mean(sh, mk))
         rows.add(f"kernels/masked_sum_L{length}", us,
                  f"us/call; pallas_vs_oracle_err={err:.2e}")
@@ -111,7 +123,7 @@ def run(quick: bool = True) -> Rows:
     noise = jax.random.uniform(jax.random.fold_in(key, 1), x.shape)
     lohi = jnp.array([float(x.min()), float(x.max())])
     for bits in (4, 8):
-        q1 = uniform_quant(x, noise, lohi, bits=bits, use_kernel=True)
+        q1 = _as(KERNEL, lambda b=bits: uniform_quant(x, noise, lohi, bits=b))
         q2 = uniform_quant_ref(x, noise, lohi[0], lohi[1], bits=bits)
         err = int(jnp.max(jnp.abs(q1.astype(jnp.int32) -
                                   q2.astype(jnp.int32))))
@@ -127,10 +139,10 @@ def run(quick: bool = True) -> Rows:
         xf = jax.random.normal(key, (rws, block))
         sign = jnp.where(jax.random.bernoulli(key, 0.5, (block,)), 1., -1.)
         nz = jax.random.uniform(jax.random.fold_in(key, 2), xf.shape)
-        am = jnp.maximum(ht_amax(xf, sign), 1e-12)
+        am = jnp.maximum(_as("jnp", lambda: ht_amax(xf, sign)), 1e-12)
         lo, step = -am, 2.0 * am / 255
-        qk = ht_quant(xf, sign, nz, lo, step, bits=8, use_kernel=True)
-        qr = ht_quant(xf, sign, nz, lo, step, bits=8, use_kernel=False)
+        qk, qr = (_as(impl, lambda: ht_quant(xf, sign, nz, lo, step, bits=8))
+                  for impl in (KERNEL, "jnp"))
         err = int(jnp.max(jnp.abs(qk.astype(jnp.int32) -
                                   qr.astype(jnp.int32))))
         us = _t(lambda: ht_quant(xf, sign, nz, lo, step, bits=8))
@@ -156,10 +168,9 @@ def run(quick: bool = True) -> Rows:
     lo_b = jax.random.normal(key, (nblk,))
     step_b = jax.random.uniform(key, (nblk,)) * 0.05 + 1e-3
     mk2 = (jax.random.uniform(key, (n_peers, s)) > 0.05).astype(jnp.float32)
-    dk = dequant_masked_mean(codes, lo_b, step_b, mk2, block=blk,
-                             use_kernel=True)
-    dr = dequant_masked_mean(codes, lo_b, step_b, mk2, block=blk,
-                             use_kernel=False)
+    dk, dr = (_as(impl, lambda: dequant_masked_mean(codes, lo_b, step_b, mk2,
+                                                    block=blk))
+              for impl in (KERNEL, "jnp"))
     err = float(jnp.max(jnp.abs(dk - dr)))
     us = _t(lambda: dequant_masked_mean(codes, lo_b, step_b, mk2, block=blk))
     rows.add(f"kernels/dequant_masked_mean_L{s}", us,

@@ -12,8 +12,9 @@
 (b) ``sync_packed`` with ``optireduce_q`` at 1% drop over a
     gpt2-paper-shaped gradient arena (the trainer's ``arena_plan`` of its
     params: 24 buckets of 25 MB, each 6400 x 1024), once through the
-    Mosaic-compiled codec kernels and once through the jnp codec path, on
-    the same arena and key. The two must agree (``KERNEL_REL_TOL``), and
+    Mosaic-compiled codec kernels (the TPU default) and once through the
+    jnp codec path (``kernels.runtime.impl_scope("jnp")``), on the same
+    arena and key. The two must agree (``KERNEL_REL_TOL``), and
     each must reproduce the arena to within 8-bit quantization noise.
 --chips 4: only 4-way replicated DP of gpt2-paper (full width, depth cut to
     ``DP4_LAYERS``) at global batch 32, seq 1024:
@@ -141,6 +142,7 @@ def phase_kernels(*, seed: int):
     from repro.configs import get_config
     from repro.core.allreduce import OptiReduceConfig, SyncContext, \
         sync_packed
+    from repro.kernels import runtime
     from repro.launch.mesh import make_host_mesh
     from repro.models import init_params
     from repro.train.trainer import TrainConfig, arena_plan
@@ -156,28 +158,27 @@ def phase_kernels(*, seed: int):
     arena = 1e-3 * jax.random.normal(jax.random.fold_in(key, 1),
                                      plan.arena_shape)
     mesh = make_host_mesh(dp=1)
+    sync = OptiReduceConfig(strategy="optireduce_q", drop_rate=0.01,
+                            hadamard_block=1024)
+
+    def body(a, k):
+        return sync_packed(a, SyncContext(cfg=sync, key=k), mode="pipelined")
+
     outs = {}
-    for use_kernels in (True, False):
-        sync = OptiReduceConfig(strategy="optireduce_q", drop_rate=0.01,
-                                hadamard_block=1024, use_kernels=use_kernels)
-
-        def body(a, k, sync=sync):
-            return sync_packed(a, SyncContext(cfg=sync, key=k),
-                               mode="pipelined")
-
+    for name, impl in (("kernels", "mosaic"), ("jnp", "jnp")):
         f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
                                   out_specs=P(), check_vma=False))
         t = time.perf_counter()
-        compiled = f.lower(arena, key).compile()
+        with runtime.impl_scope(impl):
+            compiled = f.lower(arena, key).compile()
         compile_s = time.perf_counter() - t
-        if use_kernels:
+        if impl == "mosaic":
             check("tpu_custom_call" in compiled.as_text(),
-                  "phase (b): no Mosaic kernel in the use_kernels program")
+                  "phase (b): no Mosaic kernel in the kernels program")
         out = jax.block_until_ready(compiled(arena, key))
         t = time.perf_counter()
         out = jax.block_until_ready(compiled(arena, key))
         run_s = time.perf_counter() - t
-        name = "kernels" if use_kernels else "jnp"
         check(bool(jnp.all(jnp.isfinite(out))), f"phase (b) {name}: "
               "non-finite output")
         rel = float(jnp.linalg.norm(out - arena) / jnp.linalg.norm(arena))
@@ -269,8 +270,8 @@ def main(argv=None) -> None:
     from repro.launch.compile_cache import enable_compile_cache
 
     smoke(f"compile cache {enable_compile_cache()}")
-    check(runtime.resolve() == "compile", "Pallas kernels would not "
-          f"compile (kernel mode {runtime.kernel_mode()!r})")
+    check(runtime.impl() == "mosaic", "Pallas kernels would not compile "
+          f"(codec kernel implementation {runtime.impl()!r})")
     smoke(f"device {dev.device_kind} x{len(devices)}")
 
     t = time.perf_counter()

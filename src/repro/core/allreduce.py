@@ -7,8 +7,9 @@ all_to_all or explicit round schedules, 1D or hierarchical 2D pod×data), a
 **Transport** (``Reliable``, ``Lossy`` = the UBT drop model + stats,
 ``AdaptiveTransport`` = the §3.2 controllers picking next-step codec/incast),
 and a **Codec** (``Identity``, ``Hadamard``, ``HTQuant`` shared-grid
-quantization, kernel-dispatched under ``cfg.use_kernels``).  See DESIGN.md
-§3 for the layering and the strategy-author migration notes.
+quantization).  Codec stages call the kernel wrappers, which run Mosaic on
+a TPU and the jnp forms elsewhere (``kernels.runtime``).  See DESIGN.md §3
+for the layering and the strategy-author migration notes.
 
 This module keeps the stable, config-driven surface:
 

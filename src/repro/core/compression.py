@@ -58,30 +58,28 @@ class THCCompressed(NamedTuple):
     lohi: jnp.ndarray    # shared (2,) quantization range
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("bits", "block"))
 def thc_compress(x: jnp.ndarray, key: jax.Array, lohi: jnp.ndarray, *,
-                 bits: int = 4, block: int = 4096,
-                 use_kernel: bool = False) -> THCCompressed:
+                 bits: int = 4, block: int = 4096) -> THCCompressed:
     """Rotate (randomized HT) then quantize onto the shared [lo, hi] grid.
 
     ``lohi`` must be agreed across workers (THC pre-negotiates the range;
     we compute it from a profiling step). x: flat, length % block == 0.
     """
     sign = rademacher_sign(key, block)
-    rot = randomized_fwht(x.reshape(-1, block), sign, mode="encode",
-                          use_kernel=use_kernel)
+    rot = randomized_fwht(x.reshape(-1, block), sign, mode="encode")
     noise = jax.random.uniform(jax.random.fold_in(key, 1), rot.shape)
-    codes = uniform_quant(rot, noise, lohi, bits=bits, use_kernel=use_kernel)
+    codes = uniform_quant(rot, noise, lohi, bits=bits)
     return THCCompressed(codes=codes, lohi=lohi)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block", "nsum", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("bits", "block", "nsum"))
 def thc_decompress_sum(code_sum: jnp.ndarray, key: jax.Array,
                        lohi: jnp.ndarray, *, bits: int = 4, block: int = 4096,
-                       nsum: int = 1, use_kernel: bool = False) -> jnp.ndarray:
+                       nsum: int = 1) -> jnp.ndarray:
     """Dequantize a *sum* of nsum workers' codes, un-rotate, divide by nsum."""
     sign = rademacher_sign(key, block)
     rot_sum = uniform_dequant(code_sum, lohi, bits=bits, nsum=nsum)
     mean_rot = rot_sum / nsum
-    out = randomized_fwht(mean_rot, sign, mode="decode", use_kernel=use_kernel)
+    out = randomized_fwht(mean_rot, sign, mode="decode")
     return out.reshape(-1)

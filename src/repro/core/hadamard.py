@@ -24,31 +24,30 @@ def rademacher_sign(key: jax.Array, block: int) -> jnp.ndarray:
         jnp.float32)
 
 
-def ht_encode(x: jnp.ndarray, key: jax.Array, *, block: int = 4096,
-              use_kernel: bool = False) -> jnp.ndarray:
+def ht_encode(x: jnp.ndarray, key: jax.Array, *,
+              block: int = 4096) -> jnp.ndarray:
     """Encode a flat, block-aligned bucket: per-block H @ (d * x).
 
     Routes through the fused engine's unquantized encode stage
-    (``ht_encode_fused``: sign-flip + FWHT in one pass when the kernel is
-    on) — the bits=0 configuration of kernels/ht_quant.
+    (``ht_encode_fused``: sign-flip + FWHT in one pass in the kernel) —
+    the bits=0 configuration of kernels/ht_quant.
     """
     n = x.shape[-1]
     if n % block:
         raise ValueError(f"bucket length {n} not a multiple of block {block}")
     sign = rademacher_sign(key, block)
-    y = ht_encode_fused(x.reshape(-1, block), sign, use_kernel=use_kernel)
+    y = ht_encode_fused(x.reshape(-1, block), sign)
     return y.reshape(x.shape)
 
 
-def ht_decode(y: jnp.ndarray, key: jax.Array, *, block: int = 4096,
-              use_kernel: bool = False) -> jnp.ndarray:
+def ht_decode(y: jnp.ndarray, key: jax.Array, *,
+              block: int = 4096) -> jnp.ndarray:
     """Inverse of ``ht_encode`` with the same key: per-block d * (H @ y)."""
     n = y.shape[-1]
     if n % block:
         raise ValueError(f"bucket length {n} not a multiple of block {block}")
     sign = rademacher_sign(key, block)
-    x = randomized_fwht(y.reshape(-1, block), sign, mode="decode",
-                        use_kernel=use_kernel)
+    x = randomized_fwht(y.reshape(-1, block), sign, mode="decode")
     return x.reshape(y.shape)
 
 
@@ -57,8 +56,8 @@ def ht_decode(y: jnp.ndarray, key: jax.Array, *, block: int = 4096,
 # materialized: the kernels rotate in VMEM and emit only the reduction
 # (per-block amax) or the uint8 codes (see kernels/ht_quant).
 
-def ht_encode_amax(x: jnp.ndarray, key: jax.Array, *, block: int = 4096,
-                   use_kernel: bool = False) -> jnp.ndarray:
+def ht_encode_amax(x: jnp.ndarray, key: jax.Array, *,
+                   block: int = 4096) -> jnp.ndarray:
     """Per-block amax of ``ht_encode(x)`` without materializing it.
 
     x: flat block-aligned bucket -> (nblocks,) fp32 — the quantization-grid
@@ -69,13 +68,12 @@ def ht_encode_amax(x: jnp.ndarray, key: jax.Array, *, block: int = 4096,
         raise ValueError(f"bucket length {x.shape[-1]} not a multiple of "
                          f"block {block}")
     sign = rademacher_sign(key, block)
-    return ht_amax(x.reshape(-1, block), sign, use_kernel=use_kernel)
+    return ht_amax(x.reshape(-1, block), sign)
 
 
 def ht_encode_quant(x: jnp.ndarray, key: jax.Array, noise: jnp.ndarray,
                     lo: jnp.ndarray, step: jnp.ndarray, *,
-                    block: int = 4096, bits: int = 8,
-                    use_kernel: bool = False) -> jnp.ndarray:
+                    block: int = 4096, bits: int = 8) -> jnp.ndarray:
     """Fused ``ht_encode`` + shared-grid stochastic quantization.
 
     x/noise: flat block-aligned; lo/step: (nblocks,) pmax-shared grids.
@@ -83,4 +81,4 @@ def ht_encode_quant(x: jnp.ndarray, key: jax.Array, noise: jnp.ndarray,
     """
     sign = rademacher_sign(key, block)
     return ht_quant(x.reshape(-1, block), sign, noise.reshape(-1, block),
-                    lo, step, bits=bits, use_kernel=use_kernel)
+                    lo, step, bits=bits)

@@ -21,8 +21,9 @@ strategy a :class:`CollectiveSpec` composing three orthogonal protocols:
               (blockwise randomized HT), :class:`HTQuant` (shared-grid
               uniform stochastic quantization of the rotated blocks — the
               single implementation both the bucketed strategies and the
-              FSDP ``reduce_scatter`` use, kernel-dispatched under
-              ``cfg.use_kernels``).
+              FSDP ``reduce_scatter`` use).  Every codec stage is one
+              kernel wrapper call; ``kernels.runtime`` picks the wrapper's
+              implementation from the backend.
 
 A strategy *name* resolves through a registry of named specs; new
 compositions are one-liners::
@@ -77,7 +78,8 @@ class OptiReduceConfig:
     # Hadamard transform
     use_hadamard: bool = True
     hadamard_block: int = 4096
-    # kernels: use Pallas (TPU) or the jnp MXU-form (identical math)
+    # read by nothing: kernels.runtime picks the codec implementation from
+    # the backend. Kept only because the benchmark's workloads still pass it
     use_kernels: bool = False
     # safeguards
     skip_threshold: float = 0.10
@@ -275,7 +277,7 @@ class Codec:
     def reduce(self, received: jnp.ndarray, mask: jnp.ndarray | None,
                shard_index: jnp.ndarray, enc: Encoded,
                ctx: SyncContext) -> jnp.ndarray:
-        return tar_lib.masked_mean(received, mask, ctx.cfg.use_kernels)
+        return tar_lib.masked_mean(received, mask)
 
     def encode_shard(self, own: jnp.ndarray, shard_index: jnp.ndarray,
                      enc: Encoded, ctx: SyncContext) -> jnp.ndarray:
@@ -302,16 +304,13 @@ class Hadamard(Codec):
         return cfg.hadamard_block
 
     def encode(self, x, ctx, axis):
-        return Encoded(ht_encode(x, ctx.key, block=ctx.cfg.hadamard_block,
-                                 use_kernel=ctx.cfg.use_kernels))
+        return Encoded(ht_encode(x, ctx.key, block=ctx.cfg.hadamard_block))
 
     def decode_gathered(self, gathered, enc, ctx):
-        return ht_decode(gathered, ctx.key, block=ctx.cfg.hadamard_block,
-                         use_kernel=ctx.cfg.use_kernels)
+        return ht_decode(gathered, ctx.key, block=ctx.cfg.hadamard_block)
 
     def decode_values(self, vals, enc, ctx):
-        return ht_decode(vals, ctx.key, block=ctx.cfg.hadamard_block,
-                         use_kernel=ctx.cfg.use_kernels)
+        return ht_decode(vals, ctx.key, block=ctx.cfg.hadamard_block)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -325,11 +324,10 @@ class HTQuant(Codec):
     homomorphic — the THC property, made cheap by the rotation (rotated
     blocks are near-Gaussian with comparable scales).
 
-    Under ``cfg.use_kernels`` all three quantization stages run fused
-    kernels: rotate+amax (grids), sign+FWHT+quantize (stage-1 codes — the
-    rotated fp32 bucket never hits HBM), dequant+compensated-mean (receive),
-    and the stage-2 re-quantization of the aggregated shard dispatches to
-    the grid-quantize kernel.  The jnp path is the bit-parity oracle.
+    Each quantization stage is one fused kernel: rotate+amax (grids),
+    sign+FWHT+quantize (stage-1 codes — the rotated fp32 bucket never hits
+    HBM), dequant+compensated-mean (receive), and grid-quantize (the
+    stage-2 re-quantization of the aggregated shard).
 
     ``bits=None`` reads ``cfg.quant_bits``; ``reduce_scatter_axis`` passes
     ``bits=cfg.rs_wire_bits`` and its own ``noise_salt``.  Not ``linear``:
@@ -353,26 +351,20 @@ class HTQuant(Codec):
         return lo, step
 
     def local_amax(self, x, ctx):
-        """Pre-``pmax`` half of :meth:`encode`: this peer's per-block amax.
+        """Pre-``pmax`` half of :meth:`encode`: this peer's per-block amax
+        of the rotated bucket, which is never materialized.
 
-        Returns ``(x1, amax)`` where ``x1`` is whatever the second half
-        needs (the rotated bucket on the jnp path; the un-rotated bucket on
-        the kernel path, which re-rotates in VMEM).  The host wire datapath
-        calls this, max-shares ``amax`` over the wire (an elementwise max
-        is order-free, so the shared grids are bitwise identical to the
-        fabric ``pmax``), then :meth:`encode_given_amax`.
+        The host wire datapath calls this, max-shares ``amax`` over the
+        wire (an elementwise max is order-free, so the shared grids are
+        bitwise identical to the fabric ``pmax``), then
+        :meth:`encode_given_amax` with the same un-rotated bucket.
         """
-        cfg = ctx.cfg
-        block = cfg.hadamard_block
-        if cfg.use_kernels:
-            amax = ht_encode_amax(x, ctx.key, block=block, use_kernel=True)
-            return x, amax                # rotated bucket never materialized
-        x = ht_encode(x, ctx.key, block=block, use_kernel=False)
-        return x, jnp.max(jnp.abs(x.reshape(-1, block)), axis=1)
+        return ht_encode_amax(x, ctx.key, block=ctx.cfg.hadamard_block)
 
-    def encode_given_amax(self, x1, amax, ctx) -> Encoded:
-        """Post-``pmax`` half of :meth:`encode`: quantize onto the grids
-        derived from the group-shared ``amax``."""
+    def encode_given_amax(self, x, amax, ctx) -> Encoded:
+        """Post-``pmax`` half of :meth:`encode`: rotate and quantize the
+        un-rotated bucket onto the grids derived from the group-shared
+        ``amax``."""
         cfg = ctx.cfg
         block = cfg.hadamard_block
         bits = self._bits(cfg)
@@ -382,39 +374,27 @@ class HTQuant(Codec):
         lo = -amax
         noise = jax.random.uniform(
             jax.random.fold_in(ctx.key, self.noise_salt),
-            (x1.size // block, block))
-        if cfg.use_kernels:
-            codes = ht_encode_quant(x1, ctx.key, noise, lo, step, block=block,
-                                    bits=bits, use_kernel=True)
-        else:
-            xb = x1.reshape(-1, block)
-            q = jnp.floor((xb - lo[:, None]) / step[:, None] + noise)
-            codes = jnp.clip(q, 0, levels).astype(jnp.uint8)
+            (x.size // block, block))
+        codes = ht_encode_quant(x, ctx.key, noise, lo, step, block=block,
+                                bits=bits)
         # the codes keep the bucket's layout: flat, or its (rows, L) slab
-        return Encoded(codes.reshape(x1.shape), lo=lo, step=step)
+        return Encoded(codes.reshape(x.shape), lo=lo, step=step)
 
     def encode(self, x, ctx, axis):
-        x1, amax = self.local_amax(x, ctx)
+        amax = self.local_amax(x, ctx)
         amax = jax.lax.pmax(amax, axis)
         for extra in ctx.data_axes():     # grids shared by the full DP group
             if extra != axis:
                 amax = jax.lax.pmax(amax, extra)
-        return self.encode_given_amax(x1, amax, ctx)
+        return self.encode_given_amax(x, amax, ctx)
 
     def reduce(self, received, mask, shard_index, enc, ctx):
         # received: (N, S) flat or (N, S/block, block) tile-dense codes
-        cfg = ctx.cfg
-        block = cfg.hadamard_block
-        n = received.shape[0]
+        block = ctx.cfg.hadamard_block
         nblk = received[0].size // block
         my_lo, my_step = self._grids(enc, shard_index, nblk)
-        if cfg.use_kernels:
-            return dequant_masked_mean(received, my_lo, my_step, mask,
-                                       block=block, use_kernel=True)
-        vals = (received.reshape(n, nblk, block).astype(jnp.float32)
-                * my_step[None, :, None] + my_lo[None, :, None]
-                ).reshape(received.shape)
-        return tar_lib.masked_mean(vals, mask, cfg.use_kernels)
+        return dequant_masked_mean(received, my_lo, my_step, mask,
+                                   block=block)
 
     def encode_shard(self, own, shard_index, enc, ctx):
         cfg = ctx.cfg
@@ -424,7 +404,7 @@ class HTQuant(Codec):
         noise = jax.random.uniform(
             jax.random.fold_in(ctx.key, self.stage2_salt), (nblk, block))
         codes = grid_quant(own.reshape(nblk, block), noise, my_lo, my_step,
-                           bits=self._bits(cfg), use_kernel=cfg.use_kernels)
+                           bits=self._bits(cfg))
         return codes.reshape(own.shape)
 
     def decode_gathered(self, gathered, enc, ctx):
@@ -432,12 +412,10 @@ class HTQuant(Codec):
         block = cfg.hadamard_block
         out = (gathered.reshape(-1, block).astype(jnp.float32)
                * enc.step[:, None] + enc.lo[:, None]).reshape(gathered.shape)
-        return ht_decode(out, ctx.key, block=block,
-                         use_kernel=cfg.use_kernels)
+        return ht_decode(out, ctx.key, block=block)
 
     def decode_values(self, vals, enc, ctx):
-        return ht_decode(vals, ctx.key, block=ctx.cfg.hadamard_block,
-                         use_kernel=ctx.cfg.use_kernels)
+        return ht_decode(vals, ctx.key, block=ctx.cfg.hadamard_block)
 
 
 # --------------------------------------------------------------- transports
@@ -851,9 +829,7 @@ class TarTopology(Topology):
             # the pods' split is the flat one: dense rows only if they cut
             # into g whole-row chunks (the same elements)
             x = own if own.shape[0] % g == 0 else own.reshape(-1)
-            return tar_lib.tar_allreduce(
-                x, cfg.pod_axis, use_kernel=cfg.use_kernels
-            ).reshape(own.shape)
+            return tar_lib.tar_allreduce(x, cfg.pod_axis).reshape(own.shape)
         return jax.lax.pmean(own, cfg.pod_axis)
 
     def _participation(self, cfg: OptiReduceConfig, n: int):
@@ -904,8 +880,7 @@ class TarTopology(Topology):
             # overlaps bucket k-1's shard exchange instead of serializing
             # after this bucket's rotation.  (StaleFill never wraps a
             # non-linear codec, so local_amax is a safe discriminator.)
-            x1, amax = codec.local_amax(x, ctx)
-            return (x1, None, None, None, amax)
+            return (x, None, None, None, codec.local_amax(x, ctx))
         enc = codec.encode(x, ctx, cfg.data_axis)
         # 4th slot: the re-encoded stale bucket a recovery codec may attach
         # (None otherwise — an empty pytree leaf, so the disabled path's

@@ -221,9 +221,8 @@ def ef_residual(bucket: jnp.ndarray, key: jax.Array, cfg, n: int,
     arrival = sender_arrival_masks(cfg, key, n, s)
     mine = jax.lax.dynamic_slice_in_dim(arrival, me, 1, 0)[0]
     if cfg.use_hadamard:
-        w = ht_encode(x, key, block=block, use_kernel=cfg.use_kernels)
-        resid = ht_decode((1.0 - mine) * w, key, block=block,
-                          use_kernel=cfg.use_kernels)
+        w = ht_encode(x, key, block=block)
+        resid = ht_decode((1.0 - mine) * w, key, block=block)
     else:
         resid = (1.0 - mine) * x
     return resid[:length].reshape(bucket.shape).astype(bucket.dtype)

@@ -79,25 +79,21 @@ def shard_rows(x: jnp.ndarray, n: int) -> jnp.ndarray:
     return x.reshape((n, x.shape[0] // n) + x.shape[1:])
 
 
-def masked_mean(received: jnp.ndarray, mask: jnp.ndarray | None,
-                use_kernel: bool = False) -> jnp.ndarray:
+def masked_mean(received: jnp.ndarray,
+                mask: jnp.ndarray | None) -> jnp.ndarray:
     """Drop-compensated mean over the peer axis. received: (N, S), or the
     tile-dense (N, S / L, L) shard view.
 
     The public reduction every codec shares: no mask -> plain mean; with an
-    arrival mask -> the masked compensated mean, dispatching to the Pallas
-    kernel under ``use_kernel``.
+    arrival mask -> the masked compensated mean (kernels/masked_sum).
     """
     if mask is None:
         return jnp.mean(received, axis=0)
-    if use_kernel:
-        return _masked_mean_kernel(received, mask, use_kernel=True)
-    return masked_mean_ref(received, mask)
+    return _masked_mean_kernel(received, mask)
 
 
 def tar_reduce_scatter(x: jnp.ndarray, axis: str, *,
-                       mask: jnp.ndarray | None = None,
-                       use_kernel: bool = False) -> jnp.ndarray:
+                       mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """TAR stage 1 + reduce: returns this node's aggregated shard (S,).
 
     x: flat (L,), L % N == 0, or tile-dense rows (N divides them). mask:
@@ -108,14 +104,13 @@ def tar_reduce_scatter(x: jnp.ndarray, axis: str, *,
     shards = shard_rows(x, n)
     received = jax.lax.all_to_all(shards, axis, split_axis=0, concat_axis=0,
                                   tiled=True)          # (N, S): row p = peer p's shard for me
-    return masked_mean(received, mask, use_kernel)
+    return masked_mean(received, mask)
 
 
 def tar_allreduce(x: jnp.ndarray, axis: str, *,
-                  mask: jnp.ndarray | None = None,
-                  use_kernel: bool = False) -> jnp.ndarray:
+                  mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """Full TAR: all_to_all -> compensated reduce -> all_gather. (L,)->(L,)."""
-    own = tar_reduce_scatter(x, axis, mask=mask, use_kernel=use_kernel)
+    own = tar_reduce_scatter(x, axis, mask=mask)
     return jax.lax.all_gather(own, axis, axis=0, tiled=True)
 
 
@@ -480,22 +475,20 @@ def tar_allreduce_rounds(x: jnp.ndarray, axis: str, *, incast: int = 1,
 
 def tar_allreduce_2d(x: jnp.ndarray, inner_axis: str, outer_axis: str, *,
                      mask: jnp.ndarray | None = None,
-                     outer_mask: jnp.ndarray | None = None,
-                     use_kernel: bool = False) -> jnp.ndarray:
+                     outer_mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """Hierarchical 2D TAR (§3.1.2 / App. A): groups = pods.
 
     1. intra-group: TAR reduce-scatter over ``inner_axis``  (N/G - 1 rounds)
     2. inter-group: same-rank aggregation over ``outer_axis``  (G - 1 rounds)
     3. intra-group broadcast over ``inner_axis``            (N/G - 1 rounds)
     """
-    own = tar_reduce_scatter(x, inner_axis, mask=mask, use_kernel=use_kernel)
+    own = tar_reduce_scatter(x, inner_axis, mask=mask)
     g = jax.lax.axis_size(outer_axis)
     if g > 1:
         s = own.shape[0]
         if s % g == 0:
             # TAR across pods too: shard my shard over the outer axis.
-            own = tar_allreduce(own, outer_axis, mask=outer_mask,
-                                use_kernel=use_kernel)
+            own = tar_allreduce(own, outer_axis, mask=outer_mask)
         else:
             own = jax.lax.pmean(own, outer_axis)
     return jax.lax.all_gather(own, inner_axis, axis=0, tiled=True)

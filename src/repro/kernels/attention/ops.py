@@ -3,12 +3,12 @@
 ``attention(q, k, v, scale=..., causal=...)`` runs the fused Pallas kernel
 where it applies and the dense oracle (``ref.attention_ref``) everywhere
 else. The choice follows from what the call can see, with no setting of
-its own: the kernel runs when the process kernel mode (kernels/runtime)
-resolves to ``compile`` (a TPU backend), the call is causal, the operands
-are bf16, the head size is one the kernel takes, the heads fill whole
-128-lane groups, and the sequence is a multiple of a block. On the CPU and
-in interpret mode the dense path runs as it always has; tests reach the
-interpreted kernel by passing ``interpret=True``.
+its own: the kernel runs when ``kernels.runtime.impl()`` is ``mosaic`` (a
+TPU backend), the call is causal, the operands are bf16, the head size is
+one the kernel takes, the heads fill whole 128-lane groups, and the
+sequence is a multiple of a block. Under ``jnp`` and ``interpret`` the
+dense path runs as it always has; tests reach the interpreted kernel by
+passing ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -49,7 +49,7 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """
     applies = kernel_applies(q.shape, q.dtype, causal=causal)
     if interpret is None:
-        if not (applies and runtime.resolve() == "compile"):
+        if not (applies and runtime.impl() == "mosaic"):
             return attention_ref(q, k, v, scale=scale, causal=causal)
         interpret = False
     elif not applies:

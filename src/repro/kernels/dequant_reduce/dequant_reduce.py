@@ -52,7 +52,7 @@ def dequant_masked_mean_pallas(codes: jnp.ndarray, lo_col: jnp.ndarray,
     step_col: (rows, 1) per-row grids; mask: (N, rows, L) 0/1 arrivals or
     None (lossless). Returns (rows, L) fp32.
     ``block_rows`` overrides the rows a grid block takes.
-    ``interpret=None`` resolves the process kernel mode (kernels/runtime).
+    ``interpret=None`` takes ``runtime.interpret_flag()``.
     """
     if interpret is None:
         interpret = runtime.interpret_flag()

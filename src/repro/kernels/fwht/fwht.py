@@ -91,8 +91,8 @@ def fwht_pallas(x: jnp.ndarray,
 
     sign_mode: 'none' | 'pre' (encode: H @ (d*x)) | 'post' (decode: d * (H@y)).
     ``sign`` is required unless sign_mode == 'none'; shape (n,).
-    ``interpret=None`` resolves the process kernel mode (kernels/runtime);
-    the resolved flag is a static jit argument, so mode flips retrace.
+    ``interpret=None`` takes ``runtime.interpret_flag()``;
+    the flag is a static jit argument, so a change of it retraces.
     """
     if interpret is None:
         interpret = runtime.interpret_flag()

@@ -114,6 +114,17 @@ def fwht_mxu_ref(x: jnp.ndarray) -> jnp.ndarray:
     return y.reshape(orig_shape).astype(orig_dtype)
 
 
+def randomized_fwht_mxu_ref(x: jnp.ndarray, sign: jnp.ndarray, *,
+                            mode: str) -> jnp.ndarray:
+    """``randomized_fwht_ref`` in the kernel's lane-chunk form: the jnp
+    implementation of ``ops.randomized_fwht``. x: (rows, n)."""
+    if mode == "encode":
+        return fwht_mxu_ref(x * sign[None, :])
+    if mode == "decode":
+        return fwht_mxu_ref(x) * sign[None, :]
+    raise ValueError(f"unknown mode {mode!r}")
+
+
 def randomized_fwht_ref(
     x: jnp.ndarray, sign: jnp.ndarray, *, mode: str
 ) -> jnp.ndarray:

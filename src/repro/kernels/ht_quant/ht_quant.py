@@ -105,7 +105,7 @@ def ht_amax_pallas(x: jnp.ndarray, sign: jnp.ndarray, *,
                    interpret: bool | None = None) -> jnp.ndarray:
     """Per-block amax of the rotated blocks. x: (rows, n) -> (rows,) fp32.
 
-    ``interpret=None`` resolves the process kernel mode (kernels/runtime).
+    ``interpret=None`` takes ``runtime.interpret_flag()``.
     """
     if interpret is None:
         interpret = runtime.interpret_flag()
@@ -151,7 +151,7 @@ def ht_quant_pallas(x: jnp.ndarray, sign: jnp.ndarray, noise: jnp.ndarray,
 
     x/noise: (rows, n); lo/step: (rows,) per-block grid bounds (already
     pmax-shared across workers). Returns (rows, n) uint8 codes.
-    ``interpret=None`` resolves the process kernel mode (kernels/runtime).
+    ``interpret=None`` takes ``runtime.interpret_flag()``.
     """
     if interpret is None:
         interpret = runtime.interpret_flag()

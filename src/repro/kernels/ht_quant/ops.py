@@ -1,16 +1,15 @@
 """Jit'd public wrappers for the fused HT-encode + quantize kernels.
 
-``ht_amax`` / ``ht_quant`` operate on (rows, block) — one Hadamard block per
-row, the layout ``core.allreduce`` already uses. ``use_kernel`` selects the
-Pallas kernel; the jnp oracle is identical math.  Whether the Pallas path
-runs interpreted or Mosaic-compiled resolves through the process kernel-mode
-policy (kernels/runtime) outside the jit boundary, so the resolved flag is
-part of the cache key.
+``ht_amax`` / ``ht_quant`` operate on (rows, block) -- one Hadamard block
+per row, the layout ``core.allreduce`` already uses. Each runs the
+implementation ``kernels.runtime.impl()`` names -- the Pallas kernel,
+compiled or interpreted, or its ``ref.py`` oracle (identical math) -- read
+outside the jit boundary, so the choice is part of the cache key.
 
 The unquantized fused variant of the engine is the existing sign+FWHT
-single-pass kernel (``randomized_fwht(..., use_kernel=True)``); ``ht_encode
-_fused`` re-exports it here so the sync engine has one dispatch surface for
-both the quantized (bits>0) and unquantized (bits=0) encode stages.
+single-pass kernel (``randomized_fwht``); ``ht_encode_fused`` re-exports it
+here so the sync engine has one dispatch surface for both the quantized
+(bits>0) and unquantized (bits=0) encode stages.
 """
 from __future__ import annotations
 
@@ -26,54 +25,47 @@ from .ht_quant import ht_amax_pallas, ht_quant_pallas
 from .ref import ht_amax_ref, ht_quant_ref, ht_rotate_ref  # noqa: F401
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("use_kernel", "block_rows", "interpret"))
-def _ht_amax(x: jnp.ndarray, sign: jnp.ndarray, *, use_kernel: bool,
-             block_rows: int, interpret: bool) -> jnp.ndarray:
-    if use_kernel:
-        return ht_amax_pallas(x, sign, block_rows=block_rows,
-                              interpret=interpret)
-    return ht_amax_ref(x, sign)
+@functools.partial(jax.jit, static_argnames=("block_rows", "impl"))
+def _ht_amax(x: jnp.ndarray, sign: jnp.ndarray, *, block_rows: int,
+             impl: str) -> jnp.ndarray:
+    if impl == "jnp":
+        return ht_amax_ref(x, sign)
+    return ht_amax_pallas(x, sign, block_rows=block_rows,
+                          interpret=impl == "interpret")
 
 
-def ht_amax(x: jnp.ndarray, sign: jnp.ndarray, *, use_kernel: bool = False,
+def ht_amax(x: jnp.ndarray, sign: jnp.ndarray, *,
             block_rows: int = 64) -> jnp.ndarray:
     """Per-block amax of the rotated blocks, without materializing them.
 
     x: (rows, block) -> (rows,) fp32.
     """
-    return _ht_amax(
-        x, sign, use_kernel=use_kernel, block_rows=block_rows,
-        interpret=runtime.interpret_flag() if use_kernel else True)
+    return _ht_amax(x, sign, block_rows=block_rows, impl=runtime.impl())
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "use_kernel",
-                                             "block_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits", "block_rows", "impl"))
 def _ht_quant(x: jnp.ndarray, sign: jnp.ndarray, noise: jnp.ndarray,
               lo: jnp.ndarray, step: jnp.ndarray, *, bits: int,
-              use_kernel: bool, block_rows: int,
-              interpret: bool) -> jnp.ndarray:
-    if use_kernel:
-        return ht_quant_pallas(x, sign, noise, lo, step, bits=bits,
-                               block_rows=block_rows, interpret=interpret)
-    return ht_quant_ref(x, sign, noise, lo.reshape(-1), step.reshape(-1),
-                        bits=bits)
+              block_rows: int, impl: str) -> jnp.ndarray:
+    if impl == "jnp":
+        return ht_quant_ref(x, sign, noise, lo.reshape(-1),
+                            step.reshape(-1), bits=bits)
+    return ht_quant_pallas(x, sign, noise, lo, step, bits=bits,
+                           block_rows=block_rows,
+                           interpret=impl == "interpret")
 
 
 def ht_quant(x: jnp.ndarray, sign: jnp.ndarray, noise: jnp.ndarray,
              lo: jnp.ndarray, step: jnp.ndarray, *, bits: int = 8,
-             use_kernel: bool = False, block_rows: int = 64) -> jnp.ndarray:
+             block_rows: int = 64) -> jnp.ndarray:
     """Fused sign-flip + FWHT + stochastic uniform quantization.
 
     x/noise: (rows, block); lo/step: (rows,) shared grids -> uint8 codes.
     """
-    return _ht_quant(
-        x, sign, noise, lo, step, bits=bits, use_kernel=use_kernel,
-        block_rows=block_rows,
-        interpret=runtime.interpret_flag() if use_kernel else True)
+    return _ht_quant(x, sign, noise, lo, step, bits=bits,
+                     block_rows=block_rows, impl=runtime.impl())
 
 
-def ht_encode_fused(x: jnp.ndarray, sign: jnp.ndarray, *,
-                    use_kernel: bool = False) -> jnp.ndarray:
+def ht_encode_fused(x: jnp.ndarray, sign: jnp.ndarray) -> jnp.ndarray:
     """Unquantized fused encode (sign+FWHT one-pass): the bits=0 stage."""
-    return randomized_fwht(x, sign, mode="encode", use_kernel=use_kernel)
+    return randomized_fwht(x, sign, mode="encode")

@@ -105,8 +105,8 @@ def masked_mean_pallas(shards: jnp.ndarray, mask: jnp.ndarray, *,
     (N, rows, L) -> (rows, L), or flat (N, S) -> (S,).
 
     ``block_rows`` overrides the rows a grid block takes (default ~1 MB of
-    f32 per operand block). ``interpret=None`` resolves the process kernel
-    mode (kernels/runtime).
+    f32 per operand block). ``interpret=None`` takes
+    ``runtime.interpret_flag()``.
     """
     if interpret is None:
         interpret = runtime.interpret_flag()

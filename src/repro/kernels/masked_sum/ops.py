@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the drop-compensated shard reduction.
 
-The Pallas path's interpret/compile flag resolves through the process
-kernel-mode policy (kernels/runtime) outside the jit boundary, so the
-resolved flag is part of the cache key.
+It runs the implementation ``kernels.runtime.impl()`` names -- the Pallas
+kernel, compiled or interpreted, or its ``ref.py`` oracle -- read outside
+the jit boundary, so the choice is part of the cache key.
 """
 from __future__ import annotations
 
@@ -17,18 +17,15 @@ from .masked_sum import masked_mean_pallas
 from .ref import masked_mean_ref
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("impl",))
 def _masked_mean(shards: jnp.ndarray, mask: jnp.ndarray, *,
-                 use_kernel: bool, interpret: bool) -> jnp.ndarray:
-    if use_kernel:
-        return masked_mean_pallas(shards, mask, interpret=interpret)
-    return masked_mean_ref(shards, mask)
+                 impl: str) -> jnp.ndarray:
+    if impl == "jnp":
+        return masked_mean_ref(shards, mask)
+    return masked_mean_pallas(shards, mask, interpret=impl == "interpret")
 
 
-def masked_mean(shards: jnp.ndarray, mask: jnp.ndarray, *,
-                use_kernel: bool = False) -> jnp.ndarray:
+def masked_mean(shards: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     """Drop-compensated mean over N peer shards. (N, L) x (N, L) -> (L,);
     the tile-dense (N, rows, L) shard view reduces to (rows, L)."""
-    return _masked_mean(
-        shards, mask, use_kernel=use_kernel,
-        interpret=runtime.interpret_flag() if use_kernel else True)
+    return _masked_mean(shards, mask, impl=runtime.impl())

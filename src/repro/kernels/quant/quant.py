@@ -63,7 +63,7 @@ def grid_quant_pallas(x: jnp.ndarray, noise: jnp.ndarray, lo: jnp.ndarray,
     The grid-aware sibling of :func:`uniform_quant_pallas`: lo/step are
     (rows,) operands tiled alongside the data, so one pass covers every
     Hadamard block of a shard (TAR stage-2 re-quantization).
-    ``interpret=None`` resolves the process kernel mode (kernels/runtime)."""
+    ``interpret=None`` takes ``runtime.interpret_flag()``."""
     if interpret is None:
         interpret = runtime.interpret_flag()
     return _grid_quant_call(x, noise, lo, step, bits=bits,
@@ -113,7 +113,7 @@ def uniform_quant_pallas(x: jnp.ndarray, noise: jnp.ndarray,
                          block_rows: int = 128,
                          interpret: bool | None = None) -> jnp.ndarray:
     """Quantize (rows, C) onto the shared [lo, hi] grid. lohi: shape (2,).
-    ``interpret=None`` resolves the process kernel mode (kernels/runtime)."""
+    ``interpret=None`` takes ``runtime.interpret_flag()``."""
     if interpret is None:
         interpret = runtime.interpret_flag()
     return _uniform_quant_call(x, noise, lohi, bits=bits,

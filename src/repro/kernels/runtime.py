@@ -1,51 +1,37 @@
-"""Kernel dispatch policy: run Pallas kernels interpreted or compiled.
+"""Kernel dispatch: the one place that decides how a codec kernel runs.
 
-Every Pallas call site in the repo used to hard-code ``interpret=True`` —
-correct on the CPU CI box, silently wrong on a real TPU (the fused engine
-would run in the interpreter even with Mosaic available). This module is
-the single resolution point:
+:func:`impl` names the implementation every kernel wrapper (each package's
+``ops.py``) runs:
 
-  ``interpret``  force the Pallas interpreter (any backend; bit-exact to
-                 the historical behaviour).
-  ``compile``    force Mosaic compilation; raises immediately on a backend
-                 without Mosaic support instead of surfacing a cryptic
-                 lowering failure from inside a kernel.
-  ``auto``       compile iff ``jax.default_backend() == "tpu"`` (default).
+  ``mosaic``     the Pallas kernel, compiled by Mosaic (TPU only; asking
+                 for it on another backend raises at once, not from inside
+                 a kernel's lowering).
+  ``interpret``  the Pallas kernel in the Pallas interpreter (any backend).
+  ``jnp``        the kernel's ``ref.py`` form (same math, plain XLA).
 
-Precedence: an explicit :func:`set_kernel_mode` (the ``--kernel-mode``
-launcher flag / ``TrainConfig.kernel_mode``) > the ``REPRO_KERNEL_MODE``
-environment variable > ``auto``.
+It follows from ``jax.default_backend()``: ``mosaic`` on a TPU, ``jnp``
+everywhere else. :func:`impl_scope` overrides it for tests and kernel
+benchmarks, which run the kernels in the interpreter on the CPU or lower
+Mosaic for a described TPU.
 
-The public kernel wrappers (each package's ``ops.py``) resolve the flag
-*outside* their ``jax.jit`` boundary and pass it through as a static
-argument, so the resolved mode is part of every kernel's jit cache key and
-flipping the mode mid-process cannot hit a stale trace.  Caveat: a caller
-that jits a *larger* step function around the wrappers bakes the mode in at
-its own trace time — set the mode before building train steps.
+The wrappers read :func:`impl` *outside* their ``jax.jit`` and pass it in
+as a static argument, so the choice is part of every kernel's jit cache
+key. A caller that jits a larger step around the wrappers fixes the choice
+at its own trace time.
 """
 from __future__ import annotations
 
 import contextlib
 import logging
-import os
 
 import jax
 
-ENV_VAR = "REPRO_KERNEL_MODE"
-MODES = ("auto", "interpret", "compile")
+IMPLS = ("mosaic", "interpret", "jnp")
 
 logger = logging.getLogger("repro.kernels.runtime")
 
-_explicit: str | None = None      # set_kernel_mode override
-_logged_resolution: str | None = None
-
-
-def _check(mode: str) -> str:
-    mode = str(mode).strip().lower()
-    if mode not in MODES:
-        raise ValueError(
-            f"unknown kernel mode {mode!r}; expected one of {MODES}")
-    return mode
+_override: str | None = None
+_logged: str | None = None        # the last implementation logged
 
 
 def _mosaic_available() -> bool:
@@ -53,55 +39,38 @@ def _mosaic_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def kernel_mode() -> str:
-    """The *configured* mode: explicit override > $REPRO_KERNEL_MODE > auto."""
-    if _explicit is not None:
-        return _explicit
-    env = os.environ.get(ENV_VAR, "")
-    if env.strip():
-        return _check(env)
-    return "auto"
-
-
-def set_kernel_mode(mode: str | None) -> None:
-    """Set (or, with ``None``, clear) the process-wide explicit mode."""
-    global _explicit, _logged_resolution
-    _explicit = None if mode is None else _check(mode)
-    _logged_resolution = None      # re-log on the next resolve
-
-
 @contextlib.contextmanager
-def kernel_mode_scope(mode: str | None):
-    """Temporarily pin the kernel mode (tests / benchmark sweeps)."""
-    prev = _explicit
-    set_kernel_mode(mode)
+def impl_scope(name: str):
+    """Run the kernel wrappers as ``name`` (one of :data:`IMPLS`) inside
+    the block; tests and kernel benchmarks only."""
+    global _override
+    if name not in IMPLS:
+        raise ValueError(f"unknown kernel implementation {name!r}; "
+                         f"expected one of {IMPLS}")
+    prev, _override = _override, name
     try:
         yield
     finally:
-        set_kernel_mode(prev)
+        _override = prev
 
 
-def resolve() -> str:
-    """'interpret' or 'compile' for this process, validated against the
-    backend — ``compile`` without Mosaic is an immediate, legible error."""
-    mode = kernel_mode()
-    backend = jax.default_backend()
-    if mode == "compile" and not _mosaic_available():
+def impl() -> str:
+    """The implementation the kernel wrappers run now (see module doc)."""
+    name = _override or ("mosaic" if _mosaic_available() else "jnp")
+    if name == "mosaic" and not _mosaic_available():
         raise RuntimeError(
-            f"kernel_mode='compile' needs a TPU (Mosaic) backend but "
-            f"jax.default_backend() is {backend!r}. Use "
-            f"kernel_mode='interpret' to run the kernels in the Pallas "
-            f"interpreter here, or 'auto' to pick per-backend.")
-    resolved = mode if mode != "auto" else (
-        "compile" if _mosaic_available() else "interpret")
-    global _logged_resolution
-    if _logged_resolution != resolved:
-        _logged_resolution = resolved
-        logger.info("kernel dispatch: mode=%s -> %s (backend=%s)",
-                    mode, resolved, backend)
-    return resolved
+            f"the 'mosaic' kernel implementation needs a TPU backend but "
+            f"jax.default_backend() is {jax.default_backend()!r}; use "
+            f"'interpret' to run the Pallas kernels in the interpreter here")
+    global _logged
+    if _logged != name:
+        _logged = name
+        logger.info("codec kernels: %s (backend %s)", name,
+                    jax.default_backend())
+    return name
 
 
 def interpret_flag() -> bool:
-    """The ``interpret=`` value a ``pl.pallas_call`` should receive now."""
-    return resolve() == "interpret"
+    """The ``interpret=`` a bare Pallas kernel call receives: compiled
+    under ``mosaic``, the interpreter otherwise."""
+    return impl() != "mosaic"

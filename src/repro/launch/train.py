@@ -162,11 +162,6 @@ def main(argv=None) -> dict:
                     help="bucket schedule: stage-skewed software pipeline "
                          "(overlap encode/exchange/decode across buckets), "
                          "strict scan, or batched vmap — bitwise-identical")
-    ap.add_argument("--kernel-mode", default=None,
-                    choices=("auto", "interpret", "compile"),
-                    help="Pallas kernel dispatch: Mosaic-compile, interpret, "
-                         "or auto (compile iff on a TPU backend); default "
-                         "defers to REPRO_KERNEL_MODE / 'auto'")
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--microbatch", type=int, default=None)
@@ -280,7 +275,6 @@ def main(argv=None) -> dict:
         sync_mode=args.sync_mode,
         transport_override=(WireTransport(ring.bridge_exchange)
                             if ring else None),
-        kernel_mode=args.kernel_mode,
         seq_chunk=min(512, args.seq_len))
 
     data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size,

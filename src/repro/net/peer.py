@@ -209,7 +209,7 @@ class HostPeer:
         if isinstance(codec, HTQuant):
             def enc_local(x, key):
                 x, _ = tar_lib.pad_for_tar(x, pad_n, codec.block(cfg))
-                return codec.local_amax(x, self._ctx(key))
+                return x, codec.local_amax(x, self._ctx(key))
 
             def enc_finish(x1, amax, key):
                 e = codec.encode_given_amax(x1, amax, self._ctx(key))

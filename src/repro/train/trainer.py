@@ -21,7 +21,9 @@ defaults to ``"pipelined"``, the stage-skewed software schedule in
 bucket k-1, and decodes bucket k-2 — the paper's "two in-flight buckets"
 (§5) expressed as a depth-2 skew whose in-flight payloads ride in the scan
 carry, so the exchange collectives overlap neighboring buckets' codec
-kernels by construction (see PERF.md for the skew diagram).
+kernels by construction (see PERF.md for the skew diagram). Those kernels
+run Mosaic-compiled on a TPU and as their jnp forms elsewhere:
+``kernels.runtime`` decides from the backend, no field of this config.
 
 The replicated path also keeps the whole gradient stream in a **packed
 arena**: micro-batch accumulation adds each microbatch's grads directly
@@ -50,7 +52,6 @@ from repro.core.allreduce import (OptiReduceConfig, SyncContext, rs_spec,
 from repro.core.bucket_plan import BucketPlan
 from repro.core.pipeline import arena_lane, resolve_spec
 from repro.core.safeguards import guard_scale, guard_update
-from repro.kernels import runtime as kernel_runtime
 from repro.models import lm_loss, param_specs, param_table
 from repro.models.parallel import ParallelCtx
 from repro.models.transformer import _tree_map_table
@@ -90,10 +91,6 @@ class TrainConfig:
     # spec's transport, so stage-1 arrival masks come from a real packet
     # exchange instead of the synthetic drop model. Replicated DP only.
     transport_override: Any = None
-    # Pallas kernel dispatch (DESIGN §11): 'interpret' | 'compile' | 'auto'
-    # (auto = Mosaic-compile iff running on a TPU backend). None leaves the
-    # process-level policy (REPRO_KERNEL_MODE / kernels.runtime) untouched.
-    kernel_mode: str | None = None
 
 
 def mesh_axis_names(mesh) -> tuple[str, ...]:
@@ -229,10 +226,6 @@ def packed_global_norm(batch: jnp.ndarray, plan: BucketPlan,
 def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh):
     """Returns (step_fn, shardings) where step_fn(params, opt_state, batch,
     step, key) -> (params, opt_state, metrics), jit-able under ``mesh``."""
-    if tc.kernel_mode is not None:
-        # set before any kernel shim resolves (trace time), so the whole
-        # step traces under one dispatch mode
-        kernel_runtime.set_kernel_mode(tc.kernel_mode)
     names = mesh_axis_names(mesh)
     if tc.pure_dp:
         assert "pod" not in names, "pure_dp is a single-pod remap"

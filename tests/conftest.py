@@ -30,3 +30,10 @@ except ImportError:       # hypothesis absent: profile registration is
 if settings is not None:
     settings.register_profile("ci", max_examples=20, deadline=None)
     settings.load_profile("ci")
+
+
+@pytest.fixture(params=["jnp", "interpret"])
+def impl(request):
+    """Each codec kernel implementation that runs off the TPU: a test
+    enters it with ``kernels.runtime.impl_scope(impl)``."""
+    return request.param

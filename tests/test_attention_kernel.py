@@ -120,47 +120,48 @@ def _old_attention_train(x, w, *, positions, rope_theta=10000.0):
 
 
 @pytest.mark.parametrize("hq, hkv", [(4, 4), (4, 2)], ids=["mha", "gqa"])
-def test_attention_train_on_cpu_is_the_dense_path_bitwise(hq, hkv):
-    """On the CPU the kernel mode resolves to interpret, so the shape the
-    kernel would take runs dense, and the layer's output and gradients
-    are bitwise what they were."""
-    assert runtime.resolve() == "interpret"
-    b, s, d, dh = 2, 256, 128, 64
-    ks = jax.random.split(jax.random.PRNGKey(7), 5)
-    bf = jnp.bfloat16
-    x = jax.random.normal(ks[0], (b, s, d)).astype(bf)
-    w = {"wq": (jax.random.normal(ks[1], (d, hq * dh)) * 0.05).astype(bf),
-         "wk": (jax.random.normal(ks[2], (d, hkv * dh)) * 0.05).astype(bf),
-         "wv": (jax.random.normal(ks[3], (d, hkv * dh)) * 0.05).astype(bf),
-         "wo": (jax.random.normal(ks[4], (hq * dh, d)) * 0.05).astype(bf),
-         "head_dim": dh, "attn_chunk": 4096}
-    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    assert kernel_applies((b, s, hq, dh), bf, causal=True)
+def test_attention_train_on_cpu_is_the_dense_path_bitwise(hq, hkv, impl):
+    """Off the TPU (the default ``jnp`` and the ``interpret`` codec
+    implementations) the shape the kernel would take runs dense, and the
+    layer's output and gradients are bitwise what they were."""
+    assert runtime.impl() == "jnp"
+    with runtime.impl_scope(impl):
+        b, s, d, dh = 2, 256, 128, 64
+        ks = jax.random.split(jax.random.PRNGKey(7), 5)
+        bf = jnp.bfloat16
+        x = jax.random.normal(ks[0], (b, s, d)).astype(bf)
+        w = {"wq": (jax.random.normal(ks[1], (d, hq * dh)) * 0.05).astype(bf),
+             "wk": (jax.random.normal(ks[2], (d, hkv * dh)) * 0.05).astype(bf),
+             "wv": (jax.random.normal(ks[3], (d, hkv * dh)) * 0.05).astype(bf),
+             "wo": (jax.random.normal(ks[4], (hq * dh, d)) * 0.05).astype(bf),
+             "head_dim": dh, "attn_chunk": 4096}
+        pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        assert kernel_applies((b, s, hq, dh), bf, causal=True)
 
-    def new(x, w):
-        return attention_train(x, w, ParallelCtx(), positions=pos)
+        def new(x, w):
+            return attention_train(x, w, ParallelCtx(), positions=pos)
 
-    def old(x, w):
-        return _old_attention_train(x, w, positions=pos)
+        def old(x, w):
+            return _old_attention_train(x, w, positions=pos)
 
-    params = {n: w[n] for n in ("wq", "wk", "wv", "wo")}
-    meta = {n: w[n] for n in ("head_dim", "attn_chunk")}
-    for fn in (new, old):
-        assert "pallas_call" not in str(jax.make_jaxpr(
-            lambda x, p: fn(x, {**p, **meta}))(x, params))
-    out_new, out_old = (jax.jit(lambda x, p: fn(x, {**p, **meta}))(x, params)
-                        for fn in (new, old))
-    np.testing.assert_array_equal(_f32(out_new), _f32(out_old))
-    g_new, g_old = (jax.jit(jax.grad(
-        lambda x, p: fn(x, {**p, **meta}).astype(jnp.float32).sum(),
-        argnums=(0, 1)))(x, params) for fn in (new, old))
-    for a, c in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
-        np.testing.assert_array_equal(_f32(a), _f32(c))
+        params = {n: w[n] for n in ("wq", "wk", "wv", "wo")}
+        meta = {n: w[n] for n in ("head_dim", "attn_chunk")}
+        for fn in (new, old):
+            assert "pallas_call" not in str(jax.make_jaxpr(
+                lambda x, p: fn(x, {**p, **meta}))(x, params))
+        out_new, out_old = (jax.jit(lambda x, p: fn(x, {**p, **meta}))(x, params)
+                            for fn in (new, old))
+        np.testing.assert_array_equal(_f32(out_new), _f32(out_old))
+        g_new, g_old = (jax.jit(jax.grad(
+            lambda x, p: fn(x, {**p, **meta}).astype(jnp.float32).sum(),
+            argnums=(0, 1)))(x, params) for fn in (new, old))
+        for a, c in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
+            np.testing.assert_array_equal(_f32(a), _f32(c))
 
 
 def _dispatches_kernel(shape, dtype=jnp.bfloat16, causal=True,
                        monkeypatch=None):
-    monkeypatch.setattr(runtime, "resolve", lambda: "compile")
+    monkeypatch.setattr(runtime, "impl", lambda: "mosaic")
     q = jax.ShapeDtypeStruct(shape, dtype)
     jaxpr = jax.make_jaxpr(
         lambda q, k, v: attention(q, k, v, scale=0.125, causal=causal))(
@@ -200,7 +201,7 @@ def test_compiled_kernel_matches_dense_on_tpu():
     """Mosaic-compiled at a gpt2-paper layer's shape, 8 rows of 1024."""
     b, s, h, dh = 8, 1024, 12, 64
     q, k, v, do = _qkvg(b, s, h, dh, seed=11)
-    with runtime.kernel_mode_scope("auto"):
+    with runtime.impl_scope("mosaic"):
         got = jax.jit(lambda *a: _value_and_grads(
             lambda q, k, v: attention(q, k, v, scale=0.125), *a))(
                 q, k, v, do)

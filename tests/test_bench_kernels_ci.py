@@ -35,7 +35,6 @@ _DEVICE_FAMILIES = (
 def test_bench_kernels_quick_schema(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")  # schema run, never the chip
     env.pop("XLA_FLAGS", None)
-    env.pop("REPRO_KERNEL_MODE", None)   # the bench scopes its own modes
     src = os.path.join(_REPO, "src")
     env["PYTHONPATH"] = os.pathsep.join(
         [_REPO, src, env.get("PYTHONPATH", "")])

@@ -230,6 +230,7 @@ from jax.sharding import PartitionSpec as P
 from jax.sharding import AxisType
 from repro.core import OptiReduceConfig, SyncContext, sync_pytree, \
     sync_pytree_unfused
+from repro.kernels.runtime import impl_scope
 
 mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
@@ -248,17 +249,18 @@ def run(fn, cfg):
     return f(tree)
 
 # 8 full 1024-elem buckets: drops + kernels + quantized exchange, bitwise
-for strat, dr, uk in (("optireduce", 0.1, False), ("optireduce", 0.1, True),
-                      ("optireduce_q", 0.05, True)):
-    cfg = OptiReduceConfig(strategy=strat, drop_rate=dr, hadamard_block=256,
-                           use_kernels=uk)
-    ref, ref_frac = run(sync_pytree_unfused, cfg)
-    out, out_frac = run(sync_pytree, cfg)
+for strat, dr, impl in (("optireduce", 0.1, "jnp"),
+                        ("optireduce", 0.1, "interpret"),
+                        ("optireduce_q", 0.05, "interpret")):
+    cfg = OptiReduceConfig(strategy=strat, drop_rate=dr, hadamard_block=256)
+    with impl_scope(impl):
+        ref, ref_frac = run(sync_pytree_unfused, cfg)
+        out, out_frac = run(sync_pytree, cfg)
     for k in tree:
         assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k])), \
-            (strat, uk, k)
+            (strat, impl, k)
     np.testing.assert_allclose(float(ref_frac), float(out_frac), atol=1e-6)
-    print(strat, "uk=%s" % uk, "bitwise OK, loss_frac %.4f" % float(out_frac))
+    print(strat, impl, "bitwise OK, loss_frac %.4f" % float(out_frac))
 """
 
 

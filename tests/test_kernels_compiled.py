@@ -3,8 +3,8 @@
 CPU CI exercises every kernel in the Pallas interpreter; these tests close
 the remaining gap on real hardware: the compiled double-buffered kernels
 must agree with their interpreted selves (same grid, same revolving-buffer
-DMA schedule, Mosaic lowering instead of the interpreter), and ``auto``
-dispatch must actually pick compilation.
+DMA schedule, Mosaic lowering instead of the interpreter), and the
+default implementation must actually be Mosaic.
 """
 import jax
 import jax.numpy as jnp
@@ -23,17 +23,16 @@ pytestmark = pytest.mark.tpu
 
 
 def _both_modes(fn):
-    with runtime.kernel_mode_scope("interpret"):
+    with runtime.impl_scope("interpret"):
         interp = np.asarray(fn())
-    with runtime.kernel_mode_scope("compile"):
+    with runtime.impl_scope("mosaic"):
         comp = np.asarray(fn())
     return interp, comp
 
 
 def test_auto_picks_compile_on_tpu():
-    with runtime.kernel_mode_scope("auto"):
-        assert runtime.resolve() == "compile"
-        assert not runtime.interpret_flag()
+    assert runtime.impl() == "mosaic"
+    assert not runtime.interpret_flag()
 
 
 @pytest.mark.parametrize("rows", [4, 37, 64])

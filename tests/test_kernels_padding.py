@@ -28,7 +28,7 @@ from repro.kernels.quant.quant import grid_quant_pallas, uniform_quant_pallas
 
 # compiled mode rides along automatically when this suite runs on a TPU box
 MODES = ["interpret"] + (
-    ["compile"] if jax.default_backend() == "tpu" else [])
+    ["mosaic"] if jax.default_backend() == "tpu" else [])
 
 N = 128          # Hadamard block / column width
 BR = 8           # block_rows: small so tails are cheap to sweep
@@ -53,7 +53,7 @@ def _rows_data(seed):
 
 def _assert_prefix(run_full, run_prefix):
     for mode in MODES:
-        with runtime.kernel_mode_scope(mode):
+        with runtime.impl_scope(mode):
             full = np.asarray(run_full())
             prefix = np.asarray(run_prefix())
         np.testing.assert_array_equal(prefix, full[:prefix.shape[0]])

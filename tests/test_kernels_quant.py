@@ -9,18 +9,20 @@ try:
 except ImportError:
     from _hypothesis_fallback import given, strategies as st
 
+from repro.kernels import runtime
 from repro.kernels.quant import (grid_quant, grid_quant_ref, uniform_dequant,
                                  uniform_quant, uniform_quant_ref)
 
 
 @pytest.mark.parametrize("bits", [2, 4, 8])
 @pytest.mark.parametrize("shape", [(4, 256), (17, 1000)])
-def test_kernel_matches_oracle(bits, shape):
+def test_kernel_matches_oracle(bits, shape, impl):
     key = jax.random.PRNGKey(bits)
     x = jax.random.normal(key, shape)
     noise = jax.random.uniform(jax.random.fold_in(key, 1), shape)
     lohi = jnp.array([float(x.min()) - 1e-3, float(x.max()) + 1e-3])
-    a = uniform_quant(x, noise, lohi, bits=bits, use_kernel=True)
+    with runtime.impl_scope(impl):
+        a = uniform_quant(x, noise, lohi, bits=bits)
     b = uniform_quant_ref(x, noise, lohi[0], lohi[1], bits=bits)
     assert int(jnp.max(jnp.abs(a.astype(jnp.int32) -
                                b.astype(jnp.int32)))) == 0
@@ -28,7 +30,7 @@ def test_kernel_matches_oracle(bits, shape):
 
 @pytest.mark.parametrize("bits", [2, 4, 8])
 @pytest.mark.parametrize("shape", [(4, 256), (37, 1000), (131, 256)])
-def test_grid_quant_kernel_matches_ref(bits, shape):
+def test_grid_quant_kernel_matches_ref(bits, shape, impl):
     """Per-row-grid quantizer (TAR stage-2 shard re-encode): kernel ==
     jnp oracle bit-exactly, including the padded-rows path."""
     key = jax.random.PRNGKey(bits + shape[0])
@@ -37,14 +39,15 @@ def test_grid_quant_kernel_matches_ref(bits, shape):
     amax = jnp.maximum(jnp.max(jnp.abs(x), axis=1), 1e-12)
     levels = (1 << bits) - 1
     lo, step = -amax, 2.0 * amax / levels
-    a = grid_quant(x, noise, lo, step, bits=bits, use_kernel=True)
+    with runtime.impl_scope(impl):
+        a = grid_quant(x, noise, lo, step, bits=bits)
     b = grid_quant_ref(x, noise, lo, step, bits=bits)
     assert a.dtype == jnp.uint8
     assert int(jnp.max(jnp.abs(a.astype(jnp.int32) -
                                b.astype(jnp.int32)))) == 0
 
 
-def test_grid_quant_matches_scalar_quant_on_uniform_grid():
+def test_grid_quant_matches_scalar_quant_on_uniform_grid(impl):
     """With every row sharing one grid, grid_quant degenerates to the
     scalar-grid uniform_quant."""
     key = jax.random.PRNGKey(9)
@@ -54,8 +57,9 @@ def test_grid_quant_matches_scalar_quant_on_uniform_grid():
     levels = 255
     lo = jnp.full((16,), -4.0)
     step = jnp.full((16,), 8.0 / levels)
-    a = grid_quant(x, noise, lo, step, bits=8, use_kernel=True)
-    b = uniform_quant(x, noise, lohi, bits=8, use_kernel=True)
+    with runtime.impl_scope(impl):
+        a = grid_quant(x, noise, lo, step, bits=8)
+        b = uniform_quant(x, noise, lohi, bits=8)
     assert int(jnp.max(jnp.abs(a.astype(jnp.int32) -
                                b.astype(jnp.int32)))) == 0
 

@@ -17,12 +17,13 @@ import sys
 
 import pytest
 
-# (strategy, drop_rate, use_kernels): Hadamard, rounds-scheduled quantized,
-# and the kernel-dispatched quantized a2a path
+# (strategy, drop_rate, codec kernel implementation): Hadamard,
+# rounds-scheduled quantized, and the quantized a2a path through the
+# interpreted Pallas kernels
 STRATEGIES = [
-    ("optireduce", 0.1, False),
-    ("tar_rounds_q", 0.05, False),
-    ("optireduce_q", 0.05, True),
+    ("optireduce", 0.1, "jnp"),
+    ("tar_rounds_q", 0.05, "jnp"),
+    ("optireduce_q", 0.05, "interpret"),
 ]
 
 CHILD = r"""
@@ -35,6 +36,7 @@ from jax.sharding import AxisType
 from repro.core.allreduce import OptiReduceConfig, SyncContext, sync_bucket
 from repro.core import drops as drops_lib, tar as tar_lib
 from repro.core.pipeline import resolve_spec
+from repro.kernels.runtime import impl_scope
 from repro.net import HostRing, InprocBackend, mask_scripted_drops, wire_spec
 
 N, L = 4, 1000            # not block-aligned: pad + tail-packet paths on
@@ -61,10 +63,9 @@ def lossy_masks(cfg):
         rate=cfg.drop_rate, packet_elems=cfg.packet_elems,
         self_index=jnp.asarray(me))) for me in range(N)}
 
-for strat, dr, uk in %(strategies)r:
+def check(strat, dr):
     cfg = OptiReduceConfig(strategy=strat, drop_rate=dr, hadamard_block=256,
-                           use_kernels=uk, quant_bits=8, packet_elems=64,
-                           incast=2)
+                           quant_bits=8, packet_elems=64, incast=2)
     ref, ref_frac = run(cfg)
     masks = lossy_masks(cfg)
     drop_fn = mask_scripted_drops(masks, cfg.packet_elems)
@@ -83,8 +84,8 @@ for strat, dr, uk in %(strategies)r:
     # scripted loss is a pure function of the packet header, so call 1
     # consumes exchange 0's masks == the Lossy masks, bitwise)
     cfg_w = OptiReduceConfig(strategy=strat, drop_rate=0.0,
-                             hadamard_block=256, use_kernels=uk,
-                             quant_bits=8, packet_elems=64, incast=2)
+                             hadamard_block=256, quant_bits=8,
+                             packet_elems=64, incast=2)
     bridge_ring = HostRing(N, cfg_w,
                            backend=InprocBackend(N, drop_fn=drop_fn))
     wspec = wire_spec(cfg_w, bridge_ring)
@@ -99,6 +100,10 @@ for strat, dr, uk in %(strategies)r:
     wt = bridge_ring.drain_telemetry()
     assert wt is not None and len(wt.peer_stage_times) == N
     print("BRIDGE_PARITY %%s OK" %% strat)
+
+for strat, dr, impl in %(strategies)r:
+    with impl_scope(impl):
+        check(strat, dr)
 
 print("ALL_WIRE_PARITY_OK")
 """

@@ -20,6 +20,7 @@ import pytest
 
 from repro.configs import get_smoke
 from repro.core.allreduce import OptiReduceConfig
+from repro.kernels import runtime
 from repro.launch.mesh import make_host_mesh
 from repro.models import abstract_params
 from repro.obs import scopes, trace
@@ -46,8 +47,7 @@ def _compiled_text(strategy, sync_mode, dp_mode, microbatch) -> str:
     cfg = get_smoke("gpt2-paper")
     tc = TrainConfig(
         sync=OptiReduceConfig(strategy=strategy, drop_rate=0.01,
-                              hadamard_block=256,
-                              use_kernels=strategy == "optireduce_q"),
+                              hadamard_block=256),
         optimizer=OptimizerConfig(name="adamw"), sync_mode=sync_mode,
         dp_mode=dp_mode, microbatch=microbatch, seq_chunk=32,
         bucket_elems=1 << 14)
@@ -57,9 +57,12 @@ def _compiled_text(strategy, sync_mode, dp_mode, microbatch) -> str:
     batch = {k: jax.ShapeDtypeStruct((4, 64), jnp.int32)
              for k in ("tokens", "labels")}
     step_fn, _ = make_step(opt_state, batch)
-    return jax.jit(step_fn).lower(
-        params, opt_state, batch, jax.ShapeDtypeStruct((), jnp.int32),
-        jax.ShapeDtypeStruct((2,), jnp.uint32)).compile().as_text()
+    # the quantized paths trace the codec kernels (interpreted here)
+    with runtime.impl_scope(
+            "interpret" if strategy == "optireduce_q" else "jnp"):
+        return jax.jit(step_fn).lower(
+            params, opt_state, batch, jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32)).compile().as_text()
 
 
 def _innermost_scope(op_name: str):

@@ -4,8 +4,9 @@ bitwise-identical between the fused ``sync_pytree`` engine — in all three
 schedules, ``scan`` / ``vmap`` / the stage-skewed ``pipelined`` software
 pipeline (including B=1/B=2, where the skew is deeper than the bucket
 count) — and the ``sync_pytree_unfused`` seed-oracle loop on an 8-device
-mesh, with drops, kernels, and quantization enabled — plus the 2D
-(pod, data) reduce-scatter replica-consistency guarantees.
+mesh, with drops, kernels (in the Pallas interpreter), and quantization
+enabled — plus the 2D (pod, data) reduce-scatter replica-consistency
+guarantees.
 
 Runs in ONE subprocess (8 forced host devices, same pattern as
 test_collectives.py); the parametrized tests assert per-strategy markers
@@ -18,20 +19,21 @@ import sys
 import pytest
 
 # seed names + register_strategy'd cross-product compositions, with the
-# knob set each is exercised under: (drop_rate, use_kernels)
+# knob set each is exercised under: (drop_rate, the codec kernels'
+# implementation scope in kernels.runtime)
 STRATEGIES = [
-    ("psum", 0.0, False),
-    ("gloo_ring", 0.0, False),
-    ("nccl_tree", 0.0, False),
-    ("bcube", 0.0, False),
-    ("tar_tcp", 0.0, True),
-    ("tar_rounds", 0.0, False),
-    ("optireduce", 0.1, True),
-    ("optireduce_2d", 0.1, True),
-    ("optireduce_q", 0.05, True),
-    ("optireduce_rounds", 0.1, False),
-    ("tar_rounds_q", 0.05, True),
-    ("ring_ht", 0.0, True),
+    ("psum", 0.0, "jnp"),
+    ("gloo_ring", 0.0, "jnp"),
+    ("nccl_tree", 0.0, "jnp"),
+    ("bcube", 0.0, "jnp"),
+    ("tar_tcp", 0.0, "interpret"),
+    ("tar_rounds", 0.0, "jnp"),
+    ("optireduce", 0.1, "interpret"),
+    ("optireduce_2d", 0.1, "interpret"),
+    ("optireduce_q", 0.05, "interpret"),
+    ("optireduce_rounds", 0.1, "jnp"),
+    ("tar_rounds_q", 0.05, "interpret"),
+    ("ring_ht", 0.0, "interpret"),
 ]
 
 CHILD = r"""
@@ -44,6 +46,7 @@ from jax.sharding import AxisType
 from repro.core import (OptiReduceConfig, SyncContext, sync_pytree,
                         sync_pytree_unfused)
 from repro.core.allreduce import reduce_scatter_axis, rs_spec
+from repro.kernels.runtime import impl_scope
 
 mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
@@ -63,11 +66,12 @@ def run(fn, cfg, bucket_elems=1024):
     return f(tree)
 
 for item in %(strategies)r:
-    strat, dr, uk = item
+    strat, dr, impl = item
     cfg = OptiReduceConfig(strategy=strat, drop_rate=dr, hadamard_block=256,
-                           use_kernels=uk, quant_bits=8, incast=3)
-    ref, ref_frac = run(sync_pytree_unfused, cfg)
-    out, out_frac = run(sync_pytree, cfg)
+                           quant_bits=8, incast=3)
+    with impl_scope(impl):
+        ref, ref_frac = run(sync_pytree_unfused, cfg)
+        out, out_frac = run(sync_pytree, cfg)
     for k in tree:
         assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k])), \
             (strat, k)
@@ -77,7 +81,9 @@ for item in %(strategies)r:
     # collectives) and the stage-skewed software pipeline (B=4 here:
     # prologue + a 2-step lax.scan steady state + epilogue all execute)
     for mode in ("vmap", "pipelined"):
-        alt, alt_frac = run(functools.partial(sync_pytree, mode=mode), cfg)
+        with impl_scope(impl):
+            alt, alt_frac = run(functools.partial(sync_pytree, mode=mode),
+                                cfg)
         for k in tree:
             assert np.array_equal(np.asarray(ref[k]), np.asarray(alt[k])), \
                 (mode, strat, k)
@@ -89,15 +95,17 @@ for item in %(strategies)r:
 # ---- skew deeper than the bucket count: B=1 and B=2 edge cases -----------
 # (tree total is 4096, so bucket_elems 4096/2048 give full tail buckets and
 # the quantized strategies stay bitwise vs the oracle)
-for strat, dr, uk in (("optireduce", 0.1, True),
-                      ("optireduce_q", 0.05, True),
-                      ("optireduce_rounds", 0.1, False)):
+for strat, dr, impl in (("optireduce", 0.1, "interpret"),
+                        ("optireduce_q", 0.05, "interpret"),
+                        ("optireduce_rounds", 0.1, "jnp")):
     cfg = OptiReduceConfig(strategy=strat, drop_rate=dr, hadamard_block=256,
-                           use_kernels=uk, quant_bits=8, incast=3)
+                           quant_bits=8, incast=3)
     for be, nb in ((4096, 1), (2048, 2)):
-        ref, ref_frac = run(sync_pytree_unfused, cfg, bucket_elems=be)
+        with impl_scope(impl):
+            ref, ref_frac = run(sync_pytree_unfused, cfg, bucket_elems=be)
         for fn in (sync_pytree, sync_pipelined):
-            out, out_frac = run(fn, cfg, bucket_elems=be)
+            with impl_scope(impl):
+                out, out_frac = run(fn, cfg, bucket_elems=be)
             for k in tree:
                 assert np.array_equal(np.asarray(ref[k]),
                                       np.asarray(out[k])), (strat, be, k)
@@ -112,14 +120,15 @@ for strat, dr, uk in (("optireduce", 0.1, True),
 import dataclasses
 from repro.runtime import SyncPolicy
 for item in %(strategies)r:
-    strat, dr, uk = item
+    strat, dr, impl = item
     cfg = OptiReduceConfig(strategy=strat, drop_rate=dr, hadamard_block=256,
-                           use_kernels=uk, quant_bits=8, incast=3)
+                           quant_bits=8, incast=3)
     policy = SyncPolicy(use_hadamard=cfg.use_hadamard, incast=cfg.incast,
                         active_peers=tuple(range(8)),
                         shard_weights=(4,) * 8, dead_links=())
-    ref, ref_frac = run(sync_pytree, cfg)
-    out, out_frac = run(sync_pytree, policy.apply(cfg))
+    with impl_scope(impl):
+        ref, ref_frac = run(sync_pytree, cfg)
+        out, out_frac = run(sync_pytree, policy.apply(cfg))
     for k in tree:
         assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k])), \
             ("policy", strat, k)
@@ -144,14 +153,15 @@ def run_scaled(cfg):
     return f(xflat)
 
 expected = np.asarray(xflat) * np.mean([1.0 + p for p in ACTIVE])
-for strat, uk, tol in (("optireduce", True, 1e-4),      # a2a: mask exclusion
-                       ("optireduce_rounds", False, 1e-4),  # subset schedule
-                       ("ring_ht", False, 1e-4),        # virtual ring
-                       ("optireduce_q", True, 5e-2)):   # quantized subset
+for strat, impl, tol in (
+        ("optireduce", "interpret", 1e-4),      # a2a: mask exclusion
+        ("optireduce_rounds", "jnp", 1e-4),     # subset schedule
+        ("ring_ht", "jnp", 1e-4),               # virtual ring
+        ("optireduce_q", "interpret", 5e-2)):   # quantized subset
     cfg = OptiReduceConfig(strategy=strat, drop_rate=0.0, hadamard_block=256,
-                           use_kernels=uk, quant_bits=8, incast=3,
-                           active_peers=ACTIVE)
-    out, _ = run_scaled(cfg)
+                           quant_bits=8, incast=3, active_peers=ACTIVE)
+    with impl_scope(impl):
+        out, _ = run_scaled(cfg)
     out = np.asarray(out)
     assert np.array_equal(out, np.broadcast_to(out[0:1], out.shape)), \
         ("participation replica divergence", strat)
@@ -160,7 +170,8 @@ for strat, uk, tol in (("optireduce", True, 1e-4),      # a2a: mask exclusion
     # with transport drops on top, replicas must still agree bitwise
     if strat != "ring_ht":                       # ring rejects Lossy
         cfgd = dataclasses.replace(cfg, drop_rate=0.1)
-        outd, _ = run_scaled(cfgd)
+        with impl_scope(impl):
+            outd, _ = run_scaled(cfgd)
         outd = np.asarray(outd)
         assert np.array_equal(outd, np.broadcast_to(outd[0:1], outd.shape)), \
             ("participation+drops divergence", strat)
@@ -264,10 +275,11 @@ def run_tree(fn, cfg, t, be, **kw):
                               out_specs=(tspec, P()), check_vma=False))
     return f(t)
 
-def same_bits(case, cfg, t, be, lane, **kw):
+def same_bits(case, cfg, t, be, lane, impl="interpret", **kw):
     assert lane_of(cfg, t, be) == lane, (case, lane_of(cfg, t, be))
-    ref, ref_frac = run_tree(sync_pytree_unfused, cfg, t, be)
-    out, out_frac = run_tree(sync_pytree, cfg, t, be, **kw)
+    with impl_scope(impl):
+        ref, ref_frac = run_tree(sync_pytree_unfused, cfg, t, be)
+        out, out_frac = run_tree(sync_pytree, cfg, t, be, **kw)
     for k in t:
         assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k])), \
             (case, k)
@@ -275,7 +287,7 @@ def same_bits(case, cfg, t, be, lane, **kw):
     print("LAYOUT %%s OK" %% case)
 
 cfg_d = OptiReduceConfig(strategy="optireduce", drop_rate=0.1,
-                         hadamard_block=256, use_kernels=True, incast=3)
+                         hadamard_block=256, incast=3)
 # B = 3: the pipelined schedule fully unrolled over a dense (3, 4, 256)
 same_bits("unrolled", cfg_d, {"w": jax.random.normal(key, (3, 1024))},
           1024, 256, mode="pipelined")
@@ -286,15 +298,14 @@ same_bits("scan", cfg_d, {"w": jax.random.normal(key, (6, 1024))}, 1024,
 same_bits("plan_fallback", cfg_d, tree, 1000, 0, mode="pipelined")
 # dense (9, 128) buckets whose 8 shards are not whole rows: the TAR stages
 # take the flat view inside a dense arena (Identity codec, lane 128)
-same_bits("view_fallback", OptiReduceConfig(strategy="tar_tcp",
-                                            use_kernels=True),
+same_bits("view_fallback", OptiReduceConfig(strategy="tar_tcp"),
           tree, 1152, 128, mode="pipelined")
 # shard weights on the rounds schedule: unit rows take the flat view
 same_bits("weighted_rounds",
           OptiReduceConfig(strategy="optireduce_rounds", drop_rate=0.1,
                            hadamard_block=256, incast=3,
                            shard_weights=(2,) * 7 + (1,)),
-          tree, 1024, 256, mode="pipelined")
+          tree, 1024, 256, impl="jnp", mode="pipelined")
 
 # recovery: the stale cache and EF residuals take the arena's shape; the
 # dense arena gives the flat arena's bits (the oracle has no stale input)
@@ -312,17 +323,20 @@ def run_stale(arena, stale):
     f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
                               out_specs=P(), check_vma=False))
     return f(arena, stale)
-got_d = dense_p.unpack(run_stale(dense_p.pack(t6), dense_p.pack(st6)))
-got_f = flat_p.unpack(run_stale(flat_p.pack(t6), flat_p.pack(st6)))
+with impl_scope("interpret"):
+    got_d = dense_p.unpack(run_stale(dense_p.pack(t6), dense_p.pack(st6)))
+    got_f = flat_p.unpack(run_stale(flat_p.pack(t6), flat_p.pack(st6)))
 assert np.array_equal(np.asarray(got_d["w"]), np.asarray(got_f["w"]))
 print("LAYOUT stale OK")
 
 cfg_ef = dataclasses.replace(cfg_d, recovery="ef")
-ef_d = recovery_lib.ef_residual_arena(dense_p.pack(t6), key, cfg_ef, 8,
-                                      jnp.int32(3),
-                                      stale=dense_p.pack(st6))
-ef_f = recovery_lib.ef_residual_arena(flat_p.pack(t6), key, cfg_ef, 8,
-                                      jnp.int32(3), stale=flat_p.pack(st6))
+with impl_scope("interpret"):
+    ef_d = recovery_lib.ef_residual_arena(dense_p.pack(t6), key, cfg_ef, 8,
+                                          jnp.int32(3),
+                                          stale=dense_p.pack(st6))
+    ef_f = recovery_lib.ef_residual_arena(flat_p.pack(t6), key, cfg_ef, 8,
+                                          jnp.int32(3),
+                                          stale=flat_p.pack(st6))
 assert ef_d.shape == (6, 4, 256)
 assert np.array_equal(np.asarray(dense_p.unpack(ef_d)["w"]),
                       np.asarray(flat_p.unpack(ef_f)["w"]))
@@ -334,7 +348,7 @@ mesh2 = jax.make_mesh((2, 4), ("pod", "data"),
                       axis_types=(AxisType.Auto,) * 2)
 g = jax.random.normal(key, (4, 64, 48))        # same gradient on every node
 cfg2 = OptiReduceConfig(drop_rate=0.05, pod_axis="pod", hadamard_block=256,
-                        rs_wire_bits=8, use_kernels=True)
+                        rs_wire_bits=8)
 
 def rs_body(x):
     ctx = SyncContext(cfg=cfg2, key=jax.random.PRNGKey(1))
@@ -344,7 +358,8 @@ def rs_body(x):
 f2 = jax.jit(jax.shard_map(rs_body, mesh=mesh2, in_specs=P(None, None, None),
                        out_specs=P(("pod", "data"), None, None),
                        check_vma=False))
-out2 = np.asarray(f2(g))                       # (8, 16, 48): pod-major rows
+with impl_scope("interpret"):
+    out2 = np.asarray(f2(g))                   # (8, 16, 48): pod-major rows
 assert np.array_equal(out2[:4], out2[4:]), \
     np.max(np.abs(out2[:4] - out2[4:]))
 print("RS2D replica-consistency OK")
@@ -360,7 +375,8 @@ def grid_body(x):
     return enc_codec.encode(local.reshape(-1), ctx, "data").lo[None]
 f3 = jax.jit(jax.shard_map(grid_body, mesh=mesh2, in_specs=P(None),
                        out_specs=P(("pod", "data"), None), check_vma=False))
-lo = np.asarray(f3(jax.random.normal(key, (2048,))))
+with impl_scope("interpret"):
+    lo = np.asarray(f3(jax.random.normal(key, (2048,))))
 assert np.all(lo == lo[0:1]), "quant grids differ across pods"
 print("RS2D grids-shared OK")
 """
@@ -381,17 +397,17 @@ def parity_output():
 
 @pytest.mark.parity
 @pytest.mark.slow
-@pytest.mark.parametrize("strategy,drop_rate,use_kernels", STRATEGIES)
+@pytest.mark.parametrize("strategy,drop_rate,impl", STRATEGIES)
 def test_spec_bitwise_matches_seed_oracle(parity_output, strategy, drop_rate,
-                                          use_kernels):
+                                          impl):
     assert f"PARITY {strategy} OK" in parity_output, parity_output
 
 
 @pytest.mark.parity
 @pytest.mark.slow
-@pytest.mark.parametrize("strategy,drop_rate,use_kernels", STRATEGIES)
+@pytest.mark.parametrize("strategy,drop_rate,impl", STRATEGIES)
 def test_pipelined_mode_bitwise(parity_output, strategy, drop_rate,
-                                use_kernels):
+                                impl):
     """Every engine schedule — mode='vmap' and the stage-skewed software
     pipeline (mode='pipelined') — is pinned bitwise to mode='scan' and the
     sync_pytree_unfused oracle for every registered strategy on 8 devices,
@@ -414,9 +430,9 @@ def test_pipelined_skew_deeper_than_bucket_count(parity_output, strategy):
 
 @pytest.mark.parity
 @pytest.mark.slow
-@pytest.mark.parametrize("strategy,drop_rate,use_kernels", STRATEGIES)
+@pytest.mark.parametrize("strategy,drop_rate,impl", STRATEGIES)
 def test_policy_full_set_is_bitwise_noop(parity_output, strategy, drop_rate,
-                                         use_kernels):
+                                         impl):
     """Acceptance: policy-driven dispatch with a full active-peer set (no
     stragglers detected), UNIFORM shard weights, and no dead links keeps
     every registered strategy bitwise-identical to its current output —

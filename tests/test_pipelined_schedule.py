@@ -1,7 +1,7 @@
 """Op-order inspection of the software-pipelined bucket schedule.
 
-The CI box runs Pallas in interpret mode, so on-TPU overlap cannot be timed
-here; what CAN be pinned is the lowered HLO: with ``mode="pipelined"`` the
+The CPU runs the codec kernels in their jnp form, so on-TPU overlap cannot
+be timed here; what CAN be pinned is the lowered HLO: with ``mode="pipelined"`` the
 exchange collectives of bucket k-1 must be *emitted between* the encode
 kernels of bucket k and the decode kernels of bucket k-2 (StableHLO emission
 follows trace order for data-independent ops, and the skew removes the data
@@ -11,9 +11,9 @@ need to overlap communication with neighboring buckets' codec work.
 Runs in a 2-forced-host-device subprocess; reads op order over the whole
 lowered module (the shard_map body is inlined into ``main``, and the nested
 codec functions hold no collectives), identifies the codec kernels by their
-``randomized_fwht`` callee specializations (encode and decode lower to
-distinct nested-jit functions) and the exchanges by the stablehlo collective
-ops.
+nested-jit callees (``randomized_fwht`` specializations, encode and decode
+distinct, for the Hadamard codec; ``_ht_amax`` for the quantizing codec's
+encode) and the exchanges by the stablehlo collective ops.
 """
 import os
 import subprocess
@@ -117,17 +117,15 @@ def lower(nbuckets, **kw):
                               out_specs={"g": P()}, check_vma=False))
     return f.lower(tree).as_text()
 
-# op order over the whole lowered module (see CHILD)
+# op order over the whole lowered module (see CHILD); the quantizing
+# codec's encode stage is its rotate-and-amax kernel call
 body = lower(3, mode="pipelined").splitlines()
 a2a = [i for i, l in enumerate(body) if "stablehlo.all_to_all" in l]
 ag = [i for i, l in enumerate(body) if "stablehlo.all_gather" in l]
 ar = [i for i, l in enumerate(body) if "stablehlo.all_reduce" in l]
-fwht = [(i, l) for i, l in enumerate(body)
-        if re.search(r"call @randomized_fwht[_0-9]*\(", l)]
-callee = lambda l: re.search(r"call @(randomized_fwht[_0-9]*)\(",
-                             l).group(1)
-enc_name = callee(fwht[0][1])
-enc = [i for i, l in fwht if callee(l) == enc_name]
+enc = [i for i, l in enumerate(body)
+       if re.search(r"call @_ht_amax[_0-9]*\(", l)]
+assert len(enc) == 3, (len(enc), "one encode per bucket")
 
 # ---- the THC grid pmax rides the exchange stage ---------------------------
 # split encode: encode_stage emits only the local amax; the pmax

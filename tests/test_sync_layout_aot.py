@@ -58,7 +58,7 @@ def v5e_mesh():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     with pytest.MonkeyPatch.context() as mp, \
-            runtime.kernel_mode_scope("compile"):
+            runtime.impl_scope("mosaic"):
         mp.setattr(runtime, "_mosaic_available", lambda: True)
         yield Mesh([topo.devices[0]], ("data",))
     jax.config.update("jax_enable_compilation_cache", prev)
@@ -71,8 +71,7 @@ def compiled(v5e_mesh):
     out = {}
     for strategy in STRATEGIES:
         cfg = OptiReduceConfig(strategy=strategy, drop_rate=0.01,
-                               drop_pattern="tail", hadamard_block=BLOCK,
-                               use_kernels=True)
+                               drop_pattern="tail", hadamard_block=BLOCK)
         tree = {"g": jax.ShapeDtypeStruct((NBUCKETS * BUCKET,), jnp.float32)}
         plan = BucketPlan.for_tree(
             tree, BUCKET, lane=arena_lane(resolve_spec(cfg), cfg))
