@@ -17,12 +17,14 @@ Parallelism (manual SPMD inside shard_map; see models/parallel.py)
     non-tp dim; gathered just-in-time in the scan body via ``pctx.gather``
     — whose custom VJP is where OptiReduce runs as the ZeRO reduce-scatter
     (see train/trainer.py).
-  * loss: vocab-sharded cross-entropy, chunked over sequence, rematerialized
-    — full logits are never alive (256k vocab would not fit otherwise).
+  * loss: vocab-sharded cross-entropy, chunked over sequence, its gradient
+    formed in the forward pass — full logits are never alive (256k vocab
+    would not fit otherwise).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, NamedTuple
 
@@ -508,8 +510,14 @@ def forward_hidden(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,
 def lm_loss(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,
             key: jax.Array, seq_chunk: int = 1024,
             remat: bool = True, unroll: bool = False) -> jnp.ndarray:
-    """Mean next-token cross-entropy with a vocab-sharded, seq-chunked,
-    rematerialized softmax (full logits are never materialized)."""
+    """Mean next-token cross-entropy with a vocab-sharded, seq-chunked
+    softmax (full logits are never materialized).
+
+    ``remat`` applies to ``forward_hidden``. The head and loss are one
+    custom-VJP function (``_head_loss``): differentiated, it forms the
+    head's gradient in the forward pass from each chunk's logits, so the
+    logits matmul runs once; undifferentiated, it computes the loss only.
+    """
     x = forward_hidden(params, batch, cfg, pctx, key=key, remat=remat,
                        unroll=unroll)
     labels = batch["labels"]
@@ -522,56 +530,104 @@ def lm_loss(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,
         head_l = emb.T                    # (D, V_local)
     else:
         head_l = _maybe_gather(pctx, head, 0, key)
+    return _head_loss(x, head_l, labels, pctx, seq_chunk, unroll)
+
+
+def _head_chunks(x, head_l, labels, pctx: ParallelCtx, seq_chunk: int,
+                 unroll: bool, grads: bool):
+    """Loss (and with ``grads`` its gradient for x and head_l) over
+    sequence chunks of the vocab-sharded head.
+
+    The cross-entropy ends the model and its cotangent is a scalar, so the
+    gradient is g = (softmax - onehot) * weight / count per chunk, with
+    dx = g W^T and dW = sum of x^T g: three matmuls a chunk, g fused into
+    the last two by XLA. On a tp mesh (``shard_map(check_vma=False)``)
+    autodiff transposes each psum_tp of the loss into a psum_tp of its
+    replicated cotangent; psum_tp of the row coefficient gives the same
+    gradient, and each shard's dx is its vocabulary slice's partial.
+    """
+    b, s, d = x.shape
+    v_local = head_l.shape[1]
+    lo = pctx.tp_index() * v_local
+    # frontend prefixes may leave a token count that is not a multiple
+    # of the requested chunk
+    chunk = math.gcd(min(seq_chunk, s), s)
+    xc = x.reshape(b, s // chunk, chunk, d).transpose(1, 0, 2, 3)
+    yc = labels.reshape(b, s // chunk, chunk).transpose(1, 0, 2)
+    denom = jnp.maximum(jnp.sum((labels >= 0).astype(jnp.float32)), 1.0)
+    w32 = head_l.astype(jnp.float32)
+
+    def chunk_loss(x_chunk, y_chunk):
+        x32 = x_chunk.astype(jnp.float32)
+        logits = jnp.einsum("bcd,dv->bcv", x32, w32)
+        m = pctx.pmax_tp(jnp.max(logits, axis=-1))
+        z = pctx.psum_tp(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
+        local_y = y_chunk - lo
+        valid = (local_y >= 0) & (local_y < v_local)
+        safe = jnp.clip(local_y, 0, v_local - 1)
+        picked = jnp.take_along_axis(logits, safe[..., None],
+                                     axis=-1)[..., 0]
+        correct = pctx.psum_tp(jnp.where(valid, picked, 0.0))
+        weight = (y_chunk >= 0).astype(jnp.float32)
+        lse = jnp.log(z) + m
+        nll = jnp.sum((lse - correct) * weight)
+        if not grads:
+            return nll, None, None
+        coef = pctx.psum_tp(weight / denom)
+        onehot = (jnp.arange(v_local) == local_y[..., None])
+        g = (jnp.exp(logits - lse[..., None]) - onehot) * coef[..., None]
+        dx = jnp.einsum("bcv,dv->bcd", g, w32).astype(x.dtype)
+        dw = jnp.einsum("bcd,bcv->dv", x32, g).astype(head_l.dtype)
+        return nll, dx, dw
+
+    dw = jnp.zeros(head_l.shape, head_l.dtype) if grads else None
+    if xc.shape[0] == 1 or unroll:
+        total = jnp.zeros(())
+        dxs = []
+        for i in range(xc.shape[0]):
+            l, dx_i, dw_i = chunk_loss(xc[i], yc[i])
+            total = total + l
+            if grads:
+                dxs.append(dx_i)
+                dw = dw + dw_i
+        dx = jnp.concatenate(dxs, axis=1) if grads else None
+    else:
+        def scan_body(carry, inp):
+            tot, dw_acc = carry
+            l, dx_i, dw_i = chunk_loss(*inp)
+            if grads:
+                dw_acc = dw_acc + dw_i
+            return (tot + l, dw_acc), dx_i
+
+        (total, dw), dxc = jax.lax.scan(scan_body, (jnp.zeros(()), dw),
+                                        (xc, yc))
+        dx = (dxc.transpose(1, 0, 2, 3).reshape(b, s, d) if grads
+              else None)
+    return total / denom, dx, dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _head_loss(x, head_l, labels, pctx, seq_chunk, unroll):
     with jax.named_scope(scopes.LM_HEAD):
-        v_local = head_l.shape[1]
-        shard = pctx.tp_index()
-        lo = shard * v_local
+        return _head_chunks(x, head_l, labels, pctx, seq_chunk, unroll,
+                            grads=False)[0]
 
-        b, s, d = x.shape
-        # frontend prefixes may leave a token count that is not a multiple
-        # of the requested chunk
-        chunk = math.gcd(min(seq_chunk, s), s)
-        xc = x.reshape(b, s // chunk, chunk, d).transpose(1, 0, 2, 3)
-        yc = labels.reshape(b, s // chunk, chunk).transpose(1, 0, 2)
 
-        def chunk_loss(x_chunk, y_chunk):
-            logits = jnp.einsum("bcd,dv->bcv", x_chunk.astype(jnp.float32),
-                                head_l.astype(jnp.float32))
-            # max-shift is gradient-neutral; pmax has no VJP, so detach
-            # first
-            m = pctx.pmax_tp(jnp.max(jax.lax.stop_gradient(logits),
-                                     axis=-1))
-            z = pctx.psum_tp(jnp.sum(jnp.exp(logits - m[..., None]),
-                                     axis=-1))
-            local_y = y_chunk - lo
-            valid = (local_y >= 0) & (local_y < v_local)
-            safe = jnp.clip(local_y, 0, v_local - 1)
-            picked = jnp.take_along_axis(logits, safe[..., None],
-                                         axis=-1)[..., 0]
-            correct = pctx.psum_tp(jnp.where(valid, picked, 0.0))
-            weight = (y_chunk >= 0).astype(jnp.float32)
-            nll = (jnp.log(z) + m - correct) * weight
-            return jnp.sum(nll), jnp.sum(weight)
+def _head_loss_fwd(x, head_l, labels, pctx, seq_chunk, unroll):
+    with jax.named_scope(scopes.LM_HEAD):
+        loss, dx, dw = _head_chunks(x, head_l, labels, pctx, seq_chunk,
+                                    unroll, grads=True)
+    return loss, (dx, dw)
 
-        if remat:
-            chunk_loss = jax.checkpoint(chunk_loss)
 
-        if xc.shape[0] == 1 or unroll:
-            total = jnp.zeros(())
-            count = jnp.zeros(())
-            for i in range(xc.shape[0]):
-                l, w = chunk_loss(xc[i], yc[i])
-                total = total + l
-                count = count + w
-        else:
-            def scan_body(carry, inp):
-                tot, cnt = carry
-                l, w = chunk_loss(*inp)
-                return (tot + l, cnt + w), None
+def _head_loss_bwd(pctx, seq_chunk, unroll, res, ct):
+    dx, dw = res
+    with jax.named_scope(scopes.LM_HEAD):
+        return ((dx.astype(jnp.float32) * ct).astype(dx.dtype),
+                (dw.astype(jnp.float32) * ct).astype(dw.dtype), None)
 
-            (total, count), _ = jax.lax.scan(
-                scan_body, (jnp.zeros(()), jnp.zeros(())), (xc, yc))
-        return total / jnp.maximum(count, 1.0)
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 def prefill_step(params, batch, cfg: ModelConfig, pctx: ParallelCtx, *,

@@ -9,6 +9,7 @@ and a traced ``Span`` shows up on the profiler's host plane; the launcher's
 import contextlib
 import glob
 import json
+import math
 import os
 import re
 import subprocess
@@ -126,19 +127,47 @@ def _op_names(text: str, scope: str) -> set:
             if any(pat.search(c) for c in m.group(1).split("/"))}
 
 
+HEAD_MATMUL = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([0-9,]*)\]\S* (?:dot|convolution)\(")
+
+
+def _head_matmuls(text: str) -> list:
+    """(output shape with the leading dims merged, op_name) of each matmul
+    of a compiled step that carries the ``lm_head`` scope."""
+    pat = re.compile(rf"(^|\(){scopes.LM_HEAD}(\)|$)")
+    out = []
+    for line in text.splitlines():
+        m, name = HEAD_MATMUL.match(line), OP_NAME.search(line)
+        if m and name and any(pat.search(c)
+                              for c in name.group(1).split("/")):
+            dims = [int(n) for n in m.group(1).split(",")]
+            out.append(((math.prod(dims[:-1]), dims[-1]), name.group(1)))
+    return out
+
+
 @pytest.mark.parametrize("path", ["replicated-optireduce_q-pipelined",
                                   "fsdp-optireduce-pipelined"])
 def test_embed_and_lm_head_scopes_lie_inside_the_step_scope(path):
     """The token lookup's and the LM head's scopes name instructions of
-    the optimized step, forward and gradient (a ``transpose(jvp(...))``
-    path), each under the step's forward/backward scope, and no
-    instruction of the sync or the optimizer."""
+    the optimized step, each under the step's forward/backward scope, and
+    no instruction of the sync or the optimizer. The lookup's gradient
+    is a ``transpose(jvp(...))`` path. The head forms its gradient in the
+    forward pass (``lm_loss``'s custom VJP): its three matmuls, the
+    logits (b·c, V), dX (b·c, d) and dW (d, V) of one sequence chunk,
+    carry the head's scope, and no other matmul does."""
     text = _compiled_text(*PATHS[path])
     for scope in (scopes.EMBED, scopes.LM_HEAD):
         paths = _op_names(text, scope)
-        assert any("transpose(jvp(" in p for p in paths), (scope, paths)
+        if scope == scopes.EMBED:
+            assert any("transpose(jvp(" in p for p in paths), (scope, paths)
         assert {_innermost_scope(p) for p in paths} == {scopes.FWD_BWD}, \
             (scope, sorted(paths)[:5])
+    cfg = get_smoke("gpt2-paper")
+    bc, d, v = 4 * 32, cfg.d_model, cfg.vocab_size    # batch 4, chunk 32
+    matmuls = _head_matmuls(text)
+    assert sorted(shape for shape, _ in matmuls) == sorted(
+        [(bc, v), (bc, d), (d, v)]), matmuls
+    assert {_innermost_scope(p) for _, p in matmuls} == {scopes.FWD_BWD}
 
 
 def _strip_metadata(hlo: str) -> str:
